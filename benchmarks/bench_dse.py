@@ -198,23 +198,36 @@ def _coldstart_workload(store_dir: str, t_spawn: float) -> None:
 
 def run_coldstart() -> None:
     """Run the identical workload in two fresh subprocesses against one
-    store: the first pays every cost (feature extraction, detailed sim,
-    XLA), the second must hit the artifact store and deserialize every
-    executable.  Emits before/after ``cold_start_to_first_result_s`` and
-    stores the full records in the --json artifact (``coldstart`` key)."""
+    store and one compile cache, both emptied first (under the checkout's
+    git-ignored ``.cache/coldstart``): the first pays every cost (feature
+    extraction, detailed sim, XLA), the second must hit the artifact store
+    and deserialize every executable.  Emits before/after
+    ``cold_start_to_first_result_s`` and stores the full records in the
+    --json artifact (``coldstart`` key).
+
+    The children need the device, and on a TPU host one process holds the
+    chip: this refuses to run once the parent has initialised a JAX
+    backend (``benchmarks.run`` schedules it before every other suite)."""
     import json
     import os
     import shutil
     import subprocess
     import sys
-    import tempfile
     import time
+
+    from repro.compat import backend_initialized
 
     from .common import SCALE, emit, set_extra
 
-    root = tempfile.mkdtemp(prefix="repro-coldstart-")
-    store = os.path.join(root, "store")
+    if backend_initialized():
+        raise RuntimeError(
+            "coldstart needs the device in its child processes, but this "
+            "process already initialised a JAX backend; run it first or alone"
+        )
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    root = os.path.join(repo, ".cache", "coldstart")
+    shutil.rmtree(root, ignore_errors=True)
+    store = os.path.join(root, "store")
 
     def child():
         env = dict(os.environ)
@@ -222,6 +235,7 @@ def run_coldstart() -> None:
             [os.path.join(repo, "src"), env.get("PYTHONPATH", "")]
         ).rstrip(os.pathsep)
         env.setdefault("BENCH_SCALE", SCALE)
+        env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(root, "jax")
         code = (
             "from benchmarks.bench_dse import _coldstart_workload; "
             f"_coldstart_workload({store!r}, {time.time()!r})"

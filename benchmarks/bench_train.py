@@ -182,7 +182,9 @@ def _measure(mode: str, n: int) -> dict:
 
 def _spawn_measure(mode: str, n: int) -> dict:
     env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"  # subprocess must never probe TPU
+    # Off the chip by design: the child measures the host data pipeline's
+    # peak RSS, and the parent (benchmarks.run) already holds the device.
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(_ROOT, "src")]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else [])

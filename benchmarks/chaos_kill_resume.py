@@ -95,6 +95,9 @@ def _spawn(store_root: str, resume_key: str, extra_env=None):
     env = os.environ.copy()
     env["PYTHONPATH"] = os.pathsep.join(
         filter(None, [_SRC, env.get("PYTHONPATH")]))
+    # Off the chip by design: this checks crash-resume of the host-side
+    # progress manifests, and the victim child is SIGKILLed mid-sweep —
+    # never do that to a process holding a TPU.
     env.setdefault("JAX_PLATFORMS", "cpu")
     env.pop("REPRO_FAULT_PLAN", None)
     env.update(extra_env or {})

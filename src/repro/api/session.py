@@ -557,7 +557,7 @@ class Session:
         mesh=None,
         plan: Optional[ExecutionPlan] = None,
         store: Optional[Union[ArtifactStore, str]] = None,
-        compile_cache: Union[None, bool, str] = None,
+        compile_cache: Optional[bool] = None,
     ):
         self.cfg = cfg if cfg is not None else TaoConfig()
         self.batch_size = batch_size
@@ -573,17 +573,12 @@ class Session:
         if isinstance(store, str):
             store = ArtifactStore(store)
         self.store = store
-        # JAX persistent compilation cache: auto-enabled alongside a store
-        # (executables land under store.xla_cache_dir so artifacts and
-        # binaries travel — and get wiped — together).  compile_cache=False
-        # opts out; True or a path enables it without a store.
-        if compile_cache is None:
-            if store is not None:
-                enable_persistent_cache(store.xla_cache_dir)
-        elif compile_cache is True:
+        # JAX persistent compilation cache (engine.aot: its directory is
+        # $JAX_COMPILATION_CACHE_DIR or the checkout's .cache/jax): on by
+        # default alongside a store; compile_cache=True enables it without
+        # one, False opts out.
+        if compile_cache or (compile_cache is None and store is not None):
             enable_persistent_cache()
-        elif compile_cache is not False:
-            enable_persistent_cache(compile_cache)
         # One partitioning decision for the whole workflow: models trained
         # by this session simulate under it, and Session.sweep composes the
         # trace queue with it.  None (the default, when no mesh/plan is

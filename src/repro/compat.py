@@ -1,7 +1,8 @@
-"""Shims over jax API drift (0.4.x .. 0.6+), collected in one place.
+"""The jax names the rest of the repo uses, for the installed jax (0.9).
 
-Every site that needs one of these imports it from here, so the next jax
-rename is a one-file fix.
+Every site that needs a sharding type, ``shard_map`` or a mesh imports it
+from here, so the next jax rename is a one-file fix (repro.analysis TAO001
+flags any direct jax.sharding/jax.experimental use outside this module).
 """
 from __future__ import annotations
 
@@ -13,22 +14,16 @@ __all__ = [
     "PartitionSpec",
     "SingleDeviceSharding",
     "shard_map",
+    "backend_initialized",
     "make_mesh",
-    "activate_mesh",
-    "cost_analysis",
     "on_tpu",
-    "enable_compilation_cache_flags",
-    "register_monitoring_listener",
 ]
 
-# The sharding types the rest of the repo may name.  They have moved once
-# already (jax.experimental.maps/pjit era -> jax.sharding); importing them
-# from here keeps the next move a one-file fix.  repro.analysis TAO001
-# flags any direct jax.sharding/jax.experimental use outside this module.
 Mesh = jax.sharding.Mesh
 NamedSharding = jax.sharding.NamedSharding
 PartitionSpec = jax.sharding.PartitionSpec
 SingleDeviceSharding = jax.sharding.SingleDeviceSharding
+shard_map = jax.shard_map
 
 
 def on_tpu() -> bool:
@@ -40,66 +35,20 @@ def on_tpu() -> bool:
     """
     return jax.default_backend() == "tpu"
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-    shard_map = jax.shard_map
-else:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+
+def backend_initialized() -> bool:
+    """Whether this process has initialised a JAX backend — on a TPU host,
+    whether it now holds the chip, which a child process then cannot
+    open."""
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
 
 
-def make_mesh(shape, axes):
-    """jax.make_mesh; newer jax wants explicit axis types, 0.4.x has none."""
-    try:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def activate_mesh(mesh):
-    """Context manager activating a mesh: jax.set_mesh on >= 0.6; on 0.4.x
-    the Mesh object is itself the context manager."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
-
-def cost_analysis(compiled):
-    """compiled.cost_analysis() returns a dict on recent jax, a one-element
-    list of dicts on 0.4.x."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
-def enable_compilation_cache_flags(directory: str) -> bool:
-    """Point jax's persistent compilation cache at ``directory``; returns
-    False when this jax build has no persistent-cache support at all.  The
-    size/time thresholds are zeroed where the flags exist (their names and
-    availability drifted across 0.4.x) so even sub-millisecond CPU-sized
-    executables persist — exactly the ones this repro's cold-start tests
-    replay."""
-    try:
-        jax.config.update("jax_compilation_cache_dir", directory)
-    except (AttributeError, KeyError, ValueError):
-        return False
-    for flag, value in (
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-    ):
-        try:
-            jax.config.update(flag, value)
-        except (AttributeError, KeyError, ValueError):
-            pass
-    return True
-
-
-def register_monitoring_listener(callback) -> bool:
-    """jax.monitoring.register_event_listener where available (the event
-    stream the persistent-cache hit/miss counters ride on); returns False
-    on jax builds without it — counters then just stay 0."""
-    mon = getattr(jax, "monitoring", None)
-    if mon is None or not hasattr(mon, "register_event_listener"):
-        return False
-    mon.register_event_listener(callback)
-    return True
+def make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the compiler places whatever the
+    step does not pin with ``shard_map`` specs."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )

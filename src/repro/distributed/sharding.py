@@ -67,9 +67,7 @@ def mesh_context(mesh: Mesh, rules: Optional[Dict[str, Tuple[str, ...]]] = None)
     prev = getattr(_local, "ctx", None)
     _local.ctx = (mesh, rules or LOGICAL_RULES)
     try:
-        from ..compat import activate_mesh
-
-        with activate_mesh(mesh):
+        with jax.set_mesh(mesh):
             yield mesh
     finally:
         _local.ctx = prev

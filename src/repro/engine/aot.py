@@ -4,12 +4,16 @@ The step caches in ``engine/runner.py`` and ``train/trainer.py`` make
 compiles-per-*process* the invariant (one per geometry).  This module
 extends that to compiles-per-*cluster*:
 
-  * ``enable_persistent_cache(dir)`` points JAX's persistent compilation
-    cache at a directory (thresholds zeroed so every executable persists,
-    including the small CPU-backend steps this repro's tests run).  Any
-    later ``jit`` — or AOT ``lower().compile()`` — that re-derives an
-    already-cached computation deserializes the executable instead of
-    invoking XLA.
+  * ``enable_persistent_cache()`` turns on JAX's persistent compilation
+    cache (thresholds zeroed so every executable persists, including the
+    small CPU-backend steps this repro's tests run).  Any later ``jit`` —
+    or AOT ``lower().compile()`` — that re-derives an already-cached
+    computation deserializes the executable instead of invoking XLA.  The
+    cache has one home: ``$JAX_COMPILATION_CACHE_DIR`` when that is set
+    (jax reads it itself, and nothing here overrides it), else the fixed,
+    git-ignored ``<checkout>/.cache/jax``.  A fixed path matters because
+    the path is part of what the cache is keyed on: a directory that moves
+    never hits.
   * ``xla_cache_counters()`` counts *actual* XLA compiles vs disk
     deserializations via ``jax.monitoring`` events, which is how the
     cross-process tests assert "0 XLA compiles" in a warm process — the
@@ -20,9 +24,8 @@ extends that to compiles-per-*cluster*:
     ``ShapeDtypeStruct``s and compiled ahead of time, so the first real
     batch runs a ready executable.
 
-``Session(store=...)`` (repro.api) enables the persistent cache under the
-artifact store root by default, so executables and artifacts share one
-warm directory.
+``Session(store=...)`` (repro.api) enables the persistent cache by
+default, next to its artifact store.
 """
 from __future__ import annotations
 
@@ -31,9 +34,8 @@ from typing import Any, Dict, Optional
 
 import jax
 
-from ..compat import enable_compilation_cache_flags, register_monitoring_listener
-
 __all__ = [
+    "DEFAULT_CACHE_DIR",
     "enable_persistent_cache",
     "persistent_cache_status",
     "xla_cache_counters",
@@ -50,11 +52,11 @@ _EVT_MISSES = "/jax/compilation_cache/cache_misses"
 
 _COUNTERS: Dict[str, int] = {"requests": 0, "hits": 0, "misses": 0}
 _LISTENING = False
-_ENABLED_DIR: Optional[str] = None
 
-# enable() honours this env var when no directory is passed — how
-# subprocess tests and CI point every process at one shared cache
-_ENV_DIR = "REPRO_COMPILE_CACHE"
+# where the cache lives when $JAX_COMPILATION_CACHE_DIR is not set
+DEFAULT_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..", ".cache", "jax"
+))
 
 
 def _listener(event: str, **kwargs) -> None:
@@ -66,26 +68,20 @@ def _listener(event: str, **kwargs) -> None:
         _COUNTERS["misses"] += 1
 
 
-def enable_persistent_cache(directory: Optional[str] = None) -> str:
-    """Turn on the JAX persistent compilation cache at ``directory``
-    (default: ``$REPRO_COMPILE_CACHE`` or ``~/.cache/repro/xla``) and
-    start counting hit/miss events.  Idempotent; re-enabling with a
-    different directory repoints the cache.  Returns the directory."""
-    global _LISTENING, _ENABLED_DIR
-    if directory is None:
-        directory = os.environ.get(_ENV_DIR) or os.path.join(
-            os.path.expanduser("~"), ".cache", "repro", "xla"
-        )
-    directory = os.path.abspath(os.path.expanduser(directory))
-    os.makedirs(directory, exist_ok=True)
-    # flag names drifted across jax 0.4.x; the compat shim zeroes the
-    # persistence thresholds where they exist and degrades to a no-op on
-    # builds with no persistent cache at all (callers still run, cold)
-    enable_compilation_cache_flags(directory)
+def enable_persistent_cache() -> str:
+    """Turn on the JAX persistent compilation cache and start counting
+    hit/miss events.  The directory is ``$JAX_COMPILATION_CACHE_DIR`` when
+    set, else ``DEFAULT_CACHE_DIR``.  Idempotent; returns the directory."""
+    global _LISTENING
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     if not _LISTENING:
-        _LISTENING = register_monitoring_listener(_listener)
-    _ENABLED_DIR = directory
-    return directory
+        jax.monitoring.register_event_listener(_listener)
+        _LISTENING = True
+    return jax.config.jax_compilation_cache_dir
 
 
 def xla_cache_counters() -> Dict[str, int]:

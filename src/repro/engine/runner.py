@@ -32,7 +32,8 @@ Design points (each measured by ``benchmarks/bench_timing.py``):
     NumPy feature pre-pass with the device scan kernels in
     ``kernels/features/``: raw trace columns are shipped once, features are
     extracted on device, and batches become device-side slices
-    (bit-identical to the NumPy path; see docs/engine.md).
+    (bit-identical to the NumPy path on the CPU backend; see
+    docs/engine.md and, for the TPU, docs/kernels.md).
     ``feature_backend="fused"`` goes further: one megakernel launch per
     batch (``kernels/fused/``) produces the model inputs directly from the
     raw columns with the scan state carried across batches — features only
@@ -236,12 +237,13 @@ class EngineConfig:
     # "pallas": staged device extraction — the trace's int32/bool columns
     # are shipped once, the Pallas scan kernels compute brhist/memdist on
     # device, and batches are device-side slices of the materialized
-    # feature arrays (bit-identical to the NumPy path; falls back to it
-    # when addresses exceed the int32-exact window).
+    # feature arrays.
     # "fused": one megakernel launch per batch (kernels/fused/) produces
     # the model inputs straight from the raw columns, scan state carried
-    # across batches — no O(trace) feature materialization (bit-identical;
-    # same NumPy fallback).
+    # across batches — no O(trace) feature materialization.
+    # Both device backends match the NumPy path exactly on the CPU backend
+    # (docs/kernels.md says what holds on the TPU), and both raise
+    # ValueError on traces with addresses outside |addr| < 2^30.
     feature_backend: str = "numpy"
     feature_chunk: int = 512     # Pallas scan grid chunk (trace positions)
     # "fp32": exact float path.  "int8": W8A8 quantized forward — per-
@@ -651,7 +653,9 @@ class StreamingEngine:
             "seen": jnp.zeros((), jnp.int32),
             "total": jnp.asarray(nw, jnp.int32),
         }
-        return carry
+        # placed where the step's replicated output carry lives, so the
+        # first call and every later one trace to the same program
+        return self.plan.replicate(carry)
 
     def step_entry_for(self, n: int) -> _CachedStep:
         """The cached step entry ``simulate`` will use for a trace of
@@ -844,14 +848,16 @@ class StreamingEngine:
                 trace_columns,
             )
 
+            # raises when addresses leave the int32-exact window: the
+            # device backend the caller asked for never silently becomes
+            # the NumPy one
             cols = trace_columns(func_trace, cfg.features)
-            if cols is not None:  # addresses fit the int32-exact window
-                if self.ecfg.feature_backend == "fused":
-                    fused_batches = self._fused_batches(cols, w_eff, count)
-                else:
-                    dev_arrays = device_feature_arrays(
-                        cols, cfg.features, chunk=self.ecfg.feature_chunk
-                    )
+            if self.ecfg.feature_backend == "fused":
+                fused_batches = self._fused_batches(cols, w_eff, count)
+            else:
+                dev_arrays = device_feature_arrays(
+                    cols, cfg.features, chunk=self.ecfg.feature_chunk
+                )
         if fs is None and dev_arrays is None and fused_batches is None:
             fs = extract_features(func_trace, cfg.features, with_labels=False)
 

@@ -29,6 +29,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_kernel", "flash_attention_pallas"]
 
@@ -207,7 +208,7 @@ def flash_attention_pallas(
             pltpu_scratch((bq, 1)),
             pltpu_scratch((bq, Dv)),
         ],
-        compiler_params=dict(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         )
         if not interpret
@@ -219,6 +220,4 @@ def flash_attention_pallas(
 
 def pltpu_scratch(shape):
     """VMEM f32 scratch allocation (TPU memory space)."""
-    from jax.experimental.pallas import tpu as pltpu
-
     return pltpu.VMEM(shape, jnp.float32)

@@ -2,9 +2,11 @@
 
 On TPU the kernels lower natively through Mosaic; everywhere else they run
 under ``interpret=True`` so CPU CI exercises the same programs.  The
-contract — enforced by ``tests/test_feature_kernels.py`` — is that the
-device extraction is **bit-identical** to the NumPy specification
-(``core.features.extract_features`` / ``extract_features_reference``):
+contract — enforced on the CPU backend by ``tests/test_feature_kernels.py``
+— is that the device extraction is **bit-identical** to the NumPy
+specification (``core.features.extract_features`` /
+``extract_features_reference``); on the TPU everything but the signed-log
+values stays bit-exact (docs/kernels.md "Exactness"):
 
   * branch-history rows are copies of {-1, 0, +1} values (exact);
   * memory-distance deltas are int32 subtractions (exact) converted to
@@ -15,8 +17,9 @@ device extraction is **bit-identical** to the NumPy specification
     into fma (one rounding instead of two) and break bit-equality.
 
 ``trace_columns`` does the cheap host-side prep (bucket hash on the int64
-pc, int32 address narrowing) and returns None when addresses fall outside
-the int32-exact window, in which case callers fall back to the NumPy path.
+pc, int32 address narrowing) and raises ``ValueError`` when addresses fall
+outside the int32-exact window: a device feature path never silently
+becomes the NumPy one.
 """
 from __future__ import annotations
 
@@ -87,19 +90,33 @@ def signed_log_device(d: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(d < 0, -r, r)
 
 
+def chunked_column(v: jnp.ndarray, chunk: int) -> jnp.ndarray:
+    """(n,) per-position column -> zero-padded (nc, 1, chunk), the layout
+    of the kernels' SMEM column blocks (pad rows are non-branch, non-mem:
+    the scan state passes through them untouched)."""
+    n = v.shape[0]
+    nc = max(1, -(-n // chunk))
+    return jnp.pad(v, (0, nc * chunk - n)).reshape(nc, 1, chunk)
+
+
+def kernel_chunk(chunk: int) -> int:
+    """The grid chunk rounded up to whole 8-row sublane tiles, as Mosaic
+    wants the (chunk, F) output blocks; results do not depend on it."""
+    return -(-chunk // 8) * 8
+
+
 @functools.partial(
     jax.jit, static_argnames=("n_buckets", "n_queue", "chunk", "interpret")
 )
 def _branch_history_padded(bucket, outcome, *, n_buckets, n_queue, chunk, interpret):
-    n = bucket.shape[0]
-    nc = max(1, -(-n // chunk))
-    pad = nc * chunk - n
-    b2 = jnp.pad(bucket, (0, pad)).reshape(nc, chunk)
-    o2 = jnp.pad(outcome, (0, pad)).reshape(nc, chunk)  # pad rows: non-branch
     out = branch_history_pallas(
-        b2, o2, n_buckets=n_buckets, n_queue=n_queue, interpret=interpret
+        chunked_column(bucket, chunk),
+        chunked_column(outcome, chunk),
+        n_buckets=n_buckets,
+        n_queue=n_queue,
+        interpret=interpret,
     )
-    return out.reshape(nc * chunk, n_queue)[:n]
+    return out[: bucket.shape[0]]
 
 
 def branch_history_scan(
@@ -123,20 +140,20 @@ def branch_history_scan(
         outcome,
         n_buckets=n_buckets,
         n_queue=n_queue,
-        chunk=chunk,
+        chunk=kernel_chunk(chunk),
         interpret=interpret,
     )
 
 
 @functools.partial(jax.jit, static_argnames=("n_mem", "chunk", "interpret"))
 def _memdist_padded(addr, mem, *, n_mem, chunk, interpret):
-    n = addr.shape[0]
-    nc = max(1, -(-n // chunk))
-    pad = nc * chunk - n
-    a2 = jnp.pad(addr, (0, pad)).reshape(nc, chunk)
-    m2 = jnp.pad(mem, (0, pad)).reshape(nc, chunk)  # pad rows: non-mem
-    out = memdist_delta_pallas(a2, m2, n_mem=n_mem, interpret=interpret)
-    return out.reshape(nc * chunk, n_mem)[:n]
+    out = memdist_delta_pallas(
+        chunked_column(addr, chunk),
+        chunked_column(mem, chunk),
+        n_mem=n_mem,
+        interpret=interpret,
+    )
+    return out[: addr.shape[0]]
 
 
 def memdist_delta_scan(
@@ -155,23 +172,26 @@ def memdist_delta_scan(
     if addr.shape[0] == 0:
         return jnp.zeros((0, n_mem), jnp.float32)
     return _memdist_padded(
-        addr, mem, n_mem=n_mem, chunk=chunk, interpret=interpret
+        addr, mem, n_mem=n_mem, chunk=kernel_chunk(chunk), interpret=interpret
     )
 
 
-def trace_columns(
-    trace: np.ndarray, cfg: FeatureConfig
-) -> Optional[Dict[str, np.ndarray]]:
+def trace_columns(trace: np.ndarray, cfg: FeatureConfig) -> Dict[str, np.ndarray]:
     """Host-side prep of the device extraction inputs.
 
     Bucket hashing runs on the host so the int64 pc is handled exactly;
-    everything shipped to the device is int32/float32.  Returns None when
-    addresses exceed the int32-exact window (|addr| >= 2^30) — the caller
-    must then fall back to the NumPy backend.
+    everything shipped to the device is int32/float32.  Raises ValueError
+    when addresses exceed the int32-exact window (|addr| >= 2^30): the
+    device deltas would be inexact, and only the NumPy backend
+    (``extract_features``) handles such traces.
     """
     addr = trace["addr"]
     if len(addr) and int(np.abs(addr).max()) >= ADDR_EXACT_LIMIT:
-        return None
+        raise ValueError(
+            f"trace addresses exceed |addr| < 2^30 (= {ADDR_EXACT_LIMIT}); "
+            "int32 device deltas would be inexact — use the NumPy feature "
+            "path (extract_features / feature_backend='numpy')"
+        )
     # Minimal payload (~28 B/instr): branch outcomes and the mem mask are
     # derived on device from the bool columns instead of being shipped as
     # widened duplicates.
@@ -275,11 +295,6 @@ def extract_features_device(
     Pallas kernels; raises ValueError when addresses exceed the int32-exact
     window (use the NumPy extractor there)."""
     cols = trace_columns(trace, cfg)
-    if cols is None:
-        raise ValueError(
-            f"trace addresses exceed |addr| < 2^30 (= {ADDR_EXACT_LIMIT}); "
-            "int32 device deltas would be inexact — use extract_features"
-        )
     arrays = device_feature_arrays(cols, cfg, chunk=chunk, interpret=interpret)
     return FeatureSet(
         opcode=np.asarray(arrays["opcode"]),

@@ -8,29 +8,32 @@ model's embedding stack reads it back.  At simulation batch sizes that
 round-trip is the bandwidth bill (see docs/kernels.md).
 
 This kernel collapses the three in-jit stages into ONE ``pallas_call`` whose
-grid walks trace chunks sequentially ("arbitrary" dimension semantics), with
-every recurrent structure carried in VMEM/SMEM scratch:
+grid walks trace chunks sequentially ("arbitrary" dimension semantics):
 
-  * the (N_b, N_q) per-bucket branch-outcome table (VMEM),
-  * the N_m-deep int32 address queue + SMEM fill counter,
+  * the vectorized per-instruction work (register bitmap via iota compare,
+    the 5-wide flag stack) runs per chunk on a (chunk, 8) int32 VMEM block
+    of the raw columns;
+  * the two sequential scans walk the chunk one position at a time, reading
+    their scalars from SMEM column blocks and pushing into the carried
+    (N_b, N_q) branch-outcome table and the N_m-deep address queue — the
+    same push steps as the staged kernels (``kernels/features/kernel``).
 
-and the vectorized per-instruction work (register bitmap via iota compare,
-the 5-wide flag stack) done per chunk in the same kernel body.  Feature rows
-exist only at batch granularity: the caller (``ops.FusedExtractor``) slices
-one batch of raw columns, runs this kernel, and feeds the result straight to
-the engine's jitted step — the O(trace) HBM FeatureSet never exists.
+Feature rows exist only at batch granularity: the caller
+(``ops.FusedExtractor``) slices one batch of raw columns, runs this kernel,
+and feeds the result straight to the engine's jitted step — the O(trace)
+HBM FeatureSet never exists.
 
-The scan state is additionally threaded ACROSS calls: the carry table/queue
-enter as inputs and leave as outputs, loaded into scratch at the first grid
-step and flushed on every step (same-block output revisiting — last write
-wins), so batch k+1 continues exactly where batch k stopped.  That is what
-lets a whole trace stream through fixed-size megakernel launches and stay
-bit-identical to one monolithic scan.
+The scan state is threaded ACROSS calls: the carry table/queue enter as
+inputs and leave as outputs.  The state outputs map to the same block on
+every grid step, so they stay resident in VMEM for the whole grid; step 0
+copies the incoming carry into them and every later step updates them in
+place.  Batch k+1 thus continues exactly where batch k stopped, which is
+what lets a whole trace stream through fixed-size launches and equal one
+monolithic scan.
 
 Memory-distance deltas are RAW int32 subtractions cast to float32, exactly
-like the staged kernel: the signed-log compression must run eagerly outside
-any compiled program (XLA fma contraction of ``a*b + c`` breaks bitwise
-equality with the NumPy backend — see ``kernels/features/ops``).
+like the staged kernel: the signed-log compression runs eagerly outside any
+compiled program (``kernels/features/ops``).
 
 Off-TPU the same program runs under ``interpret=True`` (CPU CI).
 """
@@ -43,141 +46,84 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["fused_feature_kernel", "fused_feature_pallas"]
+from ..features.kernel import SEQUENTIAL, push_branch, push_mem, smem_column
+
+__all__ = ["VCOLS", "fused_feature_kernel", "fused_feature_pallas"]
+
+# lanes of the (chunk, 8) per-instruction VMEM block, in order
+VCOLS = ("opcode", "dst", "src1", "src2", "is_branch", "taken", "is_mem", "is_store")
 
 
 def fused_feature_kernel(
-    bucket_ref,    # (1, chunk) int32 — (pc >> 2) % N_b
-    addr_ref,      # (1, chunk) int32 — byte address (|addr| < 2^30)
-    opcode_ref,    # (1, chunk) int32
-    dst_ref,       # (1, chunk) int32 — destination register id
-    src1_ref,      # (1, chunk) int32
-    src2_ref,      # (1, chunk) int32
-    branch_ref,    # (1, chunk) int32 — 1 on branches
-    taken_ref,     # (1, chunk) int32 — 1 on taken branches
-    mem_ref,       # (1, chunk) int32 — 1 on memory ops
-    store_ref,     # (1, chunk) int32 — 1 on stores
-    table_in_ref,  # (n_buckets, n_queue) f32 — incoming branch-table carry
-    mq_in_ref,     # (1, n_mem + 1) int32 — incoming queue slots + fill count
-    regbits_ref,   # out (1, chunk, num_regs) f32
-    flags_ref,     # out (1, chunk, n_flags) f32
-    brhist_ref,    # out (1, chunk, n_queue) f32
-    memdist_ref,   # out (1, chunk, n_mem) f32 — RAW deltas (signed-log later)
-    table_out_ref, # out (n_buckets, n_queue) f32 — outgoing carry
-    mq_out_ref,    # out (1, n_mem + 1) int32 — outgoing carry
-    table_scr,     # VMEM (n_buckets, n_queue) f32
-    queue_scr,     # VMEM (1, n_mem) int32
-    fill_scr,      # SMEM (1,) int32
+    bucket_ref,    # SMEM (1, chunk) int32 — (pc >> 2) % N_b
+    addr_ref,      # SMEM (1, chunk) int32 — byte address (|addr| < 2^30)
+    outcome_ref,   # SMEM (1, chunk) f32 — +1 taken / -1 not-taken / 0
+    mem_ref,       # SMEM (1, chunk) int32 — 1 on memory ops
+    cols_ref,      # (chunk, 8) int32 — the VCOLS columns
+    table_in_ref,  # (n_buckets, lanes(n_queue)) f32 — incoming carry
+    queue_in_ref,  # (2, lanes(n_mem)) int32 — incoming carry
+    regbits_ref,   # out (chunk, num_regs) f32
+    flags_ref,     # out (chunk, n_flags) f32
+    brhist_ref,    # out (chunk, n_queue) f32
+    memdist_ref,   # out (chunk, n_mem) f32 — RAW deltas (signed-log later)
+    table_ref,     # out (n_buckets, lanes(n_queue)) f32 — resident carry
+    queue_ref,     # out (2, lanes(n_mem)) int32 — resident carry
     *,
     chunk: int,
+    n_queue: int,
     n_mem: int,
-    num_regs: int,
     fp_ops: Tuple[int, ...],
 ):
-    ci = pl.program_id(0)
-
-    @pl.when(ci == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _load_state():
-        table_scr[...] = table_in_ref[...]
-        queue_scr[...] = mq_in_ref[:, :n_mem]
-        fill_scr[0] = mq_in_ref[0, n_mem]
-
-    bucket = bucket_ref[0, :]
-    addr = addr_ref[0, :]
-    br = branch_ref[0, :]
-    tk = taken_ref[0, :]
-    mm = mem_ref[0, :]
+        table_ref[...] = table_in_ref[...]
+        queue_ref[...] = queue_in_ref[...]
 
     # ---- per-instruction features: vectorized over the whole chunk ----
     # (exact integer/bool -> {0.0, 1.0} casts; any compute path is bitwise
     # identical to the staged _per_instruction_device jit)
-    reg = jax.lax.broadcasted_iota(jnp.int32, (chunk, num_regs), 1)
-    dst = dst_ref[0, :][:, None]
-    s1 = src1_ref[0, :][:, None]
-    s2 = src2_ref[0, :][:, None]
-    regbits_ref[0] = ((reg == dst) | (reg == s1) | (reg == s2)).astype(
-        jnp.float32
-    )
-    op = opcode_ref[0, :]
+    v = cols_ref[...]
+    op, dst, s1, s2, br, tk, mm, st = (v[:, j : j + 1] for j in range(len(VCOLS)))
+    reg = jax.lax.broadcasted_iota(jnp.int32, regbits_ref.shape, 1)
+    regbits_ref[...] = ((reg == dst) | (reg == s1) | (reg == s2)).astype(jnp.float32)
     is_fp = op == fp_ops[0]
     for c in fp_ops[1:]:
         is_fp |= op == c
-    flags_ref[0] = jnp.stack(
-        [br != 0, tk != 0, mm != 0, store_ref[0, :] != 0, is_fp], axis=1
-    ).astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, flags_ref.shape, 1)
+    flags = jnp.zeros(flags_ref.shape, jnp.bool_)
+    for k, bit in enumerate((br != 0, tk != 0, mm != 0, st != 0, is_fp)):
+        flags |= (lane == k) & bit
+    flags_ref[...] = flags.astype(jnp.float32)
 
     # ---- the two sequential scans, interleaved in one walk ----
-    outcome = jnp.where(
-        br != 0,
-        jnp.where(tk != 0, jnp.float32(1.0), jnp.float32(-1.0)),
-        jnp.float32(0.0),
-    )
-    slot = jax.lax.broadcasted_iota(jnp.int32, (1, n_mem), 1)
+    brhist_ref[...] = jnp.zeros_like(brhist_ref)
+    memdist_ref[...] = jnp.zeros_like(memdist_ref)
 
     def body(i, carry):
-        # branch history: read the bucket's queue, push most-recent-first
-        b = bucket[i]
-        o = outcome[i]
-        is_br = o != 0.0
-        row = table_scr[pl.ds(b, 1), :]                      # (1, n_queue)
-        brhist_ref[0, pl.ds(i, 1), :] = jnp.where(is_br, row, 0.0)
-        pushed = jnp.concatenate(
-            [jnp.full((1, 1), o, row.dtype), row[:, :-1]], axis=1
-        )
-        table_scr[pl.ds(b, 1), :] = jnp.where(is_br, pushed, row)
-        # memory distance: raw deltas against the last n_mem addresses
-        a = addr[i]
-        is_mem = mm[i] != 0
-        q = queue_scr[...]                                   # (1, n_mem)
-        filled = fill_scr[0]
-        valid = (slot < filled) & is_mem
-        delta = (a - q).astype(jnp.float32)                   # exact int32 sub
-        memdist_ref[0, pl.ds(i, 1), :] = jnp.where(valid, delta, 0.0)
-        pushed_q = jnp.concatenate(
-            [jnp.full((1, 1), a, q.dtype), q[:, :-1]], axis=1
-        )
-        queue_scr[...] = jnp.where(is_mem, pushed_q, q)
-        fill_scr[0] = jnp.where(
-            is_mem, jnp.minimum(filled + 1, n_mem), filled
-        )
+        o = outcome_ref[0, i]
+
+        @pl.when(o != 0.0)
+        def _():
+            push_branch(table_ref, brhist_ref, i, bucket_ref[0, i], o, n_queue)
+
+        @pl.when(mem_ref[0, i] != 0)
+        def _():
+            push_mem(queue_ref, memdist_ref, i, addr_ref[0, i], n_mem)
+
         return carry
 
     jax.lax.fori_loop(0, chunk, body, 0)
 
-    # flush the carry every grid step (the state outputs map to the same
-    # block on every step, so the last write — the final state — wins)
-    table_out_ref[...] = table_scr[...]
-    mq_out_ref[:, :n_mem] = queue_scr[...]
-    mq_out_ref[:, n_mem:] = jnp.full((1, 1), fill_scr[0], jnp.int32)
-
-
-def _vmem(shape, dtype=jnp.float32):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
-
-
-def _smem(shape, dtype=jnp.int32):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.SMEM(shape, dtype)
-
 
 def fused_feature_pallas(
-    bucket: jnp.ndarray,   # (nc, chunk) int32
-    addr: jnp.ndarray,     # (nc, chunk) int32
-    opcode: jnp.ndarray,   # (nc, chunk) int32
-    dst: jnp.ndarray,      # (nc, chunk) int32
-    src1: jnp.ndarray,     # (nc, chunk) int32
-    src2: jnp.ndarray,     # (nc, chunk) int32
-    branch: jnp.ndarray,   # (nc, chunk) int32 0/1
-    taken: jnp.ndarray,    # (nc, chunk) int32 0/1
-    mem: jnp.ndarray,      # (nc, chunk) int32 0/1
-    store: jnp.ndarray,    # (nc, chunk) int32 0/1
-    table: jnp.ndarray,    # (n_buckets, n_queue) f32 carry in
-    mq: jnp.ndarray,       # (1, n_mem + 1) int32 carry in
+    bucket: jnp.ndarray,   # (nc, 1, chunk) int32
+    addr: jnp.ndarray,     # (nc, 1, chunk) int32
+    outcome: jnp.ndarray,  # (nc, 1, chunk) f32
+    mem: jnp.ndarray,      # (nc, 1, chunk) int32 0/1
+    cols: jnp.ndarray,     # (nc * chunk, 8) int32 — VCOLS
+    table: jnp.ndarray,    # (n_buckets, lanes(n_queue)) f32 carry in
+    queue: jnp.ndarray,    # (2, lanes(n_mem)) int32 carry in
     *,
-    n_buckets: int,
     n_queue: int,
     n_mem: int,
     n_flags: int,
@@ -186,46 +132,41 @@ def fused_feature_pallas(
     interpret: bool = False,
 ):
     """One fused pass over ``nc * chunk`` trace positions.  Returns
-    ``(regbits, flags, brhist, memdist_raw, table_out, mq_out)`` — the last
-    two being the scan carry to thread into the next call."""
-    nc, chunk = bucket.shape
+    ``(regbits, flags, brhist, memdist_raw, table_out, queue_out)`` — the
+    last two being the scan carry to thread into the next call."""
+    nc, _, chunk = bucket.shape
     kernel = functools.partial(
         fused_feature_kernel,
         chunk=chunk,
+        n_queue=n_queue,
         n_mem=n_mem,
-        num_regs=num_regs,
         fp_ops=fp_ops,
     )
-    col = pl.BlockSpec((1, chunk), lambda c: (c, 0))
-    table_spec = pl.BlockSpec((n_buckets, n_queue), lambda c: (0, 0))
-    mq_spec = pl.BlockSpec((1, n_mem + 1), lambda c: (0, 0))
+
+    def rows(width):
+        return pl.BlockSpec((chunk, width), lambda c: (c, 0))
+
+    def whole(a):
+        return pl.BlockSpec(a.shape, lambda c: (0, 0))
+
+    n = nc * chunk
     return pl.pallas_call(
         kernel,
         grid=(nc,),
-        in_specs=[col] * 10 + [table_spec, mq_spec],
+        in_specs=[smem_column(chunk)] * 4
+        + [rows(cols.shape[1]), whole(table), whole(queue)],
         out_specs=[
-            pl.BlockSpec((1, chunk, num_regs), lambda c: (c, 0, 0)),
-            pl.BlockSpec((1, chunk, n_flags), lambda c: (c, 0, 0)),
-            pl.BlockSpec((1, chunk, n_queue), lambda c: (c, 0, 0)),
-            pl.BlockSpec((1, chunk, n_mem), lambda c: (c, 0, 0)),
-            table_spec,
-            mq_spec,
+            rows(num_regs), rows(n_flags), rows(n_queue), rows(n_mem),
+            whole(table), whole(queue),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nc, chunk, num_regs), jnp.float32),
-            jax.ShapeDtypeStruct((nc, chunk, n_flags), jnp.float32),
-            jax.ShapeDtypeStruct((nc, chunk, n_queue), jnp.float32),
-            jax.ShapeDtypeStruct((nc, chunk, n_mem), jnp.float32),
-            jax.ShapeDtypeStruct((n_buckets, n_queue), jnp.float32),
-            jax.ShapeDtypeStruct((1, n_mem + 1), jnp.int32),
+            jax.ShapeDtypeStruct((n, num_regs), jnp.float32),
+            jax.ShapeDtypeStruct((n, n_flags), jnp.float32),
+            jax.ShapeDtypeStruct((n, n_queue), jnp.float32),
+            jax.ShapeDtypeStruct((n, n_mem), jnp.float32),
+            jax.ShapeDtypeStruct(table.shape, table.dtype),
+            jax.ShapeDtypeStruct(queue.shape, queue.dtype),
         ],
-        scratch_shapes=[
-            _vmem((n_buckets, n_queue)),
-            _vmem((1, n_mem), jnp.int32),
-            _smem((1,), jnp.int32),
-        ],
-        compiler_params=dict(dimension_semantics=("arbitrary",))
-        if not interpret
-        else None,
+        compiler_params=SEQUENTIAL,
         interpret=interpret,
-    )(bucket, addr, opcode, dst, src1, src2, branch, taken, mem, store, table, mq)
+    )(bucket, addr, outcome, mem, cols, table, queue)

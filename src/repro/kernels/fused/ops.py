@@ -1,9 +1,10 @@
 """Public wrappers for the fused feature-extraction megakernel.
 
 The contract mirrors ``kernels/features/ops`` — and is enforced by
-``tests/test_fused.py``: the fused pipeline is **bit-identical** to both the
-staged Pallas backend and the NumPy specification.  That falls out of three
-invariants:
+``tests/test_fused.py`` on the CPU backend: the fused pipeline is
+**bit-identical** to both the staged Pallas backend and the NumPy
+specification (on the TPU, see docs/kernels.md "Exactness").  That falls
+out of three invariants:
 
   * regbits/flags/brhist are exact integer/bool -> {0.0, 1.0, ±1.0} values —
     any compute path produces the same bits;
@@ -34,8 +35,14 @@ import numpy as np
 from ...compat import on_tpu
 from ...core.features import FeatureConfig
 from ...uarch.isa import NUM_REGS, Op
-from ..features.ops import DEFAULT_CHUNK, signed_log_device
-from .kernel import fused_feature_pallas
+from ..features.kernel import lanes
+from ..features.ops import (
+    DEFAULT_CHUNK,
+    chunked_column,
+    kernel_chunk,
+    signed_log_device,
+)
+from .kernel import VCOLS, fused_feature_pallas
 
 __all__ = [
     "FusedExtractor",
@@ -46,51 +53,43 @@ __all__ = [
 # opcodes whose instructions set the is_fp flag (static in the kernel)
 _FP_OPS = (int(Op.FALU), int(Op.FMUL), int(Op.FDIV))
 
-# the raw trace columns a fused pass consumes, in kernel argument order
-_COLUMN_KEYS = (
-    "bucket", "addr", "opcode", "dst", "src1", "src2",
-    "is_branch", "taken", "is_mem", "is_store",
-)
+# the raw trace columns a fused pass consumes, in argument order
+_COLUMN_KEYS = ("bucket", "addr") + VCOLS
 
 
 def init_fused_state(cfg: FeatureConfig) -> Dict[str, jnp.ndarray]:
-    """The scan carry threaded across megakernel calls: the (N_b, N_q)
-    branch-outcome table and the address queue + fill counter packed into
-    one int32 row (``mq[0, :n_mem]`` = queue, ``mq[0, n_mem]`` = fill)."""
+    """The scan carry threaded across megakernel calls, in the kernel's
+    lane-padded layout: the (N_b, lanes(N_q)) branch-outcome table, and the
+    address queue as a (2, lanes(N_m)) int32 block — row 0 the addresses,
+    most recent first, row 1 which slots are filled.  Lanes past N_q / N_m
+    are padding that never reaches a feature."""
     return {
-        "table": jnp.zeros((cfg.n_buckets, cfg.n_queue), jnp.float32),
-        "mq": jnp.zeros((1, cfg.n_mem + 1), jnp.int32),
+        "table": jnp.zeros((cfg.n_buckets, lanes(cfg.n_queue)), jnp.float32),
+        "queue": jnp.zeros((2, lanes(cfg.n_mem)), jnp.int32),
     }
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=(
-        "n_buckets", "n_queue", "n_mem", "n_flags", "chunk", "interpret"
-    ),
+    jax.jit, static_argnames=("n_queue", "n_mem", "n_flags", "chunk", "interpret")
 )
-def _fused_padded(
-    bucket, addr, opcode, dst, src1, src2,
-    is_branch, taken, is_mem, is_store,
-    table, mq,
-    *,
-    n_buckets, n_queue, n_mem, n_flags, chunk, interpret,
-):
-    n = bucket.shape[0]
+def _fused_padded(cols, table, queue, *, n_queue, n_mem, n_flags, chunk, interpret):
+    """``cols``: the ``_COLUMN_KEYS`` columns, each (n,)."""
+    n = cols["bucket"].shape[0]
+    outcome = jnp.where(
+        cols["is_branch"],
+        jnp.where(cols["taken"], jnp.float32(1.0), jnp.float32(-1.0)),
+        jnp.float32(0.0),
+    )
+    per_instr = jnp.stack([cols[k].astype(jnp.int32) for k in VCOLS], axis=1)
     nc = max(1, -(-n // chunk))
-    pad = nc * chunk - n
-
-    def prep(v):
-        # pad rows are all-zero: non-branch, non-mem — the carried scan
-        # state passes through them untouched
-        return jnp.pad(v.astype(jnp.int32), (0, pad)).reshape(nc, chunk)
-
-    regbits, flags, brhist, memdist, table_out, mq_out = fused_feature_pallas(
-        prep(bucket), prep(addr), prep(opcode),
-        prep(dst), prep(src1), prep(src2),
-        prep(is_branch), prep(taken), prep(is_mem), prep(is_store),
-        table, mq,
-        n_buckets=n_buckets,
+    regbits, flags, brhist, memdist, table, queue = fused_feature_pallas(
+        chunked_column(cols["bucket"].astype(jnp.int32), chunk),
+        chunked_column(cols["addr"].astype(jnp.int32), chunk),
+        chunked_column(outcome, chunk),
+        chunked_column(cols["is_mem"].astype(jnp.int32), chunk),
+        jnp.pad(per_instr, ((0, nc * chunk - n), (0, 0))),
+        table,
+        queue,
         n_queue=n_queue,
         n_mem=n_mem,
         n_flags=n_flags,
@@ -98,15 +97,7 @@ def _fused_padded(
         fp_ops=_FP_OPS,
         interpret=interpret,
     )
-    m = nc * chunk
-    return (
-        regbits.reshape(m, NUM_REGS)[:n],
-        flags.reshape(m, n_flags)[:n],
-        brhist.reshape(m, n_queue)[:n],
-        memdist.reshape(m, n_mem)[:n],
-        table_out,
-        mq_out,
-    )
+    return regbits[:n], flags[:n], brhist[:n], memdist[:n], table, queue
 
 
 # tao: hot
@@ -128,24 +119,14 @@ def fused_feature_columns(
     """
     if interpret is None:
         interpret = not on_tpu()
-    regbits, flags, brhist, raw, table, mq = _fused_padded(
-        jnp.asarray(cols["bucket"]),
-        jnp.asarray(cols["addr"]),
-        jnp.asarray(cols["opcode"]),
-        jnp.asarray(cols["dst"]),
-        jnp.asarray(cols["src1"]),
-        jnp.asarray(cols["src2"]),
-        jnp.asarray(cols["is_branch"]),
-        jnp.asarray(cols["taken"]),
-        jnp.asarray(cols["is_mem"]),
-        jnp.asarray(cols["is_store"]),
+    regbits, flags, brhist, raw, table, queue = _fused_padded(
+        {k: jnp.asarray(cols[k]) for k in _COLUMN_KEYS},
         state["table"],
-        state["mq"],
-        n_buckets=cfg.n_buckets,
+        state["queue"],
         n_queue=cfg.n_queue,
         n_mem=cfg.n_mem,
         n_flags=cfg.flags_dim,
-        chunk=chunk,
+        chunk=kernel_chunk(chunk),
         interpret=interpret,
     )
     memdist = signed_log_device(raw)  # eager: keeps NumPy bit-equality
@@ -156,7 +137,7 @@ def fused_feature_columns(
         "brhist": brhist,
         "memdist": memdist,
     }
-    return feats, {"table": table, "mq": mq}
+    return feats, {"table": table, "queue": queue}
 
 
 class FusedExtractor:

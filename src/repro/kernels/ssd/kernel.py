@@ -19,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["ssd_kernel", "ssd_pallas"]
 
@@ -107,7 +108,7 @@ def ssd_pallas(
         out_specs=pl.BlockSpec((1, chunk, 1, P), lambda b, h, ci: (b, ci, h, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, H, P), xh.dtype),
         scratch_shapes=[_vmem((N, P))],
-        compiler_params=dict(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
         if not interpret
@@ -118,6 +119,4 @@ def ssd_pallas(
 
 
 def _vmem(shape):
-    from jax.experimental.pallas import tpu as pltpu
-
     return pltpu.VMEM(shape, jnp.float32)

@@ -223,9 +223,7 @@ def model_flops(cfg, meta) -> float:
 def analyze(lowered, compiled, meta, cfg, mesh) -> Dict:
     n_dev = mesh.devices.size
     mem = compiled.memory_analysis()
-    from ..compat import cost_analysis
-
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     h = analyze_hlo(hlo)  # loop-aware dot flops + collective bytes (per device)
 

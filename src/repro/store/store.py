@@ -17,9 +17,6 @@ Layout (all under one root, safe to blow away wholesale):
                                              arr_*.bin (ckpt typed-path
                                              format, template-free)
     <root>/tmp/                              unique staging dirs
-    <root>/xla/                              JAX persistent compilation
-                                             cache (when a Session enables
-                                             it; managed by jax itself)
 
 Concurrency and crash safety: entries are immutable once published.  A put
 stages into ``tmp/<key>-<pid>-<nonce>`` and publishes with one
@@ -135,12 +132,6 @@ class ArtifactStore:
             "stale_pins_swept": 0,
         }
         self._nonce = 0
-
-    @property
-    def xla_cache_dir(self) -> str:
-        """Where a Session points the JAX persistent compilation cache so
-        executables and artifacts travel (and GC) together."""
-        return os.path.join(self.root, "xla")
 
     # ---- paths -----------------------------------------------------------
 

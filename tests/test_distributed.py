@@ -15,9 +15,8 @@ def _run(script: str, timeout=560) -> str:
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     # Force CPU: --xla_force_host_platform_device_count works with it, and
-    # leaving JAX_PLATFORMS unset would probe for a real TPU (libtpu ships in
-    # the image), which hangs on a stale /tmp/libtpu_lockfile after any
-    # killed run.
+    # the test process may already hold the TPU runtime, which a child on
+    # the same chip cannot open.
     env["JAX_PLATFORMS"] = "cpu"
     p = subprocess.run(
         [sys.executable, "-c", script],

@@ -6,7 +6,7 @@ and the signed-log compression runs as an op-per-dispatch jax twin of
 ``core.features.signed_log`` (both sides a fixed chain of individually
 rounded float32 ops).  Covers hash-collision-heavy traces (many PCs per
 bucket), empty-queue boundaries, chunk-boundary geometry, and the int32
-address-window fallback.
+address-window refusal.
 """
 import numpy as np
 import pytest
@@ -196,7 +196,8 @@ def test_device_extraction_matches_vectorized_bitwise():
 def test_trace_columns_rejects_wide_addresses():
     t = _random_trace(16, np.random.default_rng(0))
     t["addr"][3] = ADDR_EXACT_LIMIT  # exactly at the limit -> reject
-    assert trace_columns(t, FeatureConfig()) is None
+    with pytest.raises(ValueError, match="2\\^30"):
+        trace_columns(t, FeatureConfig())
     with pytest.raises(ValueError):
         extract_features_device(t, FeatureConfig(), with_labels=False)
 

@@ -12,7 +12,6 @@ def _compile(fn, *sds):
     return jax.jit(fn).lower(*sds).compile()
 
 
-from repro.compat import cost_analysis as _cost_analysis
 
 
 def test_dot_flops_matches_cost_analysis_loop_free():
@@ -26,7 +25,7 @@ def test_dot_flops_matches_cost_analysis_loop_free():
     ]
     c = _compile(f, *sds)
     ours = analyze_hlo(c.as_text())["dot_flops"]
-    xla = _cost_analysis(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     assert ours == pytest.approx(xla, rel=0.05), (ours, xla)
 
 
@@ -53,7 +52,7 @@ def test_scan_trip_count_folding():
     assert fN == pytest.approx(N * f1, rel=0.05), (f1, fN)
     # and confirm XLA's own analysis UNDER-counts the scan (the reason this
     # module exists) — if XLA ever fixes this, we can drop the custom parse
-    xla_fN = _cost_analysis(cN)["flops"]
+    xla_fN = cN.cost_analysis()["flops"]
     assert xla_fN < fN * 0.5
 
 
@@ -69,9 +68,9 @@ def test_collectives_counted_inside_loops():
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from repro.launch.hloanalysis import analyze_hlo
-    from repro.compat import activate_mesh, make_mesh
+    from repro.compat import make_mesh
     mesh = make_mesh((8,), ("model",))
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         def f(w, x):
             def body(c, _):
                 y = c @ w                      # contraction over sharded dim

@@ -455,9 +455,10 @@ print("CHILD:" + json.dumps({
 """
 
 
-def _run_child(store_dir: str) -> dict:
+def _run_child(store_dir: str, cache_dir: str) -> dict:
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"  # subprocess must never probe TPU
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(ROOT, "src")]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else [])
@@ -476,8 +477,9 @@ def test_cross_process_zero_cold_start(tmp_path):
     compiles, 0 host feature extractions, bit-identical CPI / MPKI /
     phase-curve results on both feature backends."""
     store = str(tmp_path / "store")
-    cold = _run_child(store)
-    warm = _run_child(store)
+    cache = str(tmp_path / "jax")
+    cold = _run_child(store, cache)
+    warm = _run_child(store, cache)
 
     # cold process did real work and persisted it
     assert cold["xla"]["misses"] > 0
@@ -499,3 +501,52 @@ def test_cross_process_zero_cold_start(tmp_path):
         "pallas_cpi_phase",
     ):
         assert warm[k] == cold[k], k
+
+
+_CACHE_CHILD = r"""
+import json, os, sys
+import jax, jax.numpy as jnp
+from repro.api import Session
+Session(compile_cache=True)
+jax.jit(lambda x: x * 3.0 + 1.0)(jnp.arange(11.0)).block_until_ready()
+print("CHILD:" + json.dumps({"dir": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+def _cache_child(cache_dir):
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if cache_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+    p = subprocess.run(
+        [sys.executable, "-c", _CACHE_CHILD],
+        capture_output=True, text=True, timeout=300, env=env, cwd=ROOT,
+    )
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = [ln for ln in p.stdout.splitlines() if ln.startswith("CHILD:")][-1]
+    return json.loads(line[len("CHILD:"):])["dir"]
+
+
+def test_compile_cache_placed_from_outside(tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR is the cache when set — executables land
+    there and nowhere else; unset, the cache is the fixed, git-ignored
+    <checkout>/.cache/jax."""
+    from repro.engine.aot import DEFAULT_CACHE_DIR
+
+    def listing(d):
+        return set(os.listdir(d)) if os.path.isdir(d) else set()
+
+    outside = str(tmp_path / "jax")
+    before = listing(DEFAULT_CACHE_DIR)
+    assert _cache_child(outside) == outside
+    assert any(f.endswith("-cache") for f in os.listdir(outside))
+    assert listing(DEFAULT_CACHE_DIR) == before
+
+    assert os.path.normpath(DEFAULT_CACHE_DIR) == os.path.normpath(
+        os.path.join(ROOT, ".cache", "jax")
+    )
+    assert _cache_child(None) == DEFAULT_CACHE_DIR
+    with open(os.path.join(ROOT, ".gitignore")) as f:
+        assert ".cache/" in f.read().splitlines()
