@@ -1,0 +1,10 @@
+"""exposed_columns_ms: the mean, over the ``StreamingEngine.simulate``
+calls that lie in the traced window, of the chip-0 idle milliseconds
+inside the call's ``tao/engine.columns`` spans (host column prep,
+``trace_columns``): the part of ``request_exposed_host_ms`` that this
+host work leaves the device waiting."""
+from bench import spans
+
+
+def read(t):
+    return spans.exposed_ms(t, "engine.columns", per="engine.simulate")

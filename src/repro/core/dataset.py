@@ -19,6 +19,7 @@ from typing import Dict, Iterator, List, Optional, Sequence
 
 import numpy as np
 
+from ..spans import span
 from .features import FeatureSet
 
 __all__ = [
@@ -92,10 +93,11 @@ class WindowDataset:
             rng.shuffle(order)
         stop = n - (n % batch_size) if drop_last else n
         for lo in range(0, stop, batch_size):
-            idx = order[lo : lo + batch_size]
-            out = {k: v[idx] for k, v in self.inputs.items()}
-            if self.labels is not None:
-                out["labels"] = {k: v[idx] for k, v in self.labels.items()}
+            with span("feed.gather"):
+                idx = order[lo : lo + batch_size]
+                out = {k: v[idx] for k, v in self.inputs.items()}
+                if self.labels is not None:
+                    out["labels"] = {k: v[idx] for k, v in self.labels.items()}
             yield out
 
     def subsample(self, n: int, seed: int = 0) -> "WindowDataset":
@@ -410,7 +412,9 @@ class StreamingWindowDataset:
             rng.shuffle(order)
         stop = n - (n % batch_size) if drop_last else n
         for lo in range(0, stop, batch_size):
-            yield self.gather(order[lo : lo + batch_size])
+            with span("feed.gather"):
+                out = self.gather(order[lo : lo + batch_size])
+            yield out
 
     def subsample(self, n: int, seed: int = 0) -> "StreamingWindowDataset":
         """Uniform window subsample — same selection as

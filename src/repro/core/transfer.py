@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..spans import call_span, span
 from ..train.optim import AdamWConfig, adamw_init, adamw_update
 from ..train.trainer import CachedTrainStep, cached_train_step
 from ..uarch.isa import NUM_REGS
@@ -200,8 +201,9 @@ def _run_epochs(
         # all-reduce.  The batch stream itself is untouched, so the
         # sampled windows match the single-device run exactly.
         plan.validate_batch(batch_size)
-        params = plan.replicate(params)
-        opt = plan.replicate(opt)
+        with span("train.prepare"):
+            params = plan.replicate(params)
+            opt = plan.replicate(opt)
 
     rng = np.random.default_rng(seed)
     if rng_state is not None:
@@ -224,7 +226,8 @@ def _run_epochs(
         elif put is not None:
             batches = (put(b) for b in batches)
         for batch in batches:
-            params, opt, loss = step(params, opt, batch)
+            with span("train.step"):
+                params, opt, loss = step(params, opt, batch)
             # keep the device scalar: a float() here would sync the
             # dispatch queue once per step and serialize the prefetch
             ep_losses.append(loss)
@@ -233,11 +236,12 @@ def _run_epochs(
         # one explicit sync per epoch; summing the host scalars in step
         # order keeps the loss trajectory bit-identical to the old
         # per-step accumulation
-        ep_losses = jax.device_get(ep_losses)
-        ep_loss = 0.0
-        for x in ep_losses:
-            ep_loss += float(x)  # tao: noqa[TAO002] host numpy scalar from the per-epoch device_get above, not a device sync
-        ep_loss /= max(nb, 1)
+        with span("train.epoch_sync"):
+            ep_losses = jax.device_get(ep_losses)
+            ep_loss = 0.0
+            for x in ep_losses:
+                ep_loss += float(x)  # tao: noqa[TAO002] host numpy scalar from the per-epoch device_get above, not a device sync
+            ep_loss /= max(nb, 1)
         losses.append(ep_loss)
         if eval_fn is not None:
             evals.append(float(jax.device_get(eval_fn(params))))
@@ -299,62 +303,68 @@ def train_tao_impl(
     """
     if manifest_every < 1:
         raise ValueError(f"manifest_every must be >= 1, got {manifest_every}")
-    key = jax.random.PRNGKey(seed)
-    params = init_params if init_params is not None else init_tao(key, cfg)
-    opt_cfg = AdamWConfig(lr=lr)
-    trainable = "headonly" if freeze_embed else "all"
-    if plan is not None and not plan.sharded:
-        # the single-device plan is the default path; normalizing to None
-        # keeps one step-cache entry (and one compile) for both spellings
-        plan = None
-    step = _make_step(cfg, opt_cfg, trainable, plan=plan)
-    if freeze_embed:
-        opt = adamw_init({"adapt": params["adapt"], "pred": params["pred"]})
-    else:
-        opt = adamw_init(params)
+    with call_span("train.run") as sp:
+        # step lookup, optimizer state, resume: before the first step
+        with span("train.prepare"):
+            key = jax.random.PRNGKey(seed)
+            params = init_params if init_params is not None else init_tao(key, cfg)
+            opt_cfg = AdamWConfig(lr=lr)
+            trainable = "headonly" if freeze_embed else "all"
+            if plan is not None and not plan.sharded:
+                # the single-device plan is the default path; normalizing to None
+                # keeps one step-cache entry (and one compile) for both spellings
+                plan = None
+            step = _make_step(cfg, opt_cfg, trainable, plan=plan)
+            if freeze_embed:
+                opt = adamw_init({"adapt": params["adapt"], "pred": params["pred"]})
+            else:
+                opt = adamw_init(params)
 
-    start_epoch, rng_state, steps0 = 0, None, 0
-    losses0: List[float] = []
-    evals0: List[float] = []
-    checkpoint_cb = None
-    if store is not None and resume_key is not None:
-        # lazy: resilience.manifest pulls in the store package
-        from ..resilience.manifest import load_train_epoch, publish_train_epoch
+            start_epoch, rng_state, steps0 = 0, None, 0
+            losses0: List[float] = []
+            evals0: List[float] = []
+            checkpoint_cb = None
+            if store is not None and resume_key is not None:
+                # lazy: resilience.manifest pulls in the store package
+                from ..resilience.manifest import load_train_epoch, publish_train_epoch
 
-        state = load_train_epoch(store, resume_key, epochs)
-        if state is not None and state.get("rng_state") is not None:
-            params = state["params"]
-            # stored as a plain dict (the typed-path serializer holds
-            # dict/list/tuple trees only) — rebuild the NamedTuple
-            opt = type(opt)(**state["opt"])
-            start_epoch = state["epoch"] + 1
-            rng_state = state["rng_state"]
-            losses0 = state["losses"]
-            evals0 = state["eval_losses"]
-            steps0 = state["steps"]
+                state = load_train_epoch(store, resume_key, epochs)
+                if state is not None and state.get("rng_state") is not None:
+                    params = state["params"]
+                    # stored as a plain dict (the typed-path serializer holds
+                    # dict/list/tuple trees only) — rebuild the NamedTuple
+                    opt = type(opt)(**state["opt"])
+                    start_epoch = state["epoch"] + 1
+                    rng_state = state["rng_state"]
+                    losses0 = state["losses"]
+                    evals0 = state["eval_losses"]
+                    steps0 = state["steps"]
 
-        def checkpoint_cb(ep, p, o, ls, ev, st, rs):
-            if (ep + 1) % manifest_every and ep != epochs - 1:
-                return
-            publish_train_epoch(
-                store, resume_key, ep, jax.device_get(p),
-                jax.device_get(o)._asdict(), ls, ev, st, rs,
-            )
+                def checkpoint_cb(ep, p, o, ls, ev, st, rs):
+                    if (ep + 1) % manifest_every and ep != epochs - 1:
+                        return
+                    publish_train_epoch(
+                        store, resume_key, ep, jax.device_get(p),
+                        jax.device_get(o)._asdict(), ls, ev, st, rs,
+                    )
 
-    t0 = time.perf_counter()
-    params, losses, evals, steps = _run_epochs(
-        params, step, dataset, epochs, batch_size, opt, eval_fn, seed,
-        target_loss, plan=plan, start_epoch=start_epoch, rng_state=rng_state,
-        losses=losses0, evals=evals0, steps=steps0,
-        checkpoint_cb=checkpoint_cb,
-    )
-    return TrainResult(
-        params=params,
-        losses=losses,
-        eval_losses=evals,
-        seconds=time.perf_counter() - t0,
-        steps=steps,
-    )
+        t0 = time.perf_counter()
+        params, losses, evals, steps = _run_epochs(
+            params, step, dataset, epochs, batch_size, opt, eval_fn, seed,
+            target_loss, plan=plan, start_epoch=start_epoch, rng_state=rng_state,
+            losses=losses0, evals=evals0, steps=steps0,
+            checkpoint_cb=checkpoint_cb,
+        )
+        seconds = time.perf_counter() - t0
+        # the steps this call ran (a resumed run's earlier ones excluded)
+        sp.set_metadata(steps=steps - steps0, windows=(steps - steps0) * batch_size)
+        return TrainResult(
+            params=params,
+            losses=losses,
+            eval_losses=evals,
+            seconds=seconds,
+            steps=steps,
+        )
 
 
 def train_tao(cfg: TaoConfig, dataset: TrainData, **kw) -> TrainResult:

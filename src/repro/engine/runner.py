@@ -69,6 +69,7 @@ from ..core.model import TaoConfig, tao_forward
 from ..core.quant import quantize_tao_params, tao_forward_int8
 from ..uarch.isa import NUM_REGS
 from ..resilience.faults import fault_point
+from ..spans import call_span, in_call, span
 from .aot import abstract_like, compile_bytes_estimate
 from .metrics import DEFAULT_METRICS, MetricSpec, StepContext, resolve_metrics
 from .plan import ExecutionPlan
@@ -111,7 +112,8 @@ def _threaded_prefetch(host_batches, put, depth: int) -> Iterator:
     def produce():
         try:
             for b in host_batches:
-                dev = put(b)
+                with span("feed.put"):
+                    dev = put(b)
                 while not stop.is_set():
                     try:
                         q.put(dev, timeout=0.1)
@@ -131,12 +133,13 @@ def _threaded_prefetch(host_batches, put, depth: int) -> Iterator:
                     continue
 
     producer = threading.Thread(
-        target=produce, name="batch-prefetch", daemon=True
+        target=in_call(produce), name="batch-prefetch", daemon=True
     )
     producer.start()
     try:
         while True:
-            item = q.get()
+            with span("feed.wait"):
+                item = q.get()
             if item is _PREFETCH_STOP:
                 break
             yield item
@@ -192,16 +195,22 @@ def prefetch_to_device(
         return _threaded_prefetch(host_batches, put, depth)
 
     def inline():
+        # the consumer waits while the next host batch is made: spans
+        # close before every yield
         it = iter(host_batches)
-        try:
-            cur = put(next(it))
-        except StopIteration:
-            return
-        for nxt in it:
-            nxt_dev = put(nxt)
+        cur = _PREFETCH_STOP
+        while True:
+            with span("feed.wait"):
+                nxt = next(it, _PREFETCH_STOP)
+            if nxt is _PREFETCH_STOP:
+                break
+            with span("feed.put"):
+                nxt = put(nxt)
+            if cur is not _PREFETCH_STOP:
+                yield cur
+            cur = nxt
+        if cur is not _PREFETCH_STOP:
             yield cur
-            cur = nxt_dev
-        yield cur
 
     return inline()
 
@@ -796,23 +805,29 @@ class StreamingEngine:
         nw = count // w_eff
         nb = -(-nw // bsz)
         per = bsz * w_eff
-        extractor = FusedExtractor(
-            {k: v[:count] for k, v in cols.items()},
-            self.cfg.features,
-            chunk=self.ecfg.feature_chunk,
-            pad_to=nb * per,
-        )
-        valid = np.zeros((nb * bsz, w_eff), dtype=np.float32)
-        valid[:nw] = 1.0
-        valid = jnp.asarray(valid.reshape(nb, bsz, w_eff))
+        # a generator: the upload runs at the first next(), so its span
+        # opens here; no span stays open across a yield
+        with span("engine.upload"):
+            extractor = FusedExtractor(
+                {k: v[:count] for k, v in cols.items()},
+                self.cfg.features,
+                chunk=self.ecfg.feature_chunk,
+                pad_to=nb * per,
+            )
+            valid = np.zeros((nb * bsz, w_eff), dtype=np.float32)
+            valid[:nw] = 1.0
+            valid = jnp.asarray(valid.reshape(nb, bsz, w_eff))
         for i in range(nb):
-            feats = extractor.next_batch(per)
-            batch = {
-                k: v.reshape((bsz, w_eff) + v.shape[1:])
-                for k, v in feats.items()
-            }
-            batch["valid"] = valid[i]
-            yield self.plan.device_put(batch) if self.plan.sharded else batch
+            with span("fused.extract"):
+                feats = extractor.next_batch(per)
+                batch = {
+                    k: v.reshape((bsz, w_eff) + v.shape[1:])
+                    for k, v in feats.items()
+                }
+                batch["valid"] = valid[i]
+                if self.plan.sharded:
+                    batch = self.plan.device_put(batch)
+            yield batch
 
     # tao: hot
     def simulate(
@@ -829,106 +844,120 @@ class StreamingEngine:
         w_eff = min(cfg.window, n)
         # exact instruction count from the window grid (no float rounding)
         count = num_windows(n, cfg.window, cfg.window) * w_eff
-        entry = self._get_step(w_eff)
-        # AOT-warmed geometry: call the compiled executable directly (no
-        # dispatch-time retracing; params must be committed device arrays)
-        if entry.aot is not None:
-            step = entry.aot
-            params = self._committed_params()
-        else:
-            step = entry.fn
-            params = self._run_params()
-
-        dev_arrays = None
-        fused_batches = None
-        fs = features
-        if fs is None and self.ecfg.feature_backend in ("pallas", "fused"):
-            from ..kernels.features.ops import (  # lazy: see module note
-                device_feature_arrays,
-                trace_columns,
-            )
-
-            # raises when addresses leave the int32-exact window: the
-            # device backend the caller asked for never silently becomes
-            # the NumPy one
-            cols = trace_columns(func_trace, cfg.features)
-            if self.ecfg.feature_backend == "fused":
-                fused_batches = self._fused_batches(cols, w_eff, count)
+        bsz = self.ecfg.batch_size
+        nb = -(-(count // w_eff) // bsz)
+        with call_span(
+            "engine.simulate",
+            instructions=count,
+            positions=nb * bsz * w_eff,
+            batches=nb,
+        ):
+            entry = self._get_step(w_eff)
+            # AOT-warmed geometry: call the compiled executable directly (no
+            # dispatch-time retracing; params must be committed device arrays)
+            if entry.aot is not None:
+                step = entry.aot
+                params = self._committed_params()
             else:
-                dev_arrays = device_feature_arrays(
-                    cols, cfg.features, chunk=self.ecfg.feature_chunk
+                step = entry.fn
+                params = self._run_params()
+
+            dev_arrays = None
+            fused_batches = None
+            fs = features
+            if fs is None and self.ecfg.feature_backend in ("pallas", "fused"):
+                from ..kernels.features.ops import (  # lazy: see module note
+                    device_feature_arrays,
+                    trace_columns,
                 )
-        if fs is None and dev_arrays is None and fused_batches is None:
-            fs = extract_features(func_trace, cfg.features, with_labels=False)
 
-        if fused_batches is not None:
-            batches = fused_batches
-        elif dev_arrays is not None:
-            batches = self._device_batches(dev_arrays, w_eff, count)
-        else:
-            host_batches = stream_batches(
-                fs,
-                cfg.window,
-                self.ecfg.batch_size,
-                stride=cfg.window,
-                extra={
-                    "is_branch": func_trace["is_branch"],
-                    "is_mem": func_trace["is_mem"],
-                },
-            )
-            batches = (
-                self._prefetched(host_batches)
-                if self.ecfg.prefetch
-                else (self.plan.device_put(b) for b in host_batches)
-            )
+                # raises when addresses leave the int32-exact window: the
+                # device backend the caller asked for never silently becomes
+                # the NumPy one
+                with span("engine.columns"):
+                    cols = trace_columns(func_trace, cfg.features)
+                if self.ecfg.feature_backend == "fused":
+                    fused_batches = self._fused_batches(cols, w_eff, count)
+                else:
+                    with span("engine.upload"):
+                        dev_arrays = device_feature_arrays(
+                            cols, cfg.features, chunk=self.ecfg.feature_chunk
+                        )
+            if fs is None and dev_arrays is None and fused_batches is None:
+                with span("engine.columns"):
+                    fs = extract_features(func_trace, cfg.features, with_labels=False)
 
-        # specs' init plus the window-grid slot: running global window
-        # offset + total real windows (data, not shape — every trace
-        # shares the executable)
-        carry = self.init_carry(n)
-        pers = []
-        for batch in batches:
-            carry, per = step(params, carry, batch)
-            if self.ecfg.collect:
-                pers.append(per)
-
-        carry = jax.device_get(carry)  # single host sync for the whole trace
-        metrics: Dict[str, float] = {}
-        for s in self._specs:
-            out = s.finalize(carry[s.name], count)
-            clash = set(out) & set(metrics)
-            if clash:
-                raise ValueError(
-                    f"metric spec {s.name!r} finalized key(s) {sorted(clash)} "
-                    "already emitted by an earlier spec in this run"
+            if fused_batches is not None:
+                batches = fused_batches
+            elif dev_arrays is not None:
+                batches = self._device_batches(dev_arrays, w_eff, count)
+            else:
+                host_batches = stream_batches(
+                    fs,
+                    cfg.window,
+                    self.ecfg.batch_size,
+                    stride=cfg.window,
+                    extra={
+                        "is_branch": func_trace["is_branch"],
+                        "is_mem": func_trace["is_mem"],
+                    },
                 )
-            reserved = set(out) & _RESERVED_RESULT_ATTRS
-            if reserved:
-                raise ValueError(
-                    f"metric spec {s.name!r} finalized reserved key(s) "
-                    f"{sorted(reserved)}: SimulationResult instance "
-                    "attributes would shadow them"
+                batches = (
+                    self._prefetched(host_batches)
+                    if self.ecfg.prefetch
+                    else (self.plan.device_put(b) for b in host_batches)
                 )
-            metrics.update(out)
-        secs = time.perf_counter() - t0
 
-        arrays: Dict[str, Optional[np.ndarray]] = {
-            k: None for k in PER_INSTRUCTION_KEYS
-        }
-        if self.ecfg.collect and pers:
-            # one explicit sync for every batch's arrays (was a hidden
-            # np.asarray device->host pull per batch per key)
-            pers = jax.device_get(pers)
-            for k in arrays:
-                arrays[k] = np.concatenate([p[k] for p in pers])[:count]
+            # specs' init plus the window-grid slot: running global window
+            # offset + total real windows (data, not shape — every trace
+            # shares the executable)
+            with span("engine.upload"):
+                carry = self.init_carry(n)
+            pers = []
+            for batch in batches:
+                with span("engine.step"):
+                    carry, per = step(params, carry, batch)
+                if self.ecfg.collect:
+                    pers.append(per)
 
-        return SimulationResult(
-            num_instructions=count,
-            seconds=secs,
-            mips=count / 1e6 / secs,
-            metrics=metrics,
-            arrays=arrays,
-        )
+            with span("engine.sync"):
+                carry = jax.device_get(carry)  # single host sync for the whole trace
+                metrics: Dict[str, float] = {}
+                for s in self._specs:
+                    out = s.finalize(carry[s.name], count)
+                    clash = set(out) & set(metrics)
+                    if clash:
+                        raise ValueError(
+                            f"metric spec {s.name!r} finalized key(s) {sorted(clash)} "
+                            "already emitted by an earlier spec in this run"
+                        )
+                    reserved = set(out) & _RESERVED_RESULT_ATTRS
+                    if reserved:
+                        raise ValueError(
+                            f"metric spec {s.name!r} finalized reserved key(s) "
+                            f"{sorted(reserved)}: SimulationResult instance "
+                            "attributes would shadow them"
+                        )
+                    metrics.update(out)
+                secs = time.perf_counter() - t0
+
+                arrays: Dict[str, Optional[np.ndarray]] = {
+                    k: None for k in PER_INSTRUCTION_KEYS
+                }
+                if self.ecfg.collect and pers:
+                    # one explicit sync for every batch's arrays (was a hidden
+                    # np.asarray device->host pull per batch per key)
+                    pers = jax.device_get(pers)
+                    for k in arrays:
+                        arrays[k] = np.concatenate([p[k] for p in pers])[:count]
+
+                return SimulationResult(
+                    num_instructions=count,
+                    seconds=secs,
+                    mips=count / 1e6 / secs,
+                    metrics=metrics,
+                    arrays=arrays,
+                )
 
 
 def simulate_trace_engine(
