@@ -171,7 +171,7 @@ def run_one_chip(smoke: Smoke, args) -> None:
         signed_log_device,
         trace_columns,
     )
-    from repro.kernels.fused.ops import fused_feature_columns, init_fused_state
+    from repro.kernels.fused.ops import FusedExtractor, init_fused_state
     from repro.train.trainer import train_step_compiles
     from repro.uarch import UARCH_A
 
@@ -228,13 +228,15 @@ def run_one_chip(smoke: Smoke, args) -> None:
         smoke.check(engine.num_compiles == 1, "one step compile for the geometry")
         smoke.check(all(map(math.isfinite, st["chip"].values())), "metrics finite")
         if on_tpu():
-            # the jitted wrapper the engine's fused batches go through
-            from repro.kernels.fused.ops import _COLUMN_KEYS, _fused_padded
+            # the compiled extraction program the engine's fused batches go through
+            from repro.kernels.fused.ops import _fused_padded, _pack
 
-            cols = trace_columns(ft[: batch * cfg.window], fc)
+            per = batch * cfg.window
+            cols = trace_columns(ft[:per], fc)
             state = init_fused_state(fc)
             hlo = _fused_padded.lower(
-                {k: cols[k] for k in _COLUMN_KEYS}, state["table"], state["queue"],
+                _pack(cols, 0, per), state["table"], state["queue"],
+                np.array([per, 0], np.int32), shape=(batch, cfg.window),
                 n_queue=fc.n_queue, n_mem=fc.n_mem, n_flags=fc.flags_dim,
                 chunk=512, interpret=False,
             ).as_text()
@@ -243,7 +245,7 @@ def run_one_chip(smoke: Smoke, args) -> None:
 
     with smoke.phase("features: fused device vs NumPy"):
         cols = trace_columns(ft, fc)
-        dev, _ = fused_feature_columns(cols, init_fused_state(fc), fc)
+        dev = FusedExtractor(cols, fc).next_batch(len(ft))
         ref = extract_features(ft, fc, with_labels=False)
         for k in ("opcode", "regbits", "flags", "brhist"):
             smoke.check(np.array_equal(np.asarray(dev[k]), getattr(ref, k)),
@@ -253,10 +255,10 @@ def run_one_chip(smoke: Smoke, args) -> None:
         smoke.check(np.array_equal(staged_raw.view(np.int32), raw.view(np.int32)),
                     "raw memdist deltas bit-exact (staged scan kernel)")
         md = np.asarray(dev["memdist"])
+        compiled = jax.jit(signed_log_device)(jnp.asarray(raw), np.int32(0))
         smoke.check(
-            np.array_equal(md.view(np.int32),
-                           np.asarray(signed_log_device(jnp.asarray(raw))).view(np.int32)),
-            "fused memdist == this device's signed_log of the exact raw deltas",
+            np.array_equal(md.view(np.int32), np.asarray(compiled).view(np.int32)),
+            "fused memdist == this device's compiled signed_log of the exact raw deltas",
         )
         err = np.abs(md - ref.memdist)
         print(f"   memdist vs NumPy: max |diff| {err.max()!r}, "

@@ -1,14 +1,19 @@
 """TAO005 — fma-contraction hazard in bitwise-deterministic functions.
 
-``core.features.signed_log`` (and its Pallas twin) carry a contract the
-test suite pins: in-jit output is **bit-identical** to the NumPy
-reference, which is why both are written as one-op-per-statement Horner
-steps.  XLA is free to contract ``a * b + c`` written as a single
-expression into an fma, whose differently-rounded result breaks
-``np.array_equal`` on exactly the backends where it matters.  The hazard
-pattern is purely syntactic: an ``Add``/``Sub`` whose operand is a
-literal ``Mult`` expression.  Functions opt in with ``# tao: bitwise``;
-the fix is always the same — hoist the product into its own statement.
+``core.features.signed_log`` (and its jax twin
+``kernels.features.ops.signed_log_device``) carry a contract the test
+suite pins: the twin's output, eager or inside the compiled fused
+extraction program, is **bit-identical** to the NumPy reference on the
+CPU, which is why both are written as one-op-per-statement Horner steps.
+XLA is free to contract ``a * b + c`` into an fma, whose
+differently-rounded result breaks ``np.array_equal`` on exactly the
+backends where it matters; the twin stops it by rounding each product
+through an integer barrier (``bitcast(bitcast(p) | zero)``, ``zero``
+traced inside a compiled program), which needs the product in its own
+statement.  The hazard pattern is purely syntactic: an
+``Add``/``Sub`` whose operand is a literal ``Mult`` expression.
+Functions opt in with ``# tao: bitwise``; the fix is always the same —
+hoist the product into its own statement.
 """
 from __future__ import annotations
 

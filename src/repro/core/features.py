@@ -171,12 +171,24 @@ def _labels(trace: np.ndarray, with_labels: bool):
 # sign(d) * log2(1 + |d|) / 32 evaluated as a FIXED sequence of exactly
 # rounded float32 operations: exponent/mantissa split by bit manipulation,
 # then an atanh-series polynomial (Horner) for log2 of the mantissa.  Every
-# step is an individually rounded IEEE-754 float32 op, so NumPy and an
-# op-per-dispatch jax evaluation (``repro.kernels.features.ops.signed_log_device``)
-# produce bit-identical results — the property the pallas feature backend's
-# exact-equivalence tests rely on.  A fused/jitted evaluation would NOT be
-# bit-identical: XLA contracts `a*b + c` into fma, which rounds once instead
-# of twice.  Max relative error vs true log2 is ~6e-8 (≈1 ulp).
+# step is an individually rounded IEEE-754 float32 op, so NumPy and the jax
+# twin (``repro.kernels.features.ops.signed_log_device``) produce
+# bit-identical results — the property the device feature backends'
+# exact-equivalence tests rely on.  This function stays the spec.
+#
+# The decision on compiled evaluation lives here.  A plain jitted
+# evaluation is NOT bit-identical: XLA contracts `a*b + c` into an fma,
+# which rounds once instead of twice (`lax.optimization_barrier` does not
+# stop it).  So the jax twin keeps this exact chain of ops and routes
+# every product that feeds an add (`s * s`, each Horner `p * z`, `p * s`)
+# through an integer identity the compiler cannot see:
+# bitcast_f32(bitcast_i32(p) | zero), with `zero` an int32 argument,
+# traced inside a compiled program.  That forces the product to round to
+# float32 first, so the compiled twin equals NumPy bit for bit on the CPU
+# and runs inside the fused extraction program; on the TPU it stays within
+# 1 ulp, as the eager evaluation did (docs/kernels.md "Exactness").  Lower precision, a table
+# or skipping the compression would be a different result.  Max relative
+# error vs true log2 is ~6e-8 (≈1 ulp).
 # ---------------------------------------------------------------------------
 
 # 2/ln2 * s^(2k) atanh-series coefficients: log2(m) = (2/ln2)·atanh(s) with

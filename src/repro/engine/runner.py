@@ -793,20 +793,20 @@ class StreamingEngine:
         self, cols: Dict, w_eff: int, count: int
     ) -> Iterator[Dict]:
         """Batch iterator for the "fused" backend: the raw int32/bool
-        columns ship to the device once, then every batch is ONE megakernel
-        launch (``kernels/fused/``) with the scan state carried across
-        batches — model inputs are produced per batch and consumed by the
-        step immediately, so no O(trace) feature materialization ever
-        exists.  Window/padding/validity layout is exactly
-        ``_device_batches``'s (bit-identical by construction)."""
+        columns stay on the host, and every batch is ONE dispatch of one
+        compiled extraction program (``kernels/fused/``) fed one packed
+        host array, with the scan state carried across batches — model
+        inputs are produced per batch and consumed by the step
+        immediately, so no O(trace) feature materialization ever exists.
+        Window/padding/validity layout is exactly ``_device_batches``'s
+        (bit-identical by construction)."""
         from ..kernels.fused.ops import FusedExtractor  # lazy: module note
 
         bsz = self.ecfg.batch_size
-        nw = count // w_eff
-        nb = -(-nw // bsz)
+        nb = -(-(count // w_eff) // bsz)
         per = bsz * w_eff
-        # a generator: the upload runs at the first next(), so its span
-        # opens here; no span stays open across a yield
+        # a generator: this runs at the first next(), so the span opens
+        # here; no span stays open across a yield
         with span("engine.upload"):
             extractor = FusedExtractor(
                 {k: v[:count] for k, v in cols.items()},
@@ -814,17 +814,9 @@ class StreamingEngine:
                 chunk=self.ecfg.feature_chunk,
                 pad_to=nb * per,
             )
-            valid = np.zeros((nb * bsz, w_eff), dtype=np.float32)
-            valid[:nw] = 1.0
-            valid = jnp.asarray(valid.reshape(nb, bsz, w_eff))
-        for i in range(nb):
+        for _ in range(nb):
             with span("fused.extract"):
-                feats = extractor.next_batch(per)
-                batch = {
-                    k: v.reshape((bsz, w_eff) + v.shape[1:])
-                    for k, v in feats.items()
-                }
-                batch["valid"] = valid[i]
+                batch = extractor.next_batch(per, (bsz, w_eff))
                 if self.plan.sharded:
                     batch = self.plan.device_put(batch)
             yield batch

@@ -24,11 +24,12 @@ megakernel (``kernels/fused/kernel.py``) runs the same two push steps.
 
 The memory-distance kernel deliberately returns RAW int32-derived deltas as
 float32 (int32 subtraction is exact; int→float32 conversion is correctly
-rounded) rather than applying the signed-log compression in-kernel: inside
-one compiled program XLA contracts `a*b + c` chains into fma, which breaks
-bit-reproducibility against the NumPy backend.  The caller applies
-``ops.signed_log_device`` — an op-per-dispatch twin of
-``core.features.signed_log`` (see the comment there).
+rounded) rather than applying the signed-log compression in-kernel.  The
+caller applies ``ops.signed_log_device``, the jax twin of
+``core.features.signed_log`` (see the comment there), eagerly on the
+staged backend and inside the fused backend's compiled program.  Its
+integer rounding barriers keep XLA from contracting `a*b + c` chains into
+fma, which would break bit-reproducibility against the NumPy backend.
 
 Grid semantics: the single chunk dimension is "arbitrary" (sequential), so
 scratch state flows from chunk to chunk.  Off-TPU the same programs run

@@ -11,10 +11,11 @@ values stays bit-exact (docs/kernels.md "Exactness"):
   * branch-history rows are copies of {-1, 0, +1} values (exact);
   * memory-distance deltas are int32 subtractions (exact) converted to
     float32 (correctly rounded), with the signed-log compression applied by
-    ``signed_log_device`` — an op-per-dispatch jax twin of
-    ``core.features.signed_log``.  Each multiply/add runs as its own XLA
-    dispatch; fusing them into one jit would let XLA contract `a*b + c`
-    into fma (one rounding instead of two) and break bit-equality.
+    ``signed_log_device`` — the jax twin of ``core.features.signed_log``.
+    An integer rounding barrier after each product keeps XLA from
+    contracting `a*b + c` into an fma (one rounding instead of two), so
+    the same function serves the fused backend's compiled program and the
+    staged backend's eager calls.
 
 ``trace_columns`` does the cheap host-side prep (bucket hash on the int64
 pc, int32 address narrowing) and raises ``ValueError`` when addresses fall
@@ -58,14 +59,27 @@ ADDR_EXACT_LIMIT = 2**30
 DEFAULT_CHUNK = 512
 
 
+def _rounded(p: jnp.ndarray, zero) -> jnp.ndarray:
+    """``p`` as the float32 it was rounded to, before any later op reads it.
+
+    ``zero`` is an int32 zero the compiler cannot see (a traced argument
+    inside a compiled program): OR-ing it into ``p``'s bits is the
+    identity, but XLA can no longer contract the multiply that made ``p``
+    into the add that reads it."""
+    bits = jax.lax.bitcast_convert_type(p, jnp.int32) | zero
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
 # tao: bitwise
-def signed_log_device(d: jnp.ndarray) -> jnp.ndarray:
+def signed_log_device(d: jnp.ndarray, zero) -> jnp.ndarray:
     """Bit-exact jax twin of ``core.features.signed_log``.
 
-    Must run EAGERLY (op per dispatch): each operation is then individually
-    rounded, matching NumPy bit for bit.  Do not wrap in ``jax.jit`` — XLA's
-    fma contraction of `a*b + c` would round once instead of twice and
-    diverge from the NumPy backend in the last ulp.
+    The same chain of float32 ops, with every product that feeds an add
+    (``s * s``, each Horner ``p * z``, ``p * s``) rounded through
+    ``_rounded``.  ``zero`` is an int32 zero, traced inside a compiled
+    program: ``jax.jit(signed_log_device)(d, np.int32(0))`` equals NumPy
+    bit for bit on the CPU, as does an eager call (``core.features``
+    states the decision).
     """
     d = jnp.asarray(d, jnp.float32)
     a = jnp.abs(d)
@@ -76,15 +90,18 @@ def signed_log_device(d: jnp.ndarray) -> jnp.ndarray:
         (bits & jnp.int32(0x007FFFFF)) | jnp.int32(0x3F800000), jnp.float32
     )
     big = m > SIGNED_LOG_SQRT2
-    m = jnp.where(big, m * jnp.float32(0.5), m)
+    m = jnp.where(big, m * jnp.float32(0.5), m)  # exact: no rounding to pin
     e = (e + big).astype(jnp.float32)
     s = (m - jnp.float32(1.0)) / (m + jnp.float32(1.0))
     z = s * s
+    z = _rounded(z, zero)
     p = jnp.full_like(z, SIGNED_LOG_COEFFS[-1])
     for c in SIGNED_LOG_COEFFS[-2::-1]:
         p = p * z
+        p = _rounded(p, zero)
         p = p + jnp.float32(c)
     r = p * s
+    r = _rounded(r, zero)
     r = r + e
     r = r * jnp.float32(1.0 / 32.0)
     return jnp.where(d < 0, -r, r)
@@ -271,7 +288,7 @@ def device_feature_arrays(
         chunk=chunk,
         interpret=interpret,
     )
-    memdist = signed_log_device(deltas)  # eager: keeps NumPy bit-equality
+    memdist = signed_log_device(deltas, np.int32(0))
     return {
         "opcode": jnp.asarray(cols["opcode"], jnp.int32),
         "regbits": regbits,

@@ -19,9 +19,10 @@ grid walks trace chunks sequentially ("arbitrary" dimension semantics):
     same push steps as the staged kernels (``kernels/features/kernel``).
 
 Feature rows exist only at batch granularity: the caller
-(``ops.FusedExtractor``) slices one batch of raw columns, runs this kernel,
-and feeds the result straight to the engine's jitted step — the O(trace)
-HBM FeatureSet never exists.
+(``ops._fused_padded``, driven by ``ops.FusedExtractor``) runs this kernel
+on one batch of raw columns inside one compiled program and feeds the
+result straight to the engine's jitted step — the O(trace) HBM FeatureSet
+never exists.
 
 The scan state is threaded ACROSS calls: the carry table/queue enter as
 inputs and leave as outputs.  The state outputs map to the same block on
@@ -32,8 +33,9 @@ what lets a whole trace stream through fixed-size launches and equal one
 monolithic scan.
 
 Memory-distance deltas are RAW int32 subtractions cast to float32, exactly
-like the staged kernel: the signed-log compression runs eagerly outside any
-compiled program (``kernels/features/ops``).
+like the staged kernel: the signed-log compression runs after the kernel,
+in the same compiled program, behind ``signed_log_device``'s rounding
+barriers (``kernels/features/ops``).
 
 Off-TPU the same program runs under ``interpret=True`` (CPU CI).
 """

@@ -9,19 +9,23 @@ out of three invariants:
   * regbits/flags/brhist are exact integer/bool -> {0.0, 1.0, ±1.0} values —
     any compute path produces the same bits;
   * memory-distance deltas leave the kernel RAW (exact int32 subtraction,
-    correctly-rounded cast) and the signed-log compression runs EAGERLY via
-    ``signed_log_device`` — never inside a compiled program, where XLA's fma
-    contraction of ``a*b + c`` would diverge in the last ulp;
+    correctly-rounded cast) and the signed-log compression runs inside the
+    same compiled program through ``signed_log_device`` with a traced zero:
+    each product is rounded through an integer barrier, so XLA cannot
+    contract ``a*b + c`` into an fma that would diverge in the last ulp;
   * the scan state threads across calls exactly (float copies and int32
     values), so batch-granular extraction equals one monolithic scan.
 
 ``FusedExtractor`` is the streaming driver the engine's ``"fused"`` backend
-uses: raw int32/bool columns ship to the device once (~30 B/instr — the
-same payload as the staged backend), then each ``next_batch`` slices one
-batch worth of columns device-side, runs ONE megakernel launch, applies the
-eager signed-log, and hands the model-input dict straight to the jitted
-step.  Features exist only at batch granularity — no O(trace) FeatureSet in
-HBM (see docs/kernels.md for the bandwidth accounting).
+uses.  The raw int32/bool columns stay on the host; each ``next_batch``
+packs one batch of them into a single fixed-shape int32 array (zero-padded
+past the trace's end) and dispatches ONE compiled program,
+``_fused_padded``: the megakernel, the signed-log, the per-position
+``opcode``/``is_branch``/``is_mem``/``valid`` fields and the reshape to the
+step's batch layout.  One host->device copy (40 B/instr) and one launch per
+batch, one compile per batch shape.  Features exist only at batch
+granularity — no O(trace) FeatureSet in HBM (see docs/kernels.md for the
+bandwidth accounting).
 """
 from __future__ import annotations
 
@@ -46,7 +50,6 @@ from .kernel import VCOLS, fused_feature_pallas
 
 __all__ = [
     "FusedExtractor",
-    "fused_feature_columns",
     "init_fused_state",
 ]
 
@@ -70,24 +73,36 @@ def init_fused_state(cfg: FeatureConfig) -> Dict[str, jnp.ndarray]:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("n_queue", "n_mem", "n_flags", "chunk", "interpret")
+    jax.jit,
+    static_argnames=("shape", "n_queue", "n_mem", "n_flags", "chunk", "interpret"),
 )
-def _fused_padded(cols, table, queue, *, n_queue, n_mem, n_flags, chunk, interpret):
-    """``cols``: the ``_COLUMN_KEYS`` columns, each (n,)."""
-    n = cols["bucket"].shape[0]
+def _fused_padded(
+    packed, table, queue, counts, *, shape, n_queue, n_mem, n_flags, chunk, interpret
+):
+    """The whole extraction of ``m`` positions, one program.
+
+    ``packed``: (len(_COLUMN_KEYS), m) int32, the raw columns in
+    ``_COLUMN_KEYS`` order; ``counts``: int32 (2,), the real positions
+    among the ``m`` (the rest are padding, ``valid`` 0) and a zero for
+    ``signed_log_device``'s rounding barriers.  Returns ``(batch, table,
+    queue)``: every field of ``batch`` is reshaped to ``shape + (…)``, with
+    ``prod(shape) == m``."""
+    m = packed.shape[1]
+    col = dict(zip(_COLUMN_KEYS, packed))
+    is_branch = col["is_branch"] != 0
+    is_mem = col["is_mem"] != 0
     outcome = jnp.where(
-        cols["is_branch"],
-        jnp.where(cols["taken"], jnp.float32(1.0), jnp.float32(-1.0)),
+        is_branch,
+        jnp.where(col["taken"] != 0, jnp.float32(1.0), jnp.float32(-1.0)),
         jnp.float32(0.0),
     )
-    per_instr = jnp.stack([cols[k].astype(jnp.int32) for k in VCOLS], axis=1)
-    nc = max(1, -(-n // chunk))
-    regbits, flags, brhist, memdist, table, queue = fused_feature_pallas(
-        chunked_column(cols["bucket"].astype(jnp.int32), chunk),
-        chunked_column(cols["addr"].astype(jnp.int32), chunk),
+    nc = max(1, -(-m // chunk))
+    regbits, flags, brhist, raw, table, queue = fused_feature_pallas(
+        chunked_column(col["bucket"], chunk),
+        chunked_column(col["addr"], chunk),
         chunked_column(outcome, chunk),
-        chunked_column(cols["is_mem"].astype(jnp.int32), chunk),
-        jnp.pad(per_instr, ((0, nc * chunk - n), (0, 0))),
+        chunked_column(col["is_mem"], chunk),
+        jnp.pad(packed[2:].T, ((0, nc * chunk - m), (0, 0))),  # the VCOLS rows
         table,
         queue,
         n_queue=n_queue,
@@ -97,65 +112,46 @@ def _fused_padded(cols, table, queue, *, n_queue, n_mem, n_flags, chunk, interpr
         fp_ops=_FP_OPS,
         interpret=interpret,
     )
-    return regbits[:n], flags[:n], brhist[:n], memdist[:n], table, queue
-
-
-# tao: hot
-def fused_feature_columns(
-    cols: Dict,
-    state: Dict[str, jnp.ndarray],
-    cfg: FeatureConfig,
-    *,
-    chunk: int = DEFAULT_CHUNK,
-    interpret: Optional[bool] = None,
-) -> Tuple[Dict[str, jnp.ndarray], Dict[str, jnp.ndarray]]:
-    """One fused device pass over (a slice of) the raw trace columns.
-
-    Returns ``(features, new_state)`` where ``features`` holds the model
-    inputs (``opcode``/``regbits``/``flags``/``brhist``/``memdist``) for
-    exactly these positions and ``new_state`` is the scan carry to thread
-    into the next slice.  Bit-identical to running the staged extraction
-    over the concatenated slices.
-    """
-    if interpret is None:
-        interpret = not on_tpu()
-    regbits, flags, brhist, raw, table, queue = _fused_padded(
-        {k: jnp.asarray(cols[k]) for k in _COLUMN_KEYS},
-        state["table"],
-        state["queue"],
-        n_queue=cfg.n_queue,
-        n_mem=cfg.n_mem,
-        n_flags=cfg.flags_dim,
-        chunk=kernel_chunk(chunk),
-        interpret=interpret,
-    )
-    memdist = signed_log_device(raw)  # eager: keeps NumPy bit-equality
-    feats = {
-        "opcode": jnp.asarray(cols["opcode"], jnp.int32),
-        "regbits": regbits,
-        "flags": flags,
-        "brhist": brhist,
-        "memdist": memdist,
+    fields = {
+        "opcode": col["opcode"],
+        "regbits": regbits[:m],
+        "flags": flags[:m],
+        "brhist": brhist[:m],
+        "memdist": signed_log_device(raw[:m], counts[1]),
+        "is_branch": is_branch,
+        "is_mem": is_mem,
+        "valid": (jnp.arange(m) < counts[0]).astype(jnp.float32),
     }
-    return feats, {"table": table, "queue": queue}
+    batch = {k: v.reshape(shape + v.shape[1:]) for k, v in fields.items()}
+    return batch, table, queue
+
+
+def _pack(cols: Dict[str, np.ndarray], lo: int, m: int) -> np.ndarray:
+    """Positions ``[lo, lo + m)`` of the host columns as one
+    (len(_COLUMN_KEYS), m) int32 array, zero past the columns' end (pad
+    positions are non-branch, non-mem: the carry passes through them)."""
+    k = max(0, min(m, len(cols["bucket"]) - lo))
+    out = np.empty((len(_COLUMN_KEYS), m), np.int32)
+    for j, key in enumerate(_COLUMN_KEYS):
+        out[j, :k] = cols[key][lo : lo + k]
+    out[:, k:] = 0
+    return out
 
 
 class FusedExtractor:
-    """Streams fixed-size feature batches out of device-resident raw trace
+    """Streams fixed-size feature batches out of the raw host trace
     columns, carrying the scan state across batches.
 
     ``cols`` is the host dict from ``kernels.features.ops.trace_columns``
-    (already validated against the int32-exact address window); it ships to
-    the device ONCE here, zero-padded to ``pad_to`` positions so every
-    ``next_batch(m)`` slice is uniform (pad rows are non-branch/non-mem and
-    leave the carry untouched).  Each call runs one megakernel launch plus
-    the eager signed-log and returns the model-input dict for the next
-    ``m`` positions, including the sliced ``is_branch``/``is_mem`` bool
-    columns the engine's step masks with.
+    (already validated against the int32-exact address window), read as
+    if zero-padded to ``pad_to`` positions (pad rows are non-branch/non-mem
+    and leave the carry untouched).  Each ``next_batch(m)`` ships one
+    packed (len(_COLUMN_KEYS), m) int32 array and runs one compiled
+    extraction program; it returns the model-input dict for the next ``m``
+    positions, including the ``is_branch``/``is_mem`` bool columns the
+    engine's step masks with and ``valid`` (0.0 on pad positions).
     """
 
-    # one-time host->device column upload, not the batch loop
-    # tao: cold
     def __init__(
         self,
         cols: Dict[str, np.ndarray],
@@ -169,21 +165,25 @@ class FusedExtractor:
         pad_to = n if pad_to is None else pad_to
         if pad_to < n:
             raise ValueError(f"pad_to ({pad_to}) < column length ({n})")
-        self._cols: Dict[str, jnp.ndarray] = {}
-        for k in _COLUMN_KEYS:
-            a = jnp.asarray(cols[k])
-            if pad_to > n:
-                a = jnp.pad(a, (0, pad_to - n))
-            self._cols[k] = a
-        self._cfg = cfg
-        self._chunk = chunk
-        self._interpret = interpret
+        self._cols = cols
+        self._n = n
+        self._static = dict(
+            n_queue=cfg.n_queue,
+            n_mem=cfg.n_mem,
+            n_flags=cfg.flags_dim,
+            chunk=kernel_chunk(chunk),
+            interpret=not on_tpu() if interpret is None else interpret,
+        )
         self._pos = 0
         self._limit = pad_to
         self.state = init_fused_state(cfg)
 
     # tao: hot
-    def next_batch(self, m: int) -> Dict[str, jnp.ndarray]:
+    def next_batch(
+        self, m: int, shape: Optional[Tuple[int, ...]] = None
+    ) -> Dict[str, jnp.ndarray]:
+        """The next ``m`` positions; every field is shaped ``shape + (…)``
+        (default ``(m,)``)."""
         lo = self._pos
         if lo + m > self._limit:
             raise ValueError(
@@ -191,14 +191,14 @@ class FusedExtractor:
                 f"({lo} + {m} > {self._limit})"
             )
         self._pos = lo + m
-        sl = {k: v[lo : lo + m] for k, v in self._cols.items()}
-        feats, self.state = fused_feature_columns(
-            sl,
-            self.state,
-            self._cfg,
-            chunk=self._chunk,
-            interpret=self._interpret,
+        real = max(0, min(m, self._n - lo))
+        batch, table, queue = _fused_padded(
+            _pack(self._cols, lo, m),
+            self.state["table"],
+            self.state["queue"],
+            np.array([real, 0], np.int32),
+            shape=(m,) if shape is None else tuple(shape),
+            **self._static,
         )
-        feats["is_branch"] = sl["is_branch"]
-        feats["is_mem"] = sl["is_mem"]
-        return feats
+        self.state = {"table": table, "queue": queue}
+        return batch

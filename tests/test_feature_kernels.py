@@ -2,12 +2,14 @@
 
 The contract is EXACT (bitwise) equivalence: branch-history rows move only
 {-1, 0, +1} values, memory-distance deltas are exact int32 subtractions,
-and the signed-log compression runs as an op-per-dispatch jax twin of
+and the signed-log compression runs as a jax twin of
 ``core.features.signed_log`` (both sides a fixed chain of individually
-rounded float32 ops).  Covers hash-collision-heavy traces (many PCs per
-bucket), empty-queue boundaries, chunk-boundary geometry, and the int32
-address-window refusal.
+rounded float32 ops, eagerly or compiled behind rounding barriers).
+Covers hash-collision-heavy traces (many PCs per bucket), empty-queue
+boundaries, chunk-boundary geometry, and the int32 address-window
+refusal.
 """
+import jax
 import numpy as np
 import pytest
 
@@ -62,8 +64,11 @@ def _random_trace(n, rng, branch_p=0.4, mem_p=0.4, pc_mod=64, addr_hi=1 << 20):
 # ---------------------------------------------------------------------------
 
 
-def test_signed_log_numpy_jax_bitwise_identical():
-    """The NumPy spec and its eager-jax twin agree bit for bit."""
+@pytest.mark.parametrize("mode", ["eager", "jit"])
+def test_signed_log_numpy_jax_bitwise_identical(mode):
+    """The NumPy spec and its jax twin agree bit for bit, op per dispatch
+    and compiled (where only the rounding barriers keep XLA from
+    contracting each Horner step into an fma)."""
     rng = np.random.default_rng(7)
     d = np.concatenate(
         [
@@ -74,7 +79,10 @@ def test_signed_log_numpy_jax_bitwise_identical():
         ]
     ).astype(np.float32)
     a = signed_log(d)
-    b = np.asarray(signed_log_device(d))
+    if mode == "jit":
+        b = np.asarray(jax.jit(signed_log_device)(d, np.int32(0)))
+    else:
+        b = np.asarray(signed_log_device(d, np.int32(0)))
     np.testing.assert_array_equal(a.view(np.int32), b.view(np.int32))
 
 

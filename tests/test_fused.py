@@ -8,7 +8,9 @@ The tentpole contracts, each enforced bitwise or with a declared band:
     ``FusedExtractor.next_batch`` calls == one monolithic pass;
   * ``feature_backend="fused"`` produces CPI / MPKI / phase curves
     bit-identical to the ``"pallas"`` and ``"numpy"`` backends, while
-    SHARING their compiled step (one compile per geometry, ever);
+    SHARING their compiled step (one compile per geometry, ever), and its
+    extraction is one compiled program per geometry, launched once per
+    batch;
   * the int8 W8A8 path holds the ``bench_accuracy`` parity band
     (|dCPI|/CPI <= 5%, |dMPKI| <= max(10%, 5.0)) and gets its own
     step-cache entry (precision is part of the key);
@@ -33,11 +35,7 @@ from repro.kernels.features.ops import (
     signed_log_device,
     trace_columns,
 )
-from repro.kernels.fused.ops import (
-    FusedExtractor,
-    fused_feature_columns,
-    init_fused_state,
-)
+from repro.kernels.fused.ops import FusedExtractor
 from repro.kernels.fused.ref import fused_scan_ref, init_state_ref
 from repro.uarch import get_benchmark, run_functional
 from repro.uarch.isa import FUNC_TRACE_DTYPE, Op
@@ -76,6 +74,12 @@ def _assert_bitwise(a, b, msg=""):
         np.testing.assert_array_equal(a, b, err_msg=msg)
 
 
+def _fused_pass(cols, **kw):
+    """One fused pass over all of ``cols``: (features, carry after it)."""
+    ex = FusedExtractor(cols, FCFG, **kw)
+    return ex.next_batch(len(cols["bucket"])), ex.state
+
+
 @pytest.fixture(scope="module")
 def trace():
     return run_functional(get_benchmark("mcf"), 3000)
@@ -100,9 +104,7 @@ def test_fused_matches_scan_ref(n, chunk):
     rng = np.random.default_rng(n * 31 + chunk)
     t = _random_trace(n, rng)
     cols = trace_columns(t, FCFG)
-    feats, state = fused_feature_columns(
-        cols, init_fused_state(FCFG), FCFG, chunk=chunk
-    )
+    feats, state = _fused_pass(cols, chunk=chunk)
     outcome = np.where(
         t["is_branch"], np.where(t["taken"], 1.0, -1.0), 0.0
     ).astype(np.float32)
@@ -114,7 +116,7 @@ def test_fused_matches_scan_ref(n, chunk):
     )
     _assert_bitwise(feats["brhist"], ref["brhist"], "brhist")
     _assert_bitwise(
-        feats["memdist"], signed_log_device(ref["memdist_raw"]), "memdist"
+        feats["memdist"], signed_log_device(ref["memdist_raw"], np.int32(0)), "memdist"
     )
     # carried state agrees too (table float-exact, queue/fill integer);
     # lanes past N_q / N_m are the kernel's vreg padding
@@ -128,7 +130,7 @@ def test_fused_matches_staged_bitwise(bench):
     t = run_functional(get_benchmark(bench), 2500)
     cols = trace_columns(t, FCFG)
     staged = device_feature_arrays(cols, FCFG)
-    fused, _ = fused_feature_columns(cols, init_fused_state(FCFG), FCFG)
+    fused, _ = _fused_pass(cols)
     for f in FEATURE_FIELDS:
         _assert_bitwise(fused[f], staged[f], f"{bench}/{f}")
 
@@ -143,7 +145,7 @@ def test_fused_collision_and_boundary_geometry():
     ):
         cols = trace_columns(t, FCFG)
         staged = device_feature_arrays(cols, FCFG)
-        fused, _ = fused_feature_columns(cols, init_fused_state(FCFG), FCFG)
+        fused, _ = _fused_pass(cols)
         for f in FEATURE_FIELDS:
             _assert_bitwise(fused[f], staged[f], f)
 
@@ -154,7 +156,7 @@ def test_fused_state_threading_across_batches():
     rng = np.random.default_rng(11)
     t = _random_trace(3000, rng)
     cols = trace_columns(t, FCFG)
-    one, _ = fused_feature_columns(cols, init_fused_state(FCFG), FCFG)
+    one, _ = _fused_pass(cols)
 
     ex = FusedExtractor(cols, FCFG, pad_to=3300)
     got = {f: [] for f in FEATURE_FIELDS}
@@ -244,6 +246,35 @@ def test_fused_shares_compiled_step_across_backends(params, trace):
     assert e_np.num_compiles == 1
     assert e_fu.num_compiles == 1          # same shared _CachedStep entry
     assert cache_stats()["entries"] == before + 1
+
+
+def test_engine_fused_extraction_compiles_once_launches_per_batch(params, monkeypatch):
+    """The fused backend's extraction is one compiled program for every
+    trace length of a geometry, dispatched once per batch."""
+    from repro.kernels.fused import ops
+
+    program = ops._fused_padded
+    launches = []
+
+    def counting(*args, **kwargs):
+        launches.append(kwargs["shape"])
+        return program(*args, **kwargs)
+
+    monkeypatch.setattr(ops, "_fused_padded", counting)
+    # a batch size no other test uses: this geometry's program is new here
+    bsz = 5
+    e = StreamingEngine(
+        params, CFG, EngineConfig(batch_size=bsz, feature_backend="fused")
+    )
+    before = program._cache_size()
+    batches = []
+    for n in (400, 1300):
+        launches.clear()
+        e.simulate(run_functional(get_benchmark("mcf"), n))
+        batches.append(-(-(n // CFG.window) // bsz))
+        assert launches == [(bsz, CFG.window)] * batches[-1], n
+    assert batches[0] != batches[1]
+    assert program._cache_size() == before + 1
 
 
 def test_engine_rejects_unknown_precision(params):

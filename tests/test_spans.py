@@ -13,7 +13,6 @@ import os
 import re
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -23,7 +22,7 @@ from repro.core.transfer import warmup_train_step
 from repro.engine import EngineConfig, StreamingEngine
 from repro.engine.runner import prefetch_to_device
 from repro.kernels.features.ops import kernel_chunk, trace_columns
-from repro.kernels.fused.ops import _COLUMN_KEYS, _fused_padded, init_fused_state
+from repro.kernels.fused.ops import _fused_padded, _pack, init_fused_state
 from repro.spans import call_span
 from repro.uarch import UARCH_A
 from repro.uarch.isa import FUNC_TRACE_DTYPE
@@ -171,8 +170,8 @@ def test_executable_names_the_trace_readers_match(engine, params):
     cols = trace_columns(np.zeros(64, dtype=FUNC_TRACE_DTYPE), FCFG)
     state = init_fused_state(FCFG)
     fused = _fused_padded.lower(
-        {k: jnp.asarray(cols[k]) for k in _COLUMN_KEYS}, state["table"], state["queue"],
-        n_queue=FCFG.n_queue, n_mem=FCFG.n_mem, n_flags=FCFG.flags_dim,
+        _pack(cols, 0, 64), state["table"], state["queue"], np.array([64, 0], np.int32),
+        shape=(BATCH, 8), n_queue=FCFG.n_queue, n_mem=FCFG.n_mem, n_flags=FCFG.flags_dim,
         chunk=kernel_chunk(64), interpret=True).compile()
     assert module_name(fused) == "jit__fused_padded"
 

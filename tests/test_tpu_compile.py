@@ -75,15 +75,12 @@ def _on(sharding, tree):
 )
 def test_fused_megakernel_compiles(one_chip, fc, window):
     n = BATCH * window
-    cols = {
-        k: jax.ShapeDtypeStruct((n,), jnp.bool_ if k.startswith(("is_", "taken")) else jnp.int32)
-        for k in _COLUMN_KEYS
-    }
+    packed = jax.ShapeDtypeStruct((len(_COLUMN_KEYS), n), jnp.int32)
+    counts = jax.ShapeDtypeStruct((2,), jnp.int32)
     state = jax.eval_shape(lambda: init_fused_state(fc))
     lowered = _fused_padded.lower(
-        _on(one_chip, cols),
-        *_on(one_chip, [state["table"], state["queue"]]),
-        n_queue=fc.n_queue, n_mem=fc.n_mem, n_flags=fc.flags_dim,
+        *_on(one_chip, [packed, state["table"], state["queue"], counts]),
+        shape=(BATCH, window), n_queue=fc.n_queue, n_mem=fc.n_mem, n_flags=fc.flags_dim,
         chunk=DEFAULT_CHUNK, interpret=False,
     )
     assert "tpu_custom_call" in lowered.as_text()
