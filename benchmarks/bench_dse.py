@@ -6,6 +6,7 @@ scheduling")."""
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import numpy as np
 
@@ -95,10 +96,13 @@ def run_sweep() -> None:
     models = {ua.name: _model_for(ua) for ua in space}
     traces = {b: sess.capture(b, TEST_LEN) for b in TEST_BENCHES[:2]}
 
-    # warm the shared step once so BOTH paths below measure steady-state
-    # throughput (neither is charged the one-off XLA compile)
+    # warm both steps once so BOTH paths below measure steady-state
+    # throughput (neither is charged the one-off XLA compile): the
+    # one-model step of the sequential path, and the stacked step that
+    # runs every model of the sweep over each batch
     first = next(iter(models.values()))
     first.simulate(next(iter(traces.values())), batch_size=sess.batch_size)
+    sess.warmup([len(tr.functional) for tr in traces.values()], heads=len(models))
 
     # baseline: the single-trace engine path, sequential over the same jobs
     # (per-trace host feature prep repeats per model on the critical path).
@@ -138,12 +142,17 @@ def run_sweep() -> None:
         f"queue_depth={report.queue_depth};"
         f"prepared_async={report.prepared_async}",
     )
-    # predictions from the sweep match the single-engine path exactly
+    # predictions from the sweep match the single-engine path to a relative
+    # 1e-6: the stacked step is its own XLA program, whose float32 sums may
+    # round apart in the last bits (tests/test_sweep_stacked.py)
     for name, model in models.items():
         for tb, tr in traces.items():
             a = report.results[f"{name}/{tb}"]
             b = model.simulate(tr, batch_size=sess.batch_size)
-            assert a.cpi == b.cpi and a.l1d_mpki == b.l1d_mpki, (name, tb)
+            for m in ("cpi", "l1d_mpki"):
+                assert math.isclose(
+                    a.metrics[m], b.metrics[m], rel_tol=1e-6, abs_tol=0.0
+                ), (name, tb, m)
 
 
 # ---------------------------------------------------------------------------

@@ -58,6 +58,9 @@ WIRE_SCHEMAS: Dict[str, WireSchema] = {
                 "features_extracted",
                 "features_from_store",
                 "jobs_skipped",
+                "heads_per_step",
+                "extractions",
+                "stacks_built",
                 "results",
             }
         ),

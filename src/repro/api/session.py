@@ -62,7 +62,7 @@ from ..engine.aot import enable_persistent_cache, persistent_cache_status
 from ..engine.metrics import DEFAULT_METRICS, MetricSpec
 from ..engine.plan import ExecutionPlan
 from ..engine.runner import EngineConfig, SimulationResult, StreamingEngine
-from ..engine.scheduler import SweepJob, SweepReport, TraceSweeper
+from ..engine.scheduler import SweepJob, SweepReport, TraceSweeper, abstract_params
 from ..store import (
     ArtifactStore,
     array_digest,
@@ -602,6 +602,9 @@ class Session:
         # ground_truth and dataset share one detailed-sim run per pair (the
         # most expensive operation in the workflow)
         self._detailed: Dict[tuple, tuple] = {}
+        # (engine config, depth, async_prepare) -> the sweeper, which keeps
+        # the last stacked model set (Session.sweep)
+        self._sweeper: Optional[Tuple[tuple, TraceSweeper]] = None
 
     # ---- step 1: reusable functional traces ----------------------------
 
@@ -1009,11 +1012,16 @@ class Session:
         plan: Optional[ExecutionPlan] = None,
         resume_key: Optional[str] = None,
     ) -> SweepReport:
-        """Async DSE sweep: every (model, trace) pair streams through one
-        shared compiled step; each distinct trace is prepared once (shared
-        across models) and — on accelerator backends — the next trace's
-        host-side prep is double-buffered behind the device execution of
-        the current one.  Result keys are ``model/trace``.
+        """Async DSE sweep, trace-major: every model's params are stacked
+        once per model set (kept across calls, placed as the plan places
+        params), and each trace runs as one simulate in which every batch
+        is extracted once and one stacked step runs every model over it
+        (``report.heads_per_step``, ``report.extractions``: traces x
+        batches on the fused backend; ``report.stacks_built``).  On
+        accelerator backends the next trace's host-side prep is
+        double-buffered behind the device execution of the current one.
+        A one-model sweep runs the one-model step.  Result keys are
+        ``model/trace``.
 
         Sharded sweeps compose the trace queue with an ``ExecutionPlan``:
         pass ``plan=``/``mesh=`` (or construct the session with one) and
@@ -1050,10 +1058,15 @@ class Session:
             for mn, model in models.items()
             for tn, tr in traces.items()
         ]
-        return TraceSweeper(
-            self.cfg, ecfg, depth=depth, async_prepare=async_prepare,
-            store=self.store,
-        ).run(jobs, resume_key=resume_key)
+        # the last sweeper is kept: it holds the stacked model set, so
+        # back-to-back sweeps over the same models stack them once
+        skey = (ecfg, depth, async_prepare)
+        if self._sweeper is None or self._sweeper[0] != skey:
+            self._sweeper = (skey, TraceSweeper(
+                self.cfg, ecfg, depth=depth, async_prepare=async_prepare,
+                store=self.store,
+            ))
+        return self._sweeper[1].run(jobs, resume_key=resume_key)
 
     # ---- zero cold start ------------------------------------------------
 
@@ -1065,6 +1078,7 @@ class Session:
         train: Union[None, bool, Iterable[Dict]] = None,
         metrics: Optional[Metrics] = None,
         collect: bool = False,
+        heads: int = 1,
     ) -> Dict[str, object]:
         """AOT-compile the session's executables for a declared geometry
         set before any trace, params, or dataset exists.
@@ -1078,7 +1092,10 @@ class Session:
         ``Session(store=...)``), the executables serialize to disk — a
         later process calling ``warmup`` with the same geometries
         deserializes instead of compiling, and its first ``simulate``/
-        ``train`` hits a ready executable: zero cold start."""
+        ``train`` hits a ready executable: zero cold start.  ``heads``
+        warms the stacked step that a ``sweep`` over that many models
+        runs (1: the one-model step of ``simulate`` and one-model
+        sweeps)."""
         mets = tuple(metrics) if metrics is not None else DEFAULT_METRICS
         plan_list = list(plans) if plans is not None else [self.plan]
         geos = []
@@ -1088,9 +1105,7 @@ class Session:
             else:
                 n, bs = g, self.batch_size
             geos.append((int(n), int(bs)))
-        abstract = jax.eval_shape(
-            functools.partial(init_tao, cfg=self.cfg), jax.random.PRNGKey(0)
-        )
+        abstract = abstract_params(self.cfg, heads)
         engines: Dict[tuple, StreamingEngine] = {}
         compiled = 0
         aot = 0
@@ -1107,7 +1122,7 @@ class Session:
                         plan=plan,
                         metrics=mets,
                     )
-                    eng = StreamingEngine(abstract, self.cfg, ecfg)
+                    eng = StreamingEngine(abstract, self.cfg, ecfg, heads=heads)
                     engines[ekey] = eng
                 entry = eng.warmup(n)
                 compiled += 1

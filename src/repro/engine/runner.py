@@ -39,6 +39,13 @@ Design points (each measured by ``benchmarks/bench_timing.py``):
     raw columns with the scan state carried across batches — features only
     ever exist at batch granularity, never as an O(trace) FeatureSet in
     HBM.  Still bit-identical; all three backends share the step cache.
+  * **Stacked design points.**  ``heads=K`` (K >= 2) takes a params tree
+    whose every leaf carries a leading axis of K models of one shape (the
+    §4.3 design-space sweep: per-design heads over one embedding).  The
+    step maps the one-model body over params and carry with the batch
+    shared, so each batch is extracted once and every head runs over it
+    in one launch; ``simulate_heads`` returns one result per head.  K is part
+    of the step-cache key; a one-model engine keeps its own step.
   * **Precision.**  ``precision="int8"`` swaps the step's forward for the
     W8A8 quantized twin (``core/quant.py``): per-channel int8 weights +
     dynamic per-row int8 activations with int32 accumulation.  The
@@ -54,10 +61,11 @@ the test suite holds the engine to.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import queue
 import threading
 import time
-from typing import Dict, Iterator, Optional, Tuple, Union
+from typing import Dict, Iterator, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -449,6 +457,13 @@ def clear_step_cache() -> int:
     return n
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _per_head(tree, heads: int):
+    """``tree`` repeated on a new leading axis of ``heads`` (one carry per
+    head), in one dispatch."""
+    return jax.tree.map(lambda x: jnp.broadcast_to(x, (heads,) + x.shape), tree)
+
+
 class StreamingEngine:
     """Compile once, stream any number of traces.
 
@@ -465,6 +480,7 @@ class StreamingEngine:
         ecfg: EngineConfig = EngineConfig(),
         *,
         qparams: Optional[Dict] = None,
+        heads: int = 1,
     ):
         if ecfg.batch_size < 1:
             raise ValueError(f"batch_size must be >= 1, got {ecfg.batch_size}")
@@ -482,6 +498,19 @@ class StreamingEngine:
             raise ValueError(
                 f"feature_chunk must be >= 1, got {ecfg.feature_chunk}"
             )
+        if heads < 1:
+            raise ValueError(f"heads must be >= 1, got {heads}")
+        if heads > 1:
+            lead = {
+                tuple(getattr(x, "shape", ()))[:1]
+                for x in jax.tree_util.tree_leaves(params)
+            }
+            if lead != {(heads,)}:
+                raise ValueError(
+                    f"heads={heads} needs every params leaf stacked on a "
+                    f"leading axis of {heads} models; leading axes {sorted(lead)}"
+                )
+        self.heads = heads
         self._specs: Tuple[MetricSpec, ...] = resolve_metrics(ecfg.metrics)
         for s in self._specs:
             if s.name == _GRID_KEY:
@@ -502,6 +531,9 @@ class StreamingEngine:
         # from the fp32 params otherwise when precision="int8"
         self._qparams = qparams
         self._steps: Dict[int, _CachedStep] = {}  # effective window -> step
+        # extraction programs launched (fused: one per batch; pallas: one
+        # per trace), for the sweep scheduler's counters
+        self.extractions = 0
 
     @property
     def num_compiles(self) -> int:
@@ -587,18 +619,24 @@ class StreamingEngine:
                 per = {}
             return new_carry, per
 
+        step = body
+        if self.heads > 1:
+            # every head over the one shared batch: params and carry carry
+            # the leading K axis, per-instruction outputs come out (K, rows)
+            step = jax.vmap(body, in_axes=(0, 0, None))
         if not plan.sharded:
-            return jax.jit(body)
+            return jax.jit(step)
 
         batched = plan.batch_spec()
         batch_specs = {
             k: batched for k in INPUT_KEYS + ("valid", "is_branch", "is_mem")
         }
+        per_spec = P(None, *batched) if self.heads > 1 else batched
         per_specs = (
-            {k: batched for k in PER_INSTRUCTION_KEYS} if collect else {}
+            {k: per_spec for k in PER_INSTRUCTION_KEYS} if collect else {}
         )
         mapped = plan.wrap(
-            body,
+            step,
             in_specs=(P(), P(), batch_specs),
             out_specs=(P(), per_specs),
         )
@@ -623,6 +661,8 @@ class StreamingEngine:
                 self._specs,
                 w_eff,
             )
+            if self.heads > 1:  # a one-model engine keeps its step's key
+                key += (self.heads,)  # tao: step-key[engine-step]
             entry = _STEP_CACHE.get(key)
             if entry is None:
                 fault_point("engine.compile", payload=f"w{w_eff}")
@@ -662,6 +702,8 @@ class StreamingEngine:
             "seen": jnp.zeros((), jnp.int32),
             "total": jnp.asarray(nw, jnp.int32),
         }
+        if self.heads > 1:
+            carry = _per_head(carry, self.heads)
         # placed where the step's replicated output carry lives, so the
         # first call and every later one trace to the same program
         return self.plan.replicate(carry)
@@ -737,11 +779,15 @@ class StreamingEngine:
             return self.params
         q = self._qparams
         if q is None:
+            quantize = (
+                jax.vmap(quantize_tao_params) if self.heads > 1
+                else quantize_tao_params
+            )
             leaves = jax.tree_util.tree_leaves(self.params)
             if any(isinstance(x, jax.ShapeDtypeStruct) for x in leaves):
-                q = jax.eval_shape(quantize_tao_params, self.params)
+                q = jax.eval_shape(quantize, self.params)
             else:
-                q = quantize_tao_params(self.params)
+                q = quantize(self.params)
             self._qparams = q
         return q
 
@@ -817,16 +863,33 @@ class StreamingEngine:
         for _ in range(nb):
             with span("fused.extract"):
                 batch = extractor.next_batch(per, (bsz, w_eff))
+                self.extractions += 1
                 if self.plan.sharded:
                     batch = self.plan.device_put(batch)
             yield batch
 
-    # tao: hot
     def simulate(
         self,
         func_trace: np.ndarray,
         features: Optional[FeatureSet] = None,
     ) -> SimulationResult:
+        """Stream one trace through a one-model engine's step."""
+        if self.heads > 1:
+            raise ValueError(
+                f"a {self.heads}-head engine gives one result per head: "
+                "use simulate_heads"
+            )
+        return self.simulate_heads(func_trace, features)[0]
+
+    # tao: hot
+    def simulate_heads(
+        self,
+        func_trace: np.ndarray,
+        features: Optional[FeatureSet] = None,
+    ) -> List[SimulationResult]:
+        """Stream one trace through the step: one ``SimulationResult`` per
+        head, in the order of the stacked axis (one for a one-model
+        engine)."""
         t0 = time.perf_counter()
         fault_point("engine.simulate")
         cfg = self.cfg
@@ -843,6 +906,7 @@ class StreamingEngine:
             instructions=count,
             positions=nb * bsz * w_eff,
             batches=nb,
+            heads=self.heads,
         ):
             entry = self._get_step(w_eff)
             # AOT-warmed geometry: call the compiled executable directly (no
@@ -875,6 +939,7 @@ class StreamingEngine:
                         dev_arrays = device_feature_arrays(
                             cols, cfg.features, chunk=self.ecfg.feature_chunk
                         )
+                    self.extractions += 1
             if fs is None and dev_arrays is None and fused_batches is None:
                 with span("engine.columns"):
                     fs = extract_features(func_trace, cfg.features, with_labels=False)
@@ -914,42 +979,57 @@ class StreamingEngine:
 
             with span("engine.sync"):
                 carry = jax.device_get(carry)  # single host sync for the whole trace
-                metrics: Dict[str, float] = {}
-                for s in self._specs:
-                    out = s.finalize(carry[s.name], count)
-                    clash = set(out) & set(metrics)
-                    if clash:
-                        raise ValueError(
-                            f"metric spec {s.name!r} finalized key(s) {sorted(clash)} "
-                            "already emitted by an earlier spec in this run"
-                        )
-                    reserved = set(out) & _RESERVED_RESULT_ATTRS
-                    if reserved:
-                        raise ValueError(
-                            f"metric spec {s.name!r} finalized reserved key(s) "
-                            f"{sorted(reserved)}: SimulationResult instance "
-                            "attributes would shadow them"
-                        )
-                    metrics.update(out)
+                if self.heads == 1:
+                    finalized = [self._finalize(carry, count)]
+                else:
+                    finalized = [
+                        self._finalize(jax.tree.map(lambda x: x[h], carry), count)
+                        for h in range(self.heads)
+                    ]
                 secs = time.perf_counter() - t0
 
-                arrays: Dict[str, Optional[np.ndarray]] = {
-                    k: None for k in PER_INSTRUCTION_KEYS
-                }
                 if self.ecfg.collect and pers:
                     # one explicit sync for every batch's arrays (was a hidden
                     # np.asarray device->host pull per batch per key)
                     pers = jax.device_get(pers)
-                    for k in arrays:
-                        arrays[k] = np.concatenate([p[k] for p in pers])[:count]
+                results = []
+                for h, metrics in enumerate(finalized):
+                    arrays: Dict[str, Optional[np.ndarray]] = {
+                        k: None for k in PER_INSTRUCTION_KEYS
+                    }
+                    if self.ecfg.collect and pers:
+                        for k in arrays:
+                            parts = [p[k] if self.heads == 1 else p[k][h] for p in pers]
+                            arrays[k] = np.concatenate(parts)[:count]
+                    results.append(SimulationResult(
+                        num_instructions=count,
+                        seconds=secs,
+                        mips=count / 1e6 / secs,
+                        metrics=metrics,
+                        arrays=arrays,
+                    ))
+                return results
 
-                return SimulationResult(
-                    num_instructions=count,
-                    seconds=secs,
-                    mips=count / 1e6 / secs,
-                    metrics=metrics,
-                    arrays=arrays,
+    def _finalize(self, carry: Dict, count: int) -> Dict[str, float]:
+        """One head's host-side carry -> its finalized metrics."""
+        metrics: Dict[str, float] = {}
+        for s in self._specs:
+            out = s.finalize(carry[s.name], count)
+            clash = set(out) & set(metrics)
+            if clash:
+                raise ValueError(
+                    f"metric spec {s.name!r} finalized key(s) {sorted(clash)} "
+                    "already emitted by an earlier spec in this run"
                 )
+            reserved = set(out) & _RESERVED_RESULT_ATTRS
+            if reserved:
+                raise ValueError(
+                    f"metric spec {s.name!r} finalized reserved key(s) "
+                    f"{sorted(reserved)}: SimulationResult instance "
+                    "attributes would shadow them"
+                )
+            metrics.update(out)
+        return metrics
 
 
 def simulate_trace_engine(

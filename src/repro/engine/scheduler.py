@@ -1,12 +1,9 @@
 """Async multi-trace sweep scheduler (design-space exploration fast path).
 
-The streaming engine already reuses one compiled step across traces and —
-because params are an *argument* of the jitted step — across every model of
-the same shape.  This module adds the missing piece for DSE sweeps
-(ROADMAP "async multi-trace scheduling"): a double-buffered trace queue
-that overlaps the host-side work of trace i+1 (feature extraction +
-window-view setup) with the device execution of trace i, so the device
-never waits on the host pre-pass between traces.
+A design-space sweep simulates a few functional traces on many design
+points whose models share one shape (§4.3: per-design adapt+pred heads
+over one µarch-agnostic embedding).  The scheduler makes the trace the
+unit of work and the design points an axis of it:
 
     sweeper = TraceSweeper(cfg, EngineConfig(batch_size=64))
     report = sweeper.run([
@@ -17,17 +14,28 @@ never waits on the host pre-pass between traces.
     ])
     report.results["l1d16/mcf"].l1d_mpki
     report.num_compiles        # == 1 per effective-window geometry
-    report.traces_per_s, report.queue_occupancy_mean
+    report.heads_per_step      # design points each step ran over one batch
+    report.extractions         # device extraction programs: traces x batches
 
-A producer thread prepares jobs into a bounded queue (``depth`` slots —
-2 = classic double buffering); the consumer streams each prepared trace
-through a per-params ``StreamingEngine`` whose jitted step comes from the
-process-wide step cache, so the whole sweep compiles once per window
-geometry no matter how many (model, trace) pairs it covers.  Each distinct
-trace's features are extracted once and shared across every model
-(sequential per-model engines re-extract per pair).  On CPU-only backends
-the producer thread would contend with the step's own compute for the same
-cores, so preparation runs inline there (``async_prepare`` overrides).
+**Trace-major.**  Jobs are grouped by trace (the same trace array).  Each
+group runs as ONE ``StreamingEngine.simulate`` over the stacked params of
+every model with a job on that trace (``StreamingEngine(heads=K)``): the
+trace's columns and its per-batch extraction program run once per
+(trace, batch) on the fused backend, its host features once per distinct
+trace content on the NumPy backend, and one step per batch runs every
+head.  A group of one model keeps the one-model step.  The stacked tree
+is built once per model set and kept by the sweeper (``stacks_built``),
+placed as the plan places params, so repeated sweeps over the same
+models restack nothing.
+
+**Double buffering.**  A producer thread prepares groups into a bounded
+queue (``depth`` slots — 2 = classic double buffering), overlapping the
+host-side work of trace i+1 with the device execution of trace i.  On
+CPU-only backends the producer thread would contend with the step's own
+compute for the same cores, so preparation runs inline there
+(``async_prepare`` overrides).  Every step comes from the process-wide
+step cache, so the whole sweep compiles once per (window geometry, head
+count) no matter how many (model, trace) pairs it covers.
 """
 from __future__ import annotations
 
@@ -36,20 +44,24 @@ import functools
 import queue
 import threading
 import time
-from typing import Dict, Iterable, List, Optional, Tuple, Union
+import weakref
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
+from ..core.dataset import num_windows
 from ..core.features import FeatureSet, extract_features
 from ..core.model import TaoConfig
 from ..resilience.faults import fault_point
+from ..spans import call_span, span
 from ..store.content import array_digest, config_token, content_key, tree_digest
 from .metrics import resolve_metrics
 from .plan import ExecutionPlan
 from .runner import EngineConfig, SimulationResult, StreamingEngine
 
-__all__ = ["SweepJob", "SweepReport", "TraceSweeper", "sweep_traces"]
+__all__ = ["SweepJob", "SweepReport", "TraceSweeper", "stack_params", "sweep_traces"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,6 +102,15 @@ class SweepReport:
     # published by an earlier, possibly killed, run with the same
     # resume_key) — skipped entirely: no extraction, no device work
     jobs_skipped: int = 0
+    # design points each step ran over one batch, averaged over the steps
+    # (the models stacked per trace; 1 for a one-model sweep)
+    heads_per_step: float = 0.0
+    # device extraction programs launched (fused: one per (trace, batch);
+    # pallas: one per trace; numpy: none, see features_extracted)
+    extractions: int = 0
+    # stacked params trees built by this sweep (0 when the sweeper's kept
+    # stack served every trace)
+    stacks_built: int = 0
 
     def stats(self) -> Dict[str, Union[float, int, str]]:
         return {
@@ -103,6 +124,9 @@ class SweepReport:
             "features_extracted": self.features_extracted,
             "features_from_store": self.features_from_store,
             "jobs_skipped": self.jobs_skipped,
+            "heads_per_step": self.heads_per_step,
+            "extractions": self.extractions,
+            "stacks_built": self.stacks_built,
         }
 
     def to_dict(self) -> Dict:
@@ -121,6 +145,41 @@ class SweepReport:
 
 
 _STOP = object()
+
+
+_stack_leaf = jax.jit(jnp.stack)
+
+
+def stack_params(trees: Sequence[Dict]) -> Dict:
+    """K params trees of one shape -> one tree whose leaves carry a leading
+    axis of K models (what ``StreamingEngine(heads=K)`` runs).  One
+    compiled program per distinct leaf shape (the published model has 20):
+    a single program over every leaf of 32 models (3,136 inputs) takes
+    about a minute to compile on a 4-chip v5e host, and eager stacking
+    compiles several programs per shape."""
+    return jax.tree.map(lambda *xs: _stack_leaf(xs), *trees)
+
+
+def abstract_params(cfg: TaoConfig, heads: int = 1) -> Dict:
+    """The shapes of a model's params (``heads`` > 1: of ``heads`` models
+    stacked), with nothing computed: what AOT warm-up lowers from."""
+    from ..core.model import init_tao
+
+    one = jax.eval_shape(functools.partial(init_tao, cfg=cfg), jax.random.PRNGKey(0))
+    return one if heads == 1 else jax.eval_shape(stack_params, [one] * heads)
+
+
+def _leaf_refs(trees: Sequence[Dict]) -> List:
+    """Weak references to every leaf (held strongly where a leaf takes
+    none), so a kept stack can tell its models are still the same objects
+    without keeping them alive."""
+    refs = []
+    for leaf in jax.tree_util.tree_leaves(trees):
+        try:
+            refs.append(weakref.ref(leaf))
+        except TypeError:
+            refs.append(lambda leaf=leaf: leaf)
+    return refs
 
 
 class TraceSweeper:
@@ -160,20 +219,24 @@ class TraceSweeper:
         # content-addressed artifact store (repro.store.ArtifactStore):
         # inference features persist/load across processes through it
         self.store = store
+        # the last stacked model set: (treedef, leaf refs, engine); one kept,
+        # dropped before the next is built, so at most one stack is resident
+        self._stack: Optional[tuple] = None
 
-    def warmup(self, trace_lengths: Iterable[int]) -> Dict[str, int]:
+    def warmup(
+        self, trace_lengths: Iterable[int], *, heads: int = 1
+    ) -> Dict[str, int]:
         """AOT-compile the sweep's step for a declared geometry set before
         any jobs (or even params) exist: abstract params from
         ``jax.eval_shape`` lower through ``StreamingEngine.warmup``, and —
         with the persistent compilation cache enabled — a process that
         warms the same geometries later deserializes instead of compiling.
+        ``heads`` is the number of models the sweep will run over each
+        trace (its stacked step; 1 warms the one-model step).
         Returns ``{"geometries": ..., "aot_compiled": ...}``."""
-        from ..core.model import init_tao
-
-        abstract = jax.eval_shape(
-            functools.partial(init_tao, cfg=self.cfg), jax.random.PRNGKey(0)
+        engine = StreamingEngine(
+            abstract_params(self.cfg, heads), self.cfg, self.ecfg, heads=heads
         )
-        engine = StreamingEngine(abstract, self.cfg, self.ecfg)
         entries = [engine.warmup(n) for n in sorted(set(trace_lengths))]
         return {
             "geometries": len(entries),
@@ -295,99 +358,84 @@ class TraceSweeper:
                     remaining.append(job)
             jobs = remaining
 
+        # trace-major: one group per trace array, in first-appearance order;
+        # each group is one simulate over every model it holds
+        by_trace: Dict[int, List[SweepJob]] = {}
+        for job in jobs:
+            by_trace.setdefault(id(job.trace), []).append(job)
+        groups = list(by_trace.values())
+
         # consumer state: engines share jitted steps via the process-wide
-        # step cache; one per params object so a model's engine is reused
-        # across its traces
+        # step cache; one-model engines per params object (reused across
+        # that model's traces), stacked ones kept by the sweeper
         engines: Dict[int, StreamingEngine] = {}
         entries: Dict[int, object] = {}   # id(_CachedStep) -> _CachedStep
         baseline: Dict[int, int] = {}     # compiles before this sweep used it
+        counts = {"stacks": 0, "extractions": 0, "steps": 0, "head_steps": 0}
 
-        def consume(job: SweepJob, features: Optional[FeatureSet]) -> None:
-            nonlocal n_instr
-            fault_point("scheduler.consume", payload=job.key)
-            engine = engines.get(id(job.params))
+        def engine_for(group: List[SweepJob]) -> StreamingEngine:
+            if len(group) > 1:
+                return self._stacked_engine([j.params for j in group], counts)
+            params = group[0].params
+            engine = engines.get(id(params))
             if engine is None:
-                engine = StreamingEngine(job.params, self.cfg, self.ecfg)
-                engines[id(job.params)] = engine
+                engine = StreamingEngine(params, self.cfg, self.ecfg)
+                engines[id(params)] = engine
+            return engine
+
+        def consume(
+            index: int, group: List[SweepJob], features: Optional[FeatureSet]
+        ) -> None:
+            nonlocal n_instr
+            for job in group:
+                fault_point("scheduler.consume", payload=job.key)
+            trace = group[0].trace
+            engine = engine_for(group)
             # snapshot the shared step entry BEFORE simulating, so the
             # report attributes only compiles this sweep triggered
-            entry = engine.step_entry_for(len(job.trace))
+            entry = engine.step_entry_for(len(trace))
             if id(entry) not in entries:
                 entries[id(entry)] = entry
                 baseline[id(entry)] = entry.compiles
-            res = engine.simulate(job.trace, features=features)
-            results[job.key] = res
-            n_instr += res.num_instructions
-            if resume_key is not None:
-                from ..resilience import manifest as _manifest
+            nw = num_windows(len(trace), self.cfg.window, self.cfg.window)
+            batches = -(-nw // self.ecfg.batch_size)
+            before = engine.extractions
+            with span("sweep.group", trace=index, heads=len(group), batches=batches):
+                out = engine.simulate_heads(trace, features=features)
+            counts["extractions"] += engine.extractions - before
+            counts["steps"] += batches
+            counts["head_steps"] += batches * len(group)
+            for job, res in zip(group, out):
+                results[job.key] = res
+                n_instr += res.num_instructions
+                if resume_key is not None:
+                    from ..resilience import manifest as _manifest
 
-                _manifest.publish_sweep_result(
-                    self.store, progress_keys[job.key], res
-                )
+                    _manifest.publish_sweep_result(
+                        self.store, progress_keys[job.key], res
+                    )
 
         t0 = time.perf_counter()
-        if not self.async_prepare:
-            # inline mode (CPU backends): no producer thread to contend with
-            # the step's compute; the feature dedup still applies
-            for job in jobs:
-                consume(job, self._prepare(job, feat_cache, digests, feat_counts))
-        else:
-            q: "queue.Queue" = queue.Queue(maxsize=self.depth)
-            error: List[BaseException] = []
-            stop = threading.Event()  # set when the consumer bails out early
-
-            def produce():
-                try:
-                    for job in jobs:
-                        prepared = self._prepare(
-                            job, feat_cache, digests, feat_counts
-                        )
-                        while not stop.is_set():
-                            try:
-                                q.put((job, prepared), timeout=0.1)
-                                break
-                            except queue.Full:
-                                continue
-                        if stop.is_set():
-                            return
-                except BaseException as e:  # surfaced in the consumer
-                    error.append(e)
-                finally:
-                    while True:  # always deliver _STOP without blocking
-                        try:
-                            q.put(_STOP, timeout=0.1)
-                            break
-                        except queue.Full:
-                            if stop.is_set():
-                                break
-
-            producer = threading.Thread(
-                target=produce, name="trace-sweep-producer", daemon=True
-            )
-            producer.start()
-            try:
-                while True:
-                    occ.append(q.qsize())
-                    item = q.get()
-                    if item is _STOP:
-                        break
-                    consume(*item)
-            finally:
-                # unblock the producer (it may be parked on a full queue)
-                # and drop any prepared-but-unconsumed feature arrays
-                stop.set()
-                while True:
-                    try:
-                        q.get_nowait()
-                    except queue.Empty:
-                        break
-            producer.join()
-            if error:
-                raise error[0]
+        with call_span(
+            "sweep.call",
+            jobs=len(jobs),
+            traces=len(groups),
+            heads=len({id(j.params) for j in jobs}),
+        ):
+            if not self.async_prepare:
+                # inline mode (CPU backends): no producer thread to contend
+                # with the step's compute; the feature dedup still applies
+                for i, group in enumerate(groups):
+                    consume(
+                        i, group,
+                        self._prepare(group[0], feat_cache, digests, feat_counts),
+                    )
+            else:
+                self._run_async(groups, consume, feat_cache, digests, feat_counts, occ)
         secs = time.perf_counter() - t0
 
         return SweepReport(
-            results=results,
+            results={k: results[k] for k in keys},
             seconds=secs,
             num_traces=n_total,
             num_instructions=n_instr,
@@ -405,7 +453,91 @@ class TraceSweeper:
             features_extracted=feat_counts["extracted"],
             features_from_store=feat_counts["from_store"],
             jobs_skipped=skipped,
+            heads_per_step=(
+                counts["head_steps"] / counts["steps"] if counts["steps"] else 0.0
+            ),
+            extractions=counts["extractions"],
+            stacks_built=counts["stacks"],
         )
+
+    def _run_async(self, groups, consume, feat_cache, digests, feat_counts, occ):
+        """The producer thread prepares each group ahead of the consumer
+        (this thread) through a bounded queue."""
+        q: "queue.Queue" = queue.Queue(maxsize=self.depth)
+        error: List[BaseException] = []
+        stop = threading.Event()  # set when the consumer bails out early
+
+        def produce():
+            try:
+                for i, group in enumerate(groups):
+                    prepared = self._prepare(
+                        group[0], feat_cache, digests, feat_counts
+                    )
+                    while not stop.is_set():
+                        try:
+                            q.put((i, group, prepared), timeout=0.1)
+                            break
+                        except queue.Full:
+                            continue
+                    if stop.is_set():
+                        return
+            except BaseException as e:  # surfaced in the consumer
+                error.append(e)
+            finally:
+                while True:  # always deliver _STOP without blocking
+                    try:
+                        q.put(_STOP, timeout=0.1)
+                        break
+                    except queue.Full:
+                        if stop.is_set():
+                            break
+
+        producer = threading.Thread(
+            target=produce, name="trace-sweep-producer", daemon=True
+        )
+        producer.start()
+        try:
+            while True:
+                occ.append(q.qsize())
+                item = q.get()
+                if item is _STOP:
+                    break
+                consume(*item)
+        finally:
+            # unblock the producer (it may be parked on a full queue)
+            # and drop any prepared-but-unconsumed feature arrays
+            stop.set()
+            while True:
+                try:
+                    q.get_nowait()
+                except queue.Empty:
+                    break
+        producer.join()
+        if error:
+            raise error[0]
+
+    def _stacked_engine(self, params: List[Dict], counts: Dict[str, int]) -> StreamingEngine:
+        """The engine over ``params`` stacked on a leading axis: the kept
+        one while every leaf is still the same object, else built anew
+        (the kept stack dropped first) and placed as the plan places
+        params."""
+        treedef = jax.tree_util.tree_structure(params)
+        leaves = jax.tree_util.tree_leaves(params)
+        kept = self._stack
+        if (
+            kept is not None
+            and kept[0] == treedef
+            and all(r() is x for r, x in zip(kept[1], leaves))
+        ):
+            return kept[2]
+        self._stack = None
+        engine = StreamingEngine(
+            self.plan.replicate(stack_params(params)), self.cfg, self.ecfg,
+            heads=len(params),
+        )
+        self._stack = (treedef, _leaf_refs(params), engine)
+        counts["stacks"] += 1
+        return engine
 
 
 def sweep_traces(

@@ -1,5 +1,5 @@
-"""Profiler spans at the layer boundaries of the two hot paths: the
-simulation engine and transfer training.
+"""Profiler spans at the layer boundaries of the hot paths: the
+simulation engine, the design-space sweep and transfer training.
 
 A span is a ``jax.profiler.TraceAnnotation`` named ``tao/<layer>.<part>``.
 While no profiler runs it costs well under a microsecond on the host;
@@ -9,11 +9,11 @@ lines, so every gap in which the device idles can be put down to the span
 open at that moment.
 
 Every span carries ``call``: the identifier of the
-``StreamingEngine.simulate`` or ``train_tao_impl`` call it belongs to,
-drawn from one process-wide counter when the call opens.  A thread started
-through ``in_call`` (the prefetch producer) keeps its starter's call, so
-its spans name the same call as the consumer's.  Parentage is nesting on
-one thread.  The spans and what each covers: docs/engine.md, "Spans".
+``StreamingEngine.simulate``, ``TraceSweeper.run`` or ``train_tao_impl``
+call it belongs to, drawn from one process-wide counter when the call
+opens.  A thread started through ``in_call`` (the prefetch producer) keeps
+its starter's call, so its spans name the same call as the consumer's.
+Parentage is nesting on one thread.  The spans and what each covers: docs/engine.md, "Spans".
 """
 from __future__ import annotations
 

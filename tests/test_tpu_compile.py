@@ -130,3 +130,34 @@ def test_simulate_step_compiles_at_published_widths(topo, chips):
     mem = compiled.memory_analysis()
     # the chip holds 16 GB; the step needs ~0.1 GB of arguments + temporaries
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2 * 2**30
+
+
+def test_stacked_sweep_step_compiles_at_published_widths(topo):
+    """The design-space sweep's stacked step (``heads=32``: 32 models of the
+    published widths over one shared 64 x 129 batch) under the 4-chip data
+    plan, as the ``dse32.sweep-4chip`` cell runs it."""
+    from repro.engine.scheduler import stack_params
+
+    heads = 32
+    params = jax.eval_shape(
+        lambda: stack_params([init_tao(jax.random.PRNGKey(i), CONFIG) for i in range(heads)])
+    )
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(4), ("data",))
+    plan = ExecutionPlan.resolve(mesh, batch_size=BATCH)
+    whole = NamedSharding(mesh, PartitionSpec())
+    engine = StreamingEngine(
+        params, CONFIG, EngineConfig(batch_size=BATCH, plan=plan), heads=heads
+    )
+    n = 131_072
+    try:
+        compiled = engine.step_entry_for(n).fn.lower(
+            _on(whole, params),
+            _on(whole, jax.eval_shape(lambda: engine.init_carry(n))),
+            _on(plan.batch_sharding(), engine._abstract_batch(CONFIG.window)),
+        ).compile()
+    finally:
+        clear_step_cache()
+    mem = compiled.memory_analysis()
+    # per chip: the 32 stacked models (2.5 GB) and the step's temporaries,
+    # beside the 32 models the sweep holds replicated (2.5 GB), of 16 GB
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6 * 2**30
