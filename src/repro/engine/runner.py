@@ -17,7 +17,10 @@ Design points (each measured by ``benchmarks/bench_timing.py``):
     CPI / branch-MPKI / L1D-MPKI by default, anything plug-in code
     registers otherwise.  The instruction count comes from the window grid
     on host, and per-instruction arrays are only transferred when
-    ``EngineConfig.collect`` asks for them.
+    ``EngineConfig.collect`` asks for them.  The all-zero part of a
+    request's initial state (the specs' carries, the fused scan carry) is
+    built once per engine and kept (``state_builds``); per request the
+    trace's window count is the one new device value.
   * **Prefetch.**  The next batch's host->device transfer is enqueued before
     the current result is consumed, overlapping copy with compute.
   * **Partitioning.**  Every placement/wrapping/index-mapping decision is
@@ -534,6 +537,12 @@ class StreamingEngine:
         # extraction programs launched (fused: one per batch; pallas: one
         # per trace), for the sweep scheduler's counters
         self.extractions = 0
+        # the all-zero part of every request's initial device state, built
+        # on first use (``_zero_state``); everything it depends on — the
+        # feature config, specs, heads and plan — is fixed per engine
+        self._zeros: Optional[Tuple[Dict, Optional[Dict]]] = None
+        self._zeros_lock = threading.Lock()  # a server may simulate from two threads
+        self.state_builds = 0
 
     @property
     def num_compiles(self) -> int:
@@ -677,13 +686,46 @@ class StreamingEngine:
             _STEP_STATS["hits"] += 1
         return entry
 
+    def _zero_state(self) -> Tuple[Dict, Optional[Dict]]:
+        """The all-zero part of a request's initial device state: the step
+        carry without the grid's ``total`` (every spec's ``init()`` and the
+        grid's ``seen``, per head when ``heads > 1``, placed as the plan
+        places the carry) and, on the "fused" backend, the extraction's
+        scan carry.  Built once and handed to every request unchanged:
+        neither the step nor the extraction program donates its inputs.
+        A build under a trace (``jax.eval_shape`` over ``init_carry``)
+        yields tracers and is not kept."""
+        with self._zeros_lock:
+            if self._zeros is not None:
+                return self._zeros
+            carry = {s.name: s.init() for s in self._specs}
+            carry[_GRID_KEY] = {"seen": jnp.zeros((), jnp.int32)}
+            if self.heads > 1:
+                carry = _per_head(carry, self.heads)
+            carry = self.plan.replicate(carry)
+            scan = None
+            if self.ecfg.feature_backend == "fused":
+                from ..kernels.fused.ops import init_fused_state  # lazy: module note
+
+                scan = init_fused_state(self.cfg.features)
+            zeros = (carry, scan)
+            if not any(
+                isinstance(x, jax.core.Tracer) for x in jax.tree_util.tree_leaves(zeros)
+            ):
+                self._zeros = zeros
+                self.state_builds += 1
+            return zeros
+
     def init_carry(self, n: int) -> Dict:
         """The initial carry for a trace of ``n`` instructions: every
         requested spec's ``init()`` plus the engine's reserved window-grid
         slot (running window offset + total windows — what windowed specs
         scatter phase contributions with).  Code driving the jitted step
         directly (custom batch columns via ``stream_batches(extra=...)``)
-        must start from this, not a hand-built spec dict."""
+        must start from this, not a hand-built spec dict.
+
+        The zero leaves are the engine's kept ones (``_zero_state``); the
+        grid's ``total`` is the one new device value, one host put."""
         if n < 1:
             raise ValueError("cannot simulate an empty trace")
         nw = num_windows(n, self.cfg.window, self.cfg.window)
@@ -697,16 +739,15 @@ class StreamingEngine:
                     "chunk-index envelope; reduce num_chunks or split "
                     "the trace"
                 )
-        carry = {s.name: s.init() for s in self._specs}
-        carry[_GRID_KEY] = {
-            "seen": jnp.zeros((), jnp.int32),
-            "total": jnp.asarray(nw, jnp.int32),
-        }
-        if self.heads > 1:
-            carry = _per_head(carry, self.heads)
+        total = np.full((self.heads,) if self.heads > 1 else (), nw, np.int32)
         # placed where the step's replicated output carry lives, so the
         # first call and every later one trace to the same program
-        return self.plan.replicate(carry)
+        total = self.plan.replicate(total) if self.plan.sharded else jax.device_put(total)
+        # fresh containers over the kept leaves: a caller's edits to the
+        # returned dict never reach the next request
+        carry = jax.tree.map(lambda x: x, self._zero_state()[0])
+        carry[_GRID_KEY]["total"] = total
+        return carry
 
     def step_entry_for(self, n: int) -> _CachedStep:
         """The cached step entry ``simulate`` will use for a trace of
@@ -851,15 +892,15 @@ class StreamingEngine:
         bsz = self.ecfg.batch_size
         nb = -(-(count // w_eff) // bsz)
         per = bsz * w_eff
-        # a generator: this runs at the first next(), so the span opens
-        # here; no span stays open across a yield
-        with span("engine.upload"):
-            extractor = FusedExtractor(
-                {k: v[:count] for k, v in cols.items()},
-                self.cfg.features,
-                chunk=self.ecfg.feature_chunk,
-                pad_to=nb * per,
-            )
+        # the scan starts from the engine's kept zero carry: no device op
+        # here, the request's one put is init_carry's
+        extractor = FusedExtractor(
+            {k: v[:count] for k, v in cols.items()},
+            self.cfg.features,
+            chunk=self.ecfg.feature_chunk,
+            pad_to=nb * per,
+            state=self._zero_state()[1],
+        )
         for _ in range(nb):
             with span("fused.extract"):
                 batch = extractor.next_batch(per, (bsz, w_eff))
@@ -967,7 +1008,7 @@ class StreamingEngine:
 
             # specs' init plus the window-grid slot: running global window
             # offset + total real windows (data, not shape — every trace
-            # shares the executable)
+            # shares the executable); the zeros are the engine's kept ones
             with span("engine.upload"):
                 carry = self.init_carry(n)
             pers = []

@@ -150,6 +150,9 @@ class FusedExtractor:
     extraction program; it returns the model-input dict for the next ``m``
     positions, including the ``is_branch``/``is_mem`` bool columns the
     engine's step masks with and ``valid`` (0.0 on pad positions).
+    ``state`` is the scan carry to start from (default
+    ``init_fused_state(cfg)``); it is read, never written, so one zero
+    carry can start any number of extractors.
     """
 
     def __init__(
@@ -160,6 +163,7 @@ class FusedExtractor:
         chunk: int = DEFAULT_CHUNK,
         pad_to: Optional[int] = None,
         interpret: Optional[bool] = None,
+        state: Optional[Dict[str, jnp.ndarray]] = None,
     ):
         n = len(cols["bucket"])
         pad_to = n if pad_to is None else pad_to
@@ -176,7 +180,7 @@ class FusedExtractor:
         )
         self._pos = 0
         self._limit = pad_to
-        self.state = init_fused_state(cfg)
+        self.state = init_fused_state(cfg) if state is None else state
 
     # tao: hot
     def next_batch(

@@ -2,6 +2,7 @@
 zero-copy windowing vs the copying grid, legacy-vs-engine metric
 equivalence, and the one-compile guarantee."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -17,6 +18,7 @@ from repro.core import (
 )
 from repro.core.simulate import simulate_trace, simulate_trace_legacy
 from repro.engine import EngineConfig, MetricNotCollectedError, StreamingEngine
+from repro.engine.metrics import resolve_metrics
 from repro.uarch import get_benchmark, run_functional
 
 FCFG = FeatureConfig(n_buckets=32, n_queue=4, n_mem=8)
@@ -174,6 +176,68 @@ def test_engine_sharded_path_matches(params, trace):
     assert a.l1d_mpki == b.l1d_mpki
     legacy = simulate_trace_legacy(params, trace, CFG)
     np.testing.assert_allclose(b.fetch_lat, legacy.fetch_lat, rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The initial device state: kept per engine, one new value per request
+# ---------------------------------------------------------------------------
+
+BACKENDS = ("numpy", "pallas", "fused")
+LENGTHS = (3000, 1000, 40, 2999)
+
+
+def fresh_carry(ecfg, n):
+    """The initial carry built anew, every leaf a new array: what each
+    request started from before the engine kept its zeros."""
+    carry = {s.name: s.init() for s in resolve_metrics(ecfg.metrics)}
+    carry["__grid__"] = {
+        "seen": jnp.zeros((), jnp.int32),
+        "total": jnp.asarray(num_windows(n, CFG.window, CFG.window), jnp.int32),
+    }
+    return carry
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_engine_back_to_back_requests_match_fresh_engines(backend, params, trace):
+    """Requests of different lengths on one engine give, bit for bit, what
+    a fresh engine gives each of them, and build the zero state once.  A
+    later donation of the kept state would fail the second request; the
+    phase curve reads the grid's ``total``, so a stale one shows."""
+    ecfg = EngineConfig(batch_size=16, feature_backend=backend, collect=True,
+                        metrics=("cpi", "branch_mpki", "l1d_mpki", "cpi_phase"))
+    engine = StreamingEngine(params, CFG, ecfg)
+    got = [engine.simulate(trace[:n]) for n in LENGTHS]
+    assert engine.state_builds == 1
+    for n, g in zip(LENGTHS, got):
+        want = StreamingEngine(params, CFG, ecfg).simulate(trace[:n])
+        assert g.num_instructions == want.num_instructions
+        assert list(g.metrics) == list(want.metrics)
+        for k, v in want.metrics.items():
+            np.testing.assert_array_equal(g.metrics[k], v, err_msg=f"{n} {k}")
+        for k in ("fetch_lat", "exec_lat", "mispred_prob", "dlevel"):
+            np.testing.assert_array_equal(getattr(g, k), getattr(want, k), err_msg=f"{n} {k}")
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_init_carry_is_the_kept_zeros_and_a_fresh_total(backend, params):
+    """``init_carry(n)`` equals the carry built anew, leaf for leaf, with
+    the grid's ``total`` of ``n``; edits to a returned carry stay there."""
+    ecfg = EngineConfig(batch_size=16, feature_backend=backend)
+    engine = StreamingEngine(params, CFG, ecfg)
+    for n in (40, 3000):
+        carry = engine.init_carry(n)
+        assert int(carry["__grid__"]["total"]) == num_windows(n, CFG.window, CFG.window)
+        want = fresh_carry(ecfg, n)
+        assert jax.tree.structure(carry) == jax.tree.structure(want)
+        for a, b in zip(jax.tree.leaves(carry), jax.tree.leaves(want)):
+            assert (a.shape, a.dtype) == (b.shape, b.dtype)
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        carry["cpi"]["fetch_sum"] = jnp.float32(1.0)
+        carry["__grid__"]["seen"] = jnp.int32(7)
+    again = engine.init_carry(40)
+    assert float(again["cpi"]["fetch_sum"]) == 0.0
+    assert int(again["__grid__"]["seen"]) == 0
+    assert engine.state_builds == 1
 
 
 @pytest.mark.sanitize

@@ -35,7 +35,7 @@ from repro.kernels.features.ops import (
     signed_log_device,
     trace_columns,
 )
-from repro.kernels.fused.ops import FusedExtractor
+from repro.kernels.fused.ops import FusedExtractor, init_fused_state
 from repro.kernels.fused.ref import fused_scan_ref, init_state_ref
 from repro.uarch import get_benchmark, run_functional
 from repro.uarch.isa import FUNC_TRACE_DTYPE, Op
@@ -171,6 +171,24 @@ def test_fused_state_threading_across_batches():
         ex.next_batch(301)
     with pytest.raises(ValueError):
         FusedExtractor(cols, FCFG, pad_to=100)
+
+
+def test_fused_extractor_from_a_given_state_equals_the_default():
+    """``state=`` a zero carry starts the scan exactly where the default
+    does, batch for batch, and the given carry is read, never written (an
+    engine hands one zero carry to every request)."""
+    rng = np.random.default_rng(12)
+    cols = trace_columns(_random_trace(2000, rng), FCFG)
+    zero = init_fused_state(FCFG)
+    default = FusedExtractor(cols, FCFG, pad_to=2100)
+    given = FusedExtractor(cols, FCFG, pad_to=2100, state=zero)
+    for m in (700, 700, 700):
+        a, b = default.next_batch(m), given.next_batch(m)
+        for f in FEATURE_FIELDS + ("is_branch", "is_mem", "valid"):
+            _assert_bitwise(b[f], a[f], f)
+    for k in zero:
+        _assert_bitwise(given.state[k], default.state[k], k)
+        assert not np.asarray(zero[k]).any(), k
 
 
 # ---------------------------------------------------------------------------

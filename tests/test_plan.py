@@ -367,6 +367,54 @@ def test_sharded_sweep_inprocess(trace):
 # ---------------------------------------------------------------------------
 
 
+def test_sharded_plan_keeps_one_zero_state_subprocess():
+    """8 virtual devices, a data plan, every feature backend: requests of
+    different lengths on one sharded engine give, bit for bit, what a
+    fresh sharded engine and the single-device plan give each; the zero
+    state is built once and every carry leaf, ``total`` included, is
+    replicated over the mesh."""
+    script = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import jax
+    from repro.core import TaoConfig, FeatureConfig, init_tao, num_windows
+    from repro.distributed import data_mesh
+    from repro.engine import StreamingEngine, EngineConfig
+    from repro.uarch import get_benchmark, run_functional
+
+    cfg = TaoConfig(window=17, d_model=32, n_heads=2, n_layers=1, d_ff=64,
+                    d_cat=16, features=FeatureConfig(n_buckets=64, n_queue=4, n_mem=8))
+    params = init_tao(jax.random.PRNGKey(0), cfg)
+    ft = run_functional(get_benchmark("mcf"), 2000)
+    lengths = (2000, 700, 30)
+    mesh = data_mesh()
+    for backend in ("numpy", "pallas", "fused"):
+        ecfg = EngineConfig(batch_size=32, mesh=mesh, feature_backend=backend)
+        engine = StreamingEngine(params, cfg, ecfg)
+        single = StreamingEngine(params, cfg, EngineConfig(batch_size=32,
+                                                           feature_backend=backend))
+        for n in lengths:
+            got = engine.simulate(ft[:n]).metrics
+            assert got == StreamingEngine(params, cfg, ecfg).simulate(ft[:n]).metrics, (backend, n)
+            assert got == single.simulate(ft[:n]).metrics, (backend, n)
+        assert engine.state_builds == 1, (backend, engine.state_builds)
+        assert engine.num_compiles == 1, (backend, engine.num_compiles)
+        carry = engine.init_carry(1000)
+        assert int(carry["__grid__"]["total"]) == num_windows(1000, 17, 17)
+        for leaf in jax.tree.leaves(carry):
+            assert leaf.sharding.is_fully_replicated, backend
+            assert len(leaf.sharding.device_set) == 8, backend
+    print("ZERO_STATE_OK")
+    """)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"  # virtual devices; avoid TPU probing
+    p = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                       text=True, timeout=560, env=env)
+    assert p.returncode == 0, p.stderr[-3000:]
+    assert "ZERO_STATE_OK" in p.stdout
+
+
 def test_plans_acceptance_subprocess():
     """Single-device vs 8-virtual-device shard_map plan: identical CPI /
     MPKI and windowed phase curves on BOTH feature backends, one compile

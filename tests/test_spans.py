@@ -106,6 +106,8 @@ def test_simulate_spans(engine, trace, tmp_path):
     assert len(named(evs, "fused.extract")) == nb
     assert len(named(evs, "engine.step")) == nb
     assert len(named(evs, "engine.columns")) == len(named(evs, "engine.sync")) == 1
+    # the request's initial state: the engine's kept zeros and one put
+    assert len(named(evs, "engine.upload")) == 1
     # each step dispatch follows its batch's extraction
     for x, st in zip(named(evs, "fused.extract"), named(evs, "engine.step")):
         assert x[1] <= st[0]

@@ -215,6 +215,27 @@ def test_stacked_engine_per_head_arrays_and_checks(models, traces):
         StreamingEngine(models[0], CFG, ecfg, heads=0)
 
 
+@pytest.mark.parametrize("backend", ["numpy", "pallas", "fused"])
+def test_stacked_engine_back_to_back_keeps_one_zero_state(backend, models, traces):
+    """Requests of different lengths on one K-head engine give, bit for
+    bit, what a fresh K-head engine gives each, with the zero state built
+    once and the grid's ``total`` one per head."""
+    ecfg = EngineConfig(batch_size=BATCH, feature_backend=backend)
+    stacked = stack_params(models)
+    engine = StreamingEngine(stacked, CFG, ecfg, heads=K)
+    reqs = [traces["mcf"], traces["lee"][:700], traces["mcf"][:200]]
+    got = [engine.simulate_heads(tr) for tr in reqs]
+    assert engine.state_builds == 1
+    for tr, g in zip(reqs, got):
+        want = StreamingEngine(stacked, CFG, ecfg, heads=K).simulate_heads(tr)
+        assert [r.metrics for r in g] == [r.metrics for r in want], len(tr)
+    for n in (200, 1500):
+        total = np.asarray(engine.init_carry(n)["__grid__"]["total"])
+        assert total.dtype == np.int32
+        np.testing.assert_array_equal(total, [num_windows(n, CFG.window, CFG.window)] * K)
+    assert engine.state_builds == 1
+
+
 def test_sweep_spans(models, traces, tmp_path):
     sweeper = TraceSweeper(CFG, EngineConfig(batch_size=BATCH, feature_backend="fused"))
     jobs = jobs_of(models, traces)
