@@ -5,11 +5,10 @@
   * simulation (inference) throughput: streaming engine vs the pre-refactor
     host batch loop (`simulate_trace_legacy`), with the engine's compile
     count asserted to be exactly one
-  * §4.2 feature-extraction throughput: host NumPy (`extract_features`) vs
-    the device Pallas scan kernels (`extract_features_device`), plus the
-    fused engine (`feature_backend="pallas"`) vs the host pre-pass
+  * §4.2 feature-extraction throughput: host NumPy (`extract_features`),
+    and the fused engine's speedup over the host pre-pass engine
   * the trace->logits megakernel (`feature_backend="fused"`, asserted
-    bit-identical to the staged path) and the int8 W8A8 engine, each with
+    bit-identical to the NumPy engine) and the int8 W8A8 engine, each with
     end-to-end MIPS and host->device bytes/instr (the committed baseline
     `benchmarks/baselines/BENCH_timing.json` + `check_regression` gate
     these rows in CI)
@@ -23,7 +22,6 @@ import numpy as np
 
 from repro.core import extract_features
 from repro.core.simulate import simulate_trace_legacy
-from repro.kernels.features.ops import extract_features_device
 from repro.uarch import UARCH_A, UARCH_B, UARCH_C, get_benchmark, run_detailed, run_functional
 from repro.uarch.isa import KIND_NOP, KIND_REAL, KIND_SQUASHED
 
@@ -103,43 +101,34 @@ def run() -> None:
         f"cpi_rel_err={cpi_err:.2e}",
     )
 
-    # --- host vs device feature extraction (Pallas feature kernels) -------
+    # --- host feature extraction vs the fused device path -----------------
+    # The fused engine extracts on device, one megakernel launch per batch
+    # feeding the step directly: features never materialize in HBM.  fp32
+    # fused is bit-identical to the NumPy engine by contract.
     fcfg = cfg.features
-    extract_features_device(ft_test, fcfg)  # warm-up: compile the scans
     with Timer() as t_host:
         extract_features(ft_test, fcfg, with_labels=False)
-    with Timer() as t_dev:
-        extract_features_device(ft_test, fcfg)  # includes device->host copy
-    n_ft = len(ft_test)
-    host_mips = n_ft / 1e6 / t_host.seconds
-    dev_mips = n_ft / 1e6 / t_dev.seconds
-    # fused engine: features computed on device inside the streaming step
-    fused = model.engine(batch_size=64, feature_backend="pallas")
-    fused.simulate(ft_test)       # warm-up
-    sim_fused = fused.simulate(ft_test)
+    host_mips = len(ft_test) / 1e6 / t_host.seconds
+    mega = model.engine(batch_size=64, feature_backend="fused")
+    mega.simulate(ft_test)        # warm-up
+    sim_mega = mega.simulate(ft_test)
+    assert sim_mega.cpi == sim2.cpi, (sim_mega.cpi, sim2.cpi)
     # host->device traffic: the numpy backend ships the materialized
-    # FeatureSet (+ masks); the pallas backend ships raw int32/bool columns.
+    # FeatureSet (+ masks); the fused backend ships the raw columns packed
+    # as 10 int32 rows per batch.
     host_bpi = 4 * (1 + 32 + 5 + fcfg.n_queue + fcfg.n_mem) + 2
-    dev_bpi = 4 * 6 + 4  # 6 int32 columns + 4 bool columns (trace_columns)
+    dev_bpi = 4 * 10
     emit(
         "features/extraction",
-        1e6 / max(dev_mips * 1e6, 1e-9),
-        f"host_mips={host_mips:.4f};device_mips={dev_mips:.4f};"
-        f"device_speedup={dev_mips / host_mips:.2f}x;"
-        f"fused_engine_mips={sim_fused.mips:.4f};"
+        1e6 / max(host_mips * 1e6, 1e-9),
+        f"host_mips={host_mips:.4f};"
         f"host_prepass_engine_mips={sim2.mips:.4f};"
+        f"fused_engine_speedup={sim_mega.mips / sim2.mips:.2f}x;"
         f"transfer_bytes_per_instr={host_bpi}->{dev_bpi}"
         f"({host_bpi / dev_bpi:.1f}x less)",
     )
 
-    # --- fused megakernel backend + int8 quantized path -------------------
-    # Same raw-column payload as the staged backend (dev_bpi), but features
-    # never materialize in HBM: one megakernel launch per batch feeds the
-    # step directly.  fp32 fused is bit-identical to staged by contract.
-    mega = model.engine(batch_size=64, feature_backend="fused")
-    mega.simulate(ft_test)        # warm-up
-    sim_mega = mega.simulate(ft_test)
-    assert sim_mega.cpi == sim_fused.cpi, (sim_mega.cpi, sim_fused.cpi)
+    # --- int8 quantized path on the fused backend --------------------------
     q8 = model.engine(batch_size=64, feature_backend="fused", precision="int8")
     q8.simulate(ft_test)          # warm-up (own step: precision is keyed)
     sim_q8 = q8.simulate(ft_test)
@@ -148,7 +137,6 @@ def run() -> None:
         "fused/megakernel",
         1e6 / max(sim_mega.mips * 1e6, 1e-9),
         f"fused_mips={sim_mega.mips:.4f};int8_mips={sim_q8.mips:.4f};"
-        f"staged_mips={sim_fused.mips:.4f};"
         f"int8_cpi_rel_err={q8_err:.2e};"
         f"transfer_bytes_per_instr={dev_bpi}",
     )

@@ -166,12 +166,12 @@ def run_one_chip(smoke: Smoke, args) -> None:
     from repro.core.features import extract_features
     from repro.core.simulate import simulate_trace_legacy
     from repro.engine import persistent_cache_status
-    from repro.kernels.features.ops import (
-        memdist_delta_scan,
+    from repro.kernels.fused.ops import (
+        FusedExtractor,
+        init_fused_state,
         signed_log_device,
         trace_columns,
     )
-    from repro.kernels.fused.ops import FusedExtractor, init_fused_state
     from repro.train.trainer import train_step_compiles
     from repro.uarch import UARCH_A
 
@@ -251,9 +251,6 @@ def run_one_chip(smoke: Smoke, args) -> None:
             smoke.check(np.array_equal(np.asarray(dev[k]), getattr(ref, k)),
                         f"{k} bit-exact")
         raw = numpy_memdist_raw(ft, fc.n_mem)
-        staged_raw = np.asarray(memdist_delta_scan(cols["addr"], cols["is_mem"], n_mem=fc.n_mem))
-        smoke.check(np.array_equal(staged_raw.view(np.int32), raw.view(np.int32)),
-                    "raw memdist deltas bit-exact (staged scan kernel)")
         md = np.asarray(dev["memdist"])
         compiled = jax.jit(signed_log_device)(jnp.asarray(raw), np.int32(0))
         smoke.check(

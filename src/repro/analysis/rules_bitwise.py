@@ -1,7 +1,7 @@
 """TAO005 — fma-contraction hazard in bitwise-deterministic functions.
 
 ``core.features.signed_log`` (and its jax twin
-``kernels.features.ops.signed_log_device``) carry a contract the test
+``kernels.fused.ops.signed_log_device``) carry a contract the test
 suite pins: the twin's output, eager or inside the compiled fused
 extraction program, is **bit-identical** to the NumPy reference on the
 CPU, which is why both are written as one-op-per-statement Horner steps.

@@ -172,8 +172,8 @@ def _labels(trace: np.ndarray, with_labels: bool):
 # rounded float32 operations: exponent/mantissa split by bit manipulation,
 # then an atanh-series polynomial (Horner) for log2 of the mantissa.  Every
 # step is an individually rounded IEEE-754 float32 op, so NumPy and the jax
-# twin (``repro.kernels.features.ops.signed_log_device``) produce
-# bit-identical results — the property the device feature backends'
+# twin (``repro.kernels.fused.ops.signed_log_device``) produce
+# bit-identical results — the property the device feature backend's
 # exact-equivalence tests rely on.  This function stays the spec.
 #
 # The decision on compiled evaluation lives here.  A plain jitted

@@ -52,8 +52,9 @@ def simulate_trace(
     """Deprecated engine-backed simulation — use
     ``repro.api.TrainedModel.simulate`` (same engine, same results).
     `collect=False` keeps all metrics on device (fastest; per-instruction
-    arrays are then not collected).  `feature_backend="pallas"` fuses §4.2
-    feature extraction into the device-resident stream (docs/engine.md)."""
+    arrays are then not collected).  `feature_backend="fused"` runs §4.2
+    feature extraction on the device, one program per batch
+    (docs/engine.md)."""
     warnings.warn(
         "repro.core.simulate_trace is deprecated; use repro.api: "
         "TrainedModel(params, cfg).simulate(trace) or Session.sweep(...)",

@@ -31,17 +31,15 @@ Design points (each measured by ``benchmarks/bench_timing.py``):
     axes, and specs reduce across shards through
     ``StepContext.psum``/``pmax``.  The plan is part of the step-cache
     key, so the one-compile guarantee holds per (geometry, plan).
-  * **Feature backends.**  ``feature_backend="pallas"`` replaces the host
-    NumPy feature pre-pass with the device scan kernels in
-    ``kernels/features/``: raw trace columns are shipped once, features are
-    extracted on device, and batches become device-side slices
-    (bit-identical to the NumPy path on the CPU backend; see
-    docs/engine.md and, for the TPU, docs/kernels.md).
-    ``feature_backend="fused"`` goes further: one megakernel launch per
-    batch (``kernels/fused/``) produces the model inputs directly from the
-    raw columns with the scan state carried across batches — features only
-    ever exist at batch granularity, never as an O(trace) FeatureSet in
-    HBM.  Still bit-identical; all three backends share the step cache.
+  * **Feature backends.**  ``feature_backend="numpy"`` extracts features
+    on the host (the reference path); ``feature_backend="fused"`` replaces
+    that pre-pass with one megakernel launch per batch
+    (``kernels/fused/``), which produces the model inputs directly from
+    the raw trace columns with the scan state carried across batches —
+    features only ever exist at batch granularity, never as an O(trace)
+    FeatureSet in HBM.  Bit-identical to the NumPy path on the CPU backend
+    (docs/engine.md; for the TPU, docs/kernels.md); both backends share
+    the step cache.
   * **Stacked design points.**  ``heads=K`` (K >= 2) takes a params tree
     whose every leaf carries a leading axis of K models of one shape (the
     §4.3 design-space sweep: per-design heads over one embedding).  The
@@ -85,11 +83,10 @@ from .aot import abstract_like, compile_bytes_estimate
 from .metrics import DEFAULT_METRICS, MetricSpec, StepContext, resolve_metrics
 from .plan import ExecutionPlan
 
-# NOTE: repro.kernels.features.ops / repro.kernels.fused.ops are imported
-# lazily inside simulate(); a module-level import would close an import
-# cycle (kernels.*.ops -> repro.core package init -> core.simulate ->
-# engine.runner) and crash any consumer whose first repro import is the
-# ops module.
+# NOTE: repro.kernels.fused.ops is imported lazily inside simulate(); a
+# module-level import would close an import cycle (kernels.fused.ops ->
+# repro.core package init -> core.simulate -> engine.runner) and crash any
+# consumer whose first repro import is the ops module.
 
 __all__ = [
     "EngineConfig",
@@ -226,7 +223,7 @@ def prefetch_to_device(
     return inline()
 
 
-FEATURE_BACKENDS = ("numpy", "pallas", "fused")
+FEATURE_BACKENDS = ("numpy", "fused")
 
 PRECISIONS = ("fp32", "int8")
 
@@ -254,18 +251,13 @@ class EngineConfig:
     mesh: Optional[Mesh] = None
     plan: Optional[ExecutionPlan] = None
     # "numpy": host NumPy pre-pass + per-batch host->device transfers.
-    # "pallas": staged device extraction — the trace's int32/bool columns
-    # are shipped once, the Pallas scan kernels compute brhist/memdist on
-    # device, and batches are device-side slices of the materialized
-    # feature arrays.
     # "fused": one megakernel launch per batch (kernels/fused/) produces
     # the model inputs straight from the raw columns, scan state carried
-    # across batches — no O(trace) feature materialization.
-    # Both device backends match the NumPy path exactly on the CPU backend
-    # (docs/kernels.md says what holds on the TPU), and both raise
-    # ValueError on traces with addresses outside |addr| < 2^30.
+    # across batches — no O(trace) feature materialization.  It matches
+    # the NumPy path exactly on the CPU backend (docs/kernels.md says what
+    # holds on the TPU) and raises ValueError on traces with addresses
+    # outside |addr| < 2^30.
     feature_backend: str = "numpy"
-    feature_chunk: int = 512     # Pallas scan grid chunk (trace positions)
     # "fp32": exact float path.  "int8": W8A8 quantized forward — per-
     # channel int8 weights + dynamic per-row int8 activations, int32
     # accumulation (core/quant.py; gated on accuracy parity by
@@ -488,18 +480,19 @@ class StreamingEngine:
         if ecfg.batch_size < 1:
             raise ValueError(f"batch_size must be >= 1, got {ecfg.batch_size}")
         if ecfg.feature_backend not in FEATURE_BACKENDS:
+            # a store written earlier may still name the staged backend
+            hint = (
+                '; the staged "pallas" backend was replaced by "fused"'
+                if ecfg.feature_backend == "pallas" else ""
+            )
             raise ValueError(
                 f"feature_backend must be one of {FEATURE_BACKENDS}, "
-                f"got {ecfg.feature_backend!r}"
+                f"got {ecfg.feature_backend!r}{hint}"
             )
         if ecfg.precision not in PRECISIONS:
             raise ValueError(
                 f"precision must be one of {PRECISIONS}, "
                 f"got {ecfg.precision!r}"
-            )
-        if ecfg.feature_chunk < 1:
-            raise ValueError(
-                f"feature_chunk must be >= 1, got {ecfg.feature_chunk}"
             )
         if heads < 1:
             raise ValueError(f"heads must be >= 1, got {heads}")
@@ -534,8 +527,8 @@ class StreamingEngine:
         # from the fp32 params otherwise when precision="int8"
         self._qparams = qparams
         self._steps: Dict[int, _CachedStep] = {}  # effective window -> step
-        # extraction programs launched (fused: one per batch; pallas: one
-        # per trace), for the sweep scheduler's counters
+        # extraction programs launched (fused: one per batch), for the
+        # sweep scheduler's counters
         self.extractions = 0
         # the all-zero part of every request's initial device state, built
         # on first use (``_zero_state``); everything it depends on — the
@@ -655,8 +648,8 @@ class StreamingEngine:
         entry = self._steps.get(w_eff)
         if entry is None:
             # Keyed on exactly what the compiled step depends on — notably
-            # NOT prefetch or feature_backend, so "numpy", "pallas", and
-            # "fused" engines of the same shape share one executable
+            # NOT prefetch or feature_backend, so "numpy" and "fused"
+            # engines of the same shape share one executable
             # (precision IS keyed: int8 bakes a different forward).  The
             # resolved plan (not the raw mesh) is the partitioning key, so
             # EngineConfig(mesh=m) and EngineConfig(plan=resolve(m)) also
@@ -762,7 +755,7 @@ class StreamingEngine:
 
     def _abstract_batch(self, w_eff: int) -> Dict:
         """ShapeDtypeStructs of one step batch — the exact shapes/dtypes
-        ``stream_batches`` (and the device-side pallas slicer, which is
+        ``stream_batches`` (and the fused extractor, which is
         bit-compatible) produces for this engine's geometry."""
         b = self.ecfg.batch_size
         f = self.cfg.features
@@ -849,33 +842,6 @@ class StreamingEngine:
         the plan's."""
         return prefetch_to_device(host_batches, self.plan.device_put)
 
-    def _device_batches(
-        self, arrays: Dict, w_eff: int, count: int
-    ) -> Iterator[Dict]:
-        """Batch iterator over device-resident feature arrays (the "pallas"
-        backend): windows are device-side reshapes (the engine grid is
-        non-overlapping, stride == window), the ragged tail is zero-padded
-        on device, and per-batch slicing never touches the host."""
-        bsz = self.ecfg.batch_size
-        nw = count // w_eff
-        nb = -(-nw // bsz)
-        # arrays already carries the device-resident is_branch/is_mem bool
-        # columns (device_feature_arrays ships them once for the flags).
-        stacked = {}
-        for k, v in arrays.items():
-            v = v[:count].reshape((nw, w_eff) + v.shape[1:])
-            if nb * bsz > nw:
-                v = jnp.pad(v, [(0, nb * bsz - nw)] + [(0, 0)] * (v.ndim - 1))
-            stacked[k] = v.reshape((nb, bsz) + v.shape[1:])
-        valid = np.zeros((nb * bsz, w_eff), dtype=np.float32)
-        valid[:nw] = 1.0
-        stacked["valid"] = jnp.asarray(valid.reshape(nb, bsz, w_eff))
-        for i in range(nb):
-            batch = {k: v[i] for k, v in stacked.items()}
-            # arrays are already device-resident; a sharded plan still
-            # needs them re-laid-out across its batch axes
-            yield self.plan.device_put(batch) if self.plan.sharded else batch
-
     def _fused_batches(
         self, cols: Dict, w_eff: int, count: int
     ) -> Iterator[Dict]:
@@ -885,7 +851,7 @@ class StreamingEngine:
         host array, with the scan state carried across batches — model
         inputs are produced per batch and consumed by the step
         immediately, so no O(trace) feature materialization ever exists.
-        Window/padding/validity layout is exactly ``_device_batches``'s
+        Window/padding/validity layout is ``stream_batches``'s
         (bit-identical by construction)."""
         from ..kernels.fused.ops import FusedExtractor  # lazy: module note
 
@@ -897,7 +863,6 @@ class StreamingEngine:
         extractor = FusedExtractor(
             {k: v[:count] for k, v in cols.items()},
             self.cfg.features,
-            chunk=self.ecfg.feature_chunk,
             pad_to=nb * per,
             state=self._zero_state()[1],
         )
@@ -959,37 +924,22 @@ class StreamingEngine:
                 step = entry.fn
                 params = self._run_params()
 
-            dev_arrays = None
-            fused_batches = None
-            fs = features
-            if fs is None and self.ecfg.feature_backend in ("pallas", "fused"):
-                from ..kernels.features.ops import (  # lazy: see module note
-                    device_feature_arrays,
-                    trace_columns,
-                )
+            if features is None and self.ecfg.feature_backend == "fused":
+                from ..kernels.fused.ops import trace_columns  # lazy: module note
 
                 # raises when addresses leave the int32-exact window: the
                 # device backend the caller asked for never silently becomes
                 # the NumPy one
                 with span("engine.columns"):
                     cols = trace_columns(func_trace, cfg.features)
-                if self.ecfg.feature_backend == "fused":
-                    fused_batches = self._fused_batches(cols, w_eff, count)
-                else:
-                    with span("engine.upload"):
-                        dev_arrays = device_feature_arrays(
-                            cols, cfg.features, chunk=self.ecfg.feature_chunk
-                        )
-                    self.extractions += 1
-            if fs is None and dev_arrays is None and fused_batches is None:
-                with span("engine.columns"):
-                    fs = extract_features(func_trace, cfg.features, with_labels=False)
-
-            if fused_batches is not None:
-                batches = fused_batches
-            elif dev_arrays is not None:
-                batches = self._device_batches(dev_arrays, w_eff, count)
+                batches = self._fused_batches(cols, w_eff, count)
             else:
+                fs = features
+                if fs is None:
+                    with span("engine.columns"):
+                        fs = extract_features(
+                            func_trace, cfg.features, with_labels=False
+                        )
                 host_batches = stream_batches(
                     fs,
                     cfg.window,

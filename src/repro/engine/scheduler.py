@@ -94,8 +94,8 @@ class SweepReport:
     num_shards: int = 1           # devices each step fanned out over
     # host feature pre-passes this sweep actually ran vs loaded from the
     # artifact store (0 extracted on a warm store = the zero-cold-start
-    # invariant; both stay 0 on the pallas/fused backends, which extract
-    # on device per trace)
+    # invariant; both stay 0 on the fused backend, which extracts on
+    # device per batch)
     features_extracted: int = 0
     features_from_store: int = 0
     # jobs satisfied from crash-resume progress manifests (store entries
@@ -106,7 +106,7 @@ class SweepReport:
     # (the models stacked per trace; 1 for a one-model sweep)
     heads_per_step: float = 0.0
     # device extraction programs launched (fused: one per (trace, batch);
-    # pallas: one per trace; numpy: none, see features_extracted)
+    # numpy: none, see features_extracted)
     extractions: int = 0
     # stacked params trees built by this sweep (0 when the sweeper's kept
     # stack served every trace)
@@ -255,7 +255,7 @@ class TraceSweeper:
         counts: Dict[str, int],
     ) -> Optional[FeatureSet]:
         fault_point("scheduler.prepare", payload=job.key)
-        if self.ecfg.feature_backend in ("pallas", "fused"):
+        if self.ecfg.feature_backend == "fused":
             # device-side extraction happens in the consumer (the device is
             # the contended resource); nothing to pre-compute on host.
             return None

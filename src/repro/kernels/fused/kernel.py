@@ -1,22 +1,26 @@
 """Pallas TPU megakernel: raw trace columns -> every model input, one pass.
 
-The staged ``"pallas"`` feature backend runs four device stages per trace —
-a fused per-instruction jit (regbits/flags/outcome/mem), the branch-history
-scan, the memory-distance scan, and the eager signed-log — and materializes
-the full (n, 32 + flags + N_q + N_m) float32 FeatureSet in HBM before the
-model's embedding stack reads it back.  At simulation batch sizes that
-round-trip is the bandwidth bill (see docs/kernels.md).
-
-This kernel collapses the three in-jit stages into ONE ``pallas_call`` whose
-grid walks trace chunks sequentially ("arbitrary" dimension semantics):
+The §4.2 features need two sequential scans over the trace (the per-bucket
+branch-history table and the memory-distance address queue) besides the
+per-instruction register bitmap and flags.  This kernel runs all of it in
+ONE ``pallas_call`` whose grid walks trace chunks sequentially ("arbitrary"
+dimension semantics):
 
   * the vectorized per-instruction work (register bitmap via iota compare,
     the 5-wide flag stack) runs per chunk on a (chunk, 8) int32 VMEM block
     of the raw columns;
   * the two sequential scans walk the chunk one position at a time, reading
     their scalars from SMEM column blocks and pushing into the carried
-    (N_b, N_q) branch-outcome table and the N_m-deep address queue — the
-    same push steps as the staged kernels (``kernels/features/kernel``).
+    (N_b, N_q) branch-outcome table and the N_m-deep address queue
+    (``push_branch`` / ``push_mem``).
+
+Layout, as Mosaic wants it: the per-position scalars the scans branch and
+index on (bucket, outcome, address, mem bit) arrive as SMEM blocks and
+are read one scalar at a time; every queue lives in a row padded to whole
+128-lane vregs, so a push is a static one-lane ``pltpu.roll`` plus a select
+on lane 0.  The padding lanes past N_q / N_m hold stale values that only
+ever move further right, so they never reach an emitted lane.  The table
+row is a dynamic-sublane load/store (``pl.ds(bucket, 1)``).
 
 Feature rows exist only at batch granularity: the caller
 (``ops._fused_padded``, driven by ``ops.FusedExtractor``) runs this kernel
@@ -32,10 +36,10 @@ place.  Batch k+1 thus continues exactly where batch k stopped, which is
 what lets a whole trace stream through fixed-size launches and equal one
 monolithic scan.
 
-Memory-distance deltas are RAW int32 subtractions cast to float32, exactly
-like the staged kernel: the signed-log compression runs after the kernel,
-in the same compiled program, behind ``signed_log_device``'s rounding
-barriers (``kernels/features/ops``).
+Memory-distance deltas leave the kernel RAW (int32 subtraction is exact;
+int->float32 conversion is correctly rounded): the signed-log compression
+runs after the kernel, in the same compiled program, behind
+``ops.signed_log_device``'s rounding barriers.
 
 Off-TPU the same program runs under ``interpret=True`` (CPU CI).
 """
@@ -47,10 +51,62 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..features.kernel import SEQUENTIAL, push_branch, push_mem, smem_column
+__all__ = [
+    "SEQUENTIAL",
+    "VCOLS",
+    "fused_feature_kernel",
+    "fused_feature_pallas",
+    "lanes",
+    "push_branch",
+    "push_mem",
+    "smem_column",
+]
 
-__all__ = ["VCOLS", "fused_feature_kernel", "fused_feature_pallas"]
+LANES = 128
+
+
+def lanes(n: int) -> int:
+    """``n`` rounded up to whole 128-lane vregs (the padded queue width)."""
+    return -(-n // LANES) * LANES
+
+
+# sequential ("arbitrary") grid: scratch state flows chunk to chunk
+SEQUENTIAL = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+
+
+def _push(row, head):
+    """Shift a padded (r, lanes) queue one lane right, ``head`` into lane 0."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.where(lane == 0, head, pltpu.roll(row, 1, 1))
+
+
+def push_branch(table_scr, out_ref, i, b, o, n_queue: int):
+    """Branch at position ``i`` in bucket ``b`` with outcome ``o`` (±1):
+    emit the bucket's queue as feature row ``i``, then push ``o``."""
+    row = table_scr[pl.ds(b, 1), :]                       # (1, lanes(N_q))
+    out_ref[pl.ds(i, 1), :] = row[:, :n_queue]
+    table_scr[pl.ds(b, 1), :] = _push(row, o)
+
+
+def push_mem(queue_scr, out_ref, i, a, n_mem: int):
+    """Memory access at position ``i`` to address ``a``: emit the raw
+    deltas against the filled queue slots, then push ``a``.  ``queue_scr``
+    is (2, lanes(N_m)) int32: row 0 the addresses, row 1 the filled bits."""
+    q = queue_scr[...]
+    delta = (a - q[0:1, :]).astype(jnp.float32)           # exact int32 sub
+    out_ref[pl.ds(i, 1), :] = jnp.where(q[1:2, :] != 0, delta, 0.0)[:, :n_mem]
+    sub = jax.lax.broadcasted_iota(jnp.int32, q.shape, 0)
+    queue_scr[...] = _push(q, jnp.where(sub == 0, a, 1))
+
+
+def smem_column(chunk: int) -> pl.BlockSpec:
+    """One chunk of an (nc, 1, chunk) per-position column, as SMEM scalars.
+    (A 1-D column would need 1024-position blocks to match XLA's SMEM
+    tiling, and an (nc, chunk) one breaks the (8, 128) block rule.)"""
+    return pl.BlockSpec((None, 1, chunk), lambda c: (c, 0, 0), memory_space=pltpu.SMEM)
+
 
 # lanes of the (chunk, 8) per-instruction VMEM block, in order
 VCOLS = ("opcode", "dst", "src1", "src2", "is_branch", "taken", "is_mem", "is_store")
@@ -83,7 +139,7 @@ def fused_feature_kernel(
 
     # ---- per-instruction features: vectorized over the whole chunk ----
     # (exact integer/bool -> {0.0, 1.0} casts; any compute path is bitwise
-    # identical to the staged _per_instruction_device jit)
+    # identical to the NumPy extraction)
     v = cols_ref[...]
     op, dst, s1, s2, br, tk, mm, st = (v[:, j : j + 1] for j in range(len(VCOLS)))
     reg = jax.lax.broadcasted_iota(jnp.int32, regbits_ref.shape, 1)

@@ -1,20 +1,27 @@
 """Public wrappers for the fused feature-extraction megakernel.
 
-The contract mirrors ``kernels/features/ops`` — and is enforced by
-``tests/test_fused.py`` on the CPU backend: the fused pipeline is
-**bit-identical** to both the staged Pallas backend and the NumPy
-specification (on the TPU, see docs/kernels.md "Exactness").  That falls
-out of three invariants:
+The contract — enforced by ``tests/test_fused.py`` and
+``tests/test_feature_kernels.py`` on the CPU backend — is that the fused
+extraction is **bit-identical** to the NumPy specification
+(``core.features.extract_features`` / ``extract_features_reference``); on
+the TPU everything but the signed-log values stays bit-exact
+(docs/kernels.md "Exactness").  That falls out of three invariants:
 
   * regbits/flags/brhist are exact integer/bool -> {0.0, 1.0, ±1.0} values —
     any compute path produces the same bits;
   * memory-distance deltas leave the kernel RAW (exact int32 subtraction,
     correctly-rounded cast) and the signed-log compression runs inside the
-    same compiled program through ``signed_log_device`` with a traced zero:
-    each product is rounded through an integer barrier, so XLA cannot
-    contract ``a*b + c`` into an fma that would diverge in the last ulp;
+    same compiled program through ``signed_log_device`` — the jax twin of
+    ``core.features.signed_log`` — with a traced zero: each product is
+    rounded through an integer barrier, so XLA cannot contract ``a*b + c``
+    into an fma that would diverge in the last ulp;
   * the scan state threads across calls exactly (float copies and int32
     values), so batch-granular extraction equals one monolithic scan.
+
+``trace_columns`` does the cheap host-side prep (bucket hash on the int64
+pc, int32 address narrowing) and raises ``ValueError`` when addresses fall
+outside the int32-exact window: the device feature path never silently
+becomes the NumPy one.
 
 ``FusedExtractor`` is the streaming driver the engine's ``"fused"`` backend
 uses.  The raw int32/bool columns stay on the host; each ``next_batch``
@@ -37,21 +44,119 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...compat import on_tpu
-from ...core.features import FeatureConfig
+from ...core.features import SIGNED_LOG_COEFFS, SIGNED_LOG_SQRT2, FeatureConfig
 from ...uarch.isa import NUM_REGS, Op
-from ..features.kernel import lanes
-from ..features.ops import (
-    DEFAULT_CHUNK,
-    chunked_column,
-    kernel_chunk,
-    signed_log_device,
-)
-from .kernel import VCOLS, fused_feature_pallas
+from .kernel import VCOLS, fused_feature_pallas, lanes
 
 __all__ = [
+    "ADDR_EXACT_LIMIT",
+    "DEFAULT_CHUNK",
     "FusedExtractor",
     "init_fused_state",
+    "signed_log_device",
+    "trace_columns",
 ]
+
+# Addresses must stay within this bound for int32 deltas to be exact (and
+# overflow-free: |a - b| < 2^31 when |a|, |b| < 2^30).
+ADDR_EXACT_LIMIT = 2**30
+
+DEFAULT_CHUNK = 512
+
+
+def _rounded(p: jnp.ndarray, zero) -> jnp.ndarray:
+    """``p`` as the float32 it was rounded to, before any later op reads it.
+
+    ``zero`` is an int32 zero the compiler cannot see (a traced argument
+    inside a compiled program): OR-ing it into ``p``'s bits is the
+    identity, but XLA can no longer contract the multiply that made ``p``
+    into the add that reads it."""
+    bits = jax.lax.bitcast_convert_type(p, jnp.int32) | zero
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+# tao: bitwise
+def signed_log_device(d: jnp.ndarray, zero) -> jnp.ndarray:
+    """Bit-exact jax twin of ``core.features.signed_log``.
+
+    The same chain of float32 ops, with every product that feeds an add
+    (``s * s``, each Horner ``p * z``, ``p * s``) rounded through
+    ``_rounded``.  ``zero`` is an int32 zero, traced inside a compiled
+    program: ``jax.jit(signed_log_device)(d, np.int32(0))`` equals NumPy
+    bit for bit on the CPU, as does an eager call (``core.features``
+    states the decision).
+    """
+    d = jnp.asarray(d, jnp.float32)
+    a = jnp.abs(d)
+    x = jnp.float32(1.0) + a
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    e = ((bits >> 23) & jnp.int32(0xFF)) - jnp.int32(127)
+    m = jax.lax.bitcast_convert_type(
+        (bits & jnp.int32(0x007FFFFF)) | jnp.int32(0x3F800000), jnp.float32
+    )
+    big = m > SIGNED_LOG_SQRT2
+    m = jnp.where(big, m * jnp.float32(0.5), m)  # exact: no rounding to pin
+    e = (e + big).astype(jnp.float32)
+    s = (m - jnp.float32(1.0)) / (m + jnp.float32(1.0))
+    z = s * s
+    z = _rounded(z, zero)
+    p = jnp.full_like(z, SIGNED_LOG_COEFFS[-1])
+    for c in SIGNED_LOG_COEFFS[-2::-1]:
+        p = p * z
+        p = _rounded(p, zero)
+        p = p + jnp.float32(c)
+    r = p * s
+    r = _rounded(r, zero)
+    r = r + e
+    r = r * jnp.float32(1.0 / 32.0)
+    return jnp.where(d < 0, -r, r)
+
+
+def chunked_column(v: jnp.ndarray, chunk: int) -> jnp.ndarray:
+    """(n,) per-position column -> zero-padded (nc, 1, chunk), the layout
+    of the kernel's SMEM column blocks (pad rows are non-branch, non-mem:
+    the scan state passes through them untouched)."""
+    n = v.shape[0]
+    nc = max(1, -(-n // chunk))
+    return jnp.pad(v, (0, nc * chunk - n)).reshape(nc, 1, chunk)
+
+
+def kernel_chunk(chunk: int) -> int:
+    """The grid chunk rounded up to whole 8-row sublane tiles, as Mosaic
+    wants the (chunk, F) output blocks; results do not depend on it."""
+    return -(-chunk // 8) * 8
+
+
+def trace_columns(trace: np.ndarray, cfg: FeatureConfig) -> Dict[str, np.ndarray]:
+    """Host-side prep of the device extraction inputs.
+
+    Bucket hashing runs on the host so the int64 pc is handled exactly;
+    everything shipped to the device is int32.  Raises ValueError when
+    addresses exceed the int32-exact window (|addr| >= 2^30): the device
+    deltas would be inexact, and only the NumPy backend
+    (``extract_features``) handles such traces.
+    """
+    addr = trace["addr"]
+    if len(addr) and int(np.abs(addr).max()) >= ADDR_EXACT_LIMIT:
+        raise ValueError(
+            f"trace addresses exceed |addr| < 2^30 (= {ADDR_EXACT_LIMIT}); "
+            "int32 device deltas would be inexact — use the NumPy feature "
+            "path (extract_features / feature_backend='numpy')"
+        )
+    # branch outcomes and the mem mask are derived on device from the bool
+    # columns instead of being shipped as widened duplicates
+    return {
+        "bucket": ((trace["pc"] >> 2) % cfg.n_buckets).astype(np.int32),
+        "addr": addr.astype(np.int32),
+        "opcode": trace["opcode"].astype(np.int32),
+        "dst": trace["dst"].astype(np.int32),
+        "src1": trace["src1"].astype(np.int32),
+        "src2": trace["src2"].astype(np.int32),
+        "is_branch": trace["is_branch"],
+        "taken": trace["taken"],
+        "is_mem": trace["is_mem"],
+        "is_store": trace["is_store"],
+    }
 
 # opcodes whose instructions set the is_fp flag (static in the kernel)
 _FP_OPS = (int(Op.FALU), int(Op.FMUL), int(Op.FDIV))
@@ -142,7 +247,7 @@ class FusedExtractor:
     """Streams fixed-size feature batches out of the raw host trace
     columns, carrying the scan state across batches.
 
-    ``cols`` is the host dict from ``kernels.features.ops.trace_columns``
+    ``cols`` is the host dict from ``trace_columns``
     (already validated against the int32-exact address window), read as
     if zero-padded to ``pad_to`` positions (pad rows are non-branch/non-mem
     and leave the carry untouched).  Each ``next_batch(m)`` ships one

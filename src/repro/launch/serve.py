@@ -41,6 +41,7 @@ import contextlib
 import json
 from typing import Optional
 
+from ..engine.runner import FEATURE_BACKENDS
 from ..resilience.faults import fault_point
 from ..serve import (
     ModelRegistry,
@@ -276,7 +277,7 @@ def main(argv=None) -> None:
     ap.add_argument("--batch-size", type=int, default=64)
     ap.add_argument("--max-queue", type=int, default=64)
     ap.add_argument("--feature-backend", default="numpy",
-                    choices=("numpy", "pallas"))
+                    choices=FEATURE_BACKENDS)
     ap.add_argument("--warmup", default=None,
                     help="comma-separated trace lengths to AOT-compile for")
     ap.add_argument("--max-line-bytes", type=int,

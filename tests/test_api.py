@@ -113,7 +113,7 @@ def _legacy_carry_metrics(params, func_trace, cfg, batch_size):
 
 
 @pytest.mark.parametrize("bench,n,bsz", [("mcf", 3000, 64), ("dee", 1000, 13), ("lee", 13 * 17, 13)])
-@pytest.mark.parametrize("backend", ["numpy", "pallas"])
+@pytest.mark.parametrize("backend", ["numpy", "fused"])
 def test_builtin_specs_match_legacy_carry_bitwise(params, bench, n, bsz, backend):
     ft = run_functional(get_benchmark(bench), n)
     oracle = _legacy_carry_metrics(params, ft, CFG, bsz)
@@ -480,11 +480,11 @@ def test_ground_truth_and_dataset_share_one_detailed_run(monkeypatch, trace):
 
 
 def test_session_feature_backend_stamped_on_models(trace):
-    sess = Session(CFG, feature_backend="pallas")
+    sess = Session(CFG, feature_backend="fused")
     mdl = sess.init_model()
-    assert mdl.sim_feature_backend == "pallas"
+    assert mdl.sim_feature_backend == "fused"
     # both paths produce identical metrics (backends are bit-identical)
-    a = mdl.simulate(trace)                            # pallas via default
+    a = mdl.simulate(trace)                            # fused via default
     b = mdl.simulate(trace, feature_backend="numpy")   # explicit override
     assert a.cpi == b.cpi and a.l1d_mpki == b.l1d_mpki
 
@@ -595,7 +595,7 @@ def test_model_num_compiles_dedupes_shared_steps(params):
     mdl = TrainedModel(params=init_tao(jax.random.PRNGKey(0), cfg), cfg=cfg)
     ft = run_functional(get_benchmark("dee"), 500)
     mdl.simulate(ft, batch_size=16)
-    mdl.simulate(ft, batch_size=16, feature_backend="pallas")
+    mdl.simulate(ft, batch_size=16, feature_backend="fused")
     # two engines, one shared executable (the step-cache key excludes the
     # feature backend) -> one compile, not two
     assert len(mdl._engines) == 2

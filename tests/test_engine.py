@@ -182,7 +182,7 @@ def test_engine_sharded_path_matches(params, trace):
 # The initial device state: kept per engine, one new value per request
 # ---------------------------------------------------------------------------
 
-BACKENDS = ("numpy", "pallas", "fused")
+BACKENDS = ("numpy", "fused")
 LENGTHS = (3000, 1000, 40, 2999)
 
 
@@ -242,27 +242,24 @@ def test_init_carry_is_the_kept_zeros_and_a_fresh_total(backend, params):
 
 @pytest.mark.sanitize
 def test_engine_feature_backends_bitwise_identical(params, trace):
-    """The "pallas" backend must reproduce the "numpy" backend exactly:
+    """The "fused" backend must reproduce the "numpy" backend exactly:
     same FeatureSet bits in, same jitted step, same metrics out."""
-    from repro.kernels.features.ops import device_feature_arrays, trace_columns
+    from repro.kernels.fused.ops import FusedExtractor, trace_columns
 
     cols = trace_columns(trace, FCFG)
-    assert cols is not None
-    dev = device_feature_arrays(cols, FCFG, chunk=256)
+    dev = FusedExtractor(cols, FCFG, chunk=256).next_batch(len(trace))
     host = extract_features(trace, FCFG, with_labels=False)
     for k in ("opcode", "regbits", "flags", "brhist", "memdist"):
         np.testing.assert_array_equal(np.asarray(dev[k]), getattr(host, k), err_msg=k)
 
     e_np = StreamingEngine(params, CFG, EngineConfig(batch_size=13, collect=True))
-    e_pl = StreamingEngine(
+    e_fu = StreamingEngine(
         params,
         CFG,
-        EngineConfig(
-            batch_size=13, collect=True, feature_backend="pallas", feature_chunk=256
-        ),
+        EngineConfig(batch_size=13, collect=True, feature_backend="fused"),
     )
     a = e_np.simulate(trace)
-    b = e_pl.simulate(trace)
+    b = e_fu.simulate(trace)
     assert a.num_instructions == b.num_instructions
     assert a.cpi == b.cpi
     assert a.total_cycles == b.total_cycles
@@ -274,48 +271,34 @@ def test_engine_feature_backends_bitwise_identical(params, trace):
     np.testing.assert_array_equal(a.dlevel, b.dlevel)
 
 
-def test_engine_backends_share_compiled_step(params, trace):
-    """feature_backend is not part of the step-cache key: a pallas engine
-    created after a numpy one reuses the same executable (and vice versa)."""
-    e_np = StreamingEngine(params, CFG, EngineConfig(batch_size=11))
-    e_pl = StreamingEngine(
-        params, CFG, EngineConfig(batch_size=11, feature_backend="pallas")
-    )
-    e_np.simulate(trace)
-    e_pl.simulate(trace)
-    assert e_np.num_compiles == 1
-    assert e_pl.num_compiles == 1  # same shared _CachedStep entry
-
-
-def test_engine_pallas_short_and_ragged_traces(params):
+def test_engine_fused_backend_short_and_ragged_traces(params):
     for n in (9, 17, 18, 13 * 17 + 5):
         ft = run_functional(get_benchmark("dee"), n)
         a = simulate_trace(params, ft, CFG, batch_size=13)
-        b = simulate_trace(params, ft, CFG, batch_size=13, feature_backend="pallas")
+        b = simulate_trace(params, ft, CFG, batch_size=13, feature_backend="fused")
         assert a.num_instructions == b.num_instructions
         assert a.cpi == b.cpi, n
         assert a.branch_mpki == b.branch_mpki
 
 
-def test_engine_pallas_wide_address_fallback(params, trace):
-    """Addresses outside the int32-exact window: the device backends raise
+def test_engine_fused_backend_wide_address_fallback(params, trace):
+    """Addresses outside the int32-exact window: the device backend raises
     instead of silently taking the NumPy path; "numpy" still simulates."""
     t = trace.copy()
     t["addr"][::7] = 2**40
     a = simulate_trace(params, t, CFG, batch_size=16)
     assert np.isfinite(a.cpi)
-    for backend in ("pallas", "fused"):
-        with pytest.raises(ValueError, match="2\\^30"):
-            simulate_trace(params, t, CFG, batch_size=16, feature_backend=backend)
+    with pytest.raises(ValueError, match="2\\^30"):
+        simulate_trace(params, t, CFG, batch_size=16, feature_backend="fused")
 
 
-def test_engine_pallas_sharded_matches(params, trace):
+def test_engine_fused_backend_sharded_matches(params, trace):
     mesh = jax.make_mesh((1,), ("data",))
     plain = StreamingEngine(params, CFG, EngineConfig(batch_size=16))
     sharded = StreamingEngine(
         params,
         CFG,
-        EngineConfig(batch_size=16, mesh=mesh, feature_backend="pallas"),
+        EngineConfig(batch_size=16, mesh=mesh, feature_backend="fused"),
     )
     a = plain.simulate(trace)
     b = sharded.simulate(trace)
@@ -324,17 +307,19 @@ def test_engine_pallas_sharded_matches(params, trace):
     assert a.l1d_mpki == b.l1d_mpki
 
 
-def test_engine_rejects_unknown_feature_backend(params):
-    with pytest.raises(ValueError):
-        StreamingEngine(params, CFG, EngineConfig(feature_backend="cuda"))
-    with pytest.raises(ValueError):
-        StreamingEngine(
-            params, CFG, EngineConfig(feature_backend="pallas", feature_chunk=0)
-        )
+@pytest.mark.parametrize(
+    "backend,match",
+    [("cuda", "feature_backend"), ("pallas", 'replaced by "fused"')],
+)
+def test_engine_rejects_unknown_feature_backend(params, backend, match):
+    """Only the two backends are accepted; the staged "pallas" name, which
+    a store written earlier may still hold, points at its replacement."""
+    with pytest.raises(ValueError, match=match):
+        StreamingEngine(params, CFG, EngineConfig(feature_backend=backend))
 
 
 def test_feature_ops_importable_first():
-    """repro.kernels.features.ops must be importable as the FIRST repro
+    """repro.kernels.fused.ops must be importable as the FIRST repro
     import (regression: a module-level ops import in engine.runner closed
     an import cycle through the repro.core package init)."""
     import os
@@ -346,7 +331,7 @@ def test_feature_ops_importable_first():
     env["JAX_PLATFORMS"] = "cpu"
     p = subprocess.run(
         [sys.executable, "-c",
-         "import repro.kernels.features.ops as o; print(o.ADDR_EXACT_LIMIT)"],
+         "import repro.kernels.fused.ops as o; print(o.ADDR_EXACT_LIMIT)"],
         capture_output=True, text=True, timeout=120, env=env,
     )
     assert p.returncode == 0, p.stderr[-2000:]

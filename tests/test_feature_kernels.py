@@ -1,13 +1,15 @@
-"""Pallas feature-extraction kernels vs the NumPy executable specification.
+"""Device feature extraction (the fused megakernel) vs the jnp scan
+oracles and the NumPy executable specification.
 
 The contract is EXACT (bitwise) equivalence: branch-history rows move only
 {-1, 0, +1} values, memory-distance deltas are exact int32 subtractions,
 and the signed-log compression runs as a jax twin of
 ``core.features.signed_log`` (both sides a fixed chain of individually
 rounded float32 ops, eagerly or compiled behind rounding barriers).
-Covers hash-collision-heavy traces (many PCs per bucket), empty-queue
-boundaries, chunk-boundary geometry, and the int32 address-window
-refusal.
+Covers feature configs and grid chunks of their own (``tests/test_fused.py``
+holds one config), hash-collision-heavy traces (many PCs per bucket),
+empty-queue boundaries, chunk-boundary geometry, and the int32
+address-window refusal.
 """
 import jax
 import numpy as np
@@ -15,19 +17,19 @@ import pytest
 
 from repro.core.features import (
     FeatureConfig,
+    FeatureSet,
     extract_features,
     extract_features_reference,
     signed_log,
 )
-from repro.kernels.features.ops import (
+from repro.kernels.fused.ops import (
     ADDR_EXACT_LIMIT,
-    branch_history_scan,
-    extract_features_device,
-    memdist_delta_scan,
+    DEFAULT_CHUNK,
+    FusedExtractor,
     signed_log_device,
     trace_columns,
 )
-from repro.kernels.features.ref import (
+from repro.kernels.fused.ref import (
     branch_history_scan_ref,
     memdist_delta_scan_ref,
 )
@@ -35,6 +37,34 @@ from repro.uarch import get_benchmark, run_functional
 from repro.uarch.isa import FUNC_TRACE_DTYPE, Op
 
 FIELDS = ("opcode", "regbits", "flags", "brhist", "memdist")
+
+
+def _fused_columns(cols, cfg, chunk=DEFAULT_CHUNK):
+    """One whole-trace fused pass over host columns (one batch)."""
+    return FusedExtractor(cols, cfg, chunk=chunk).next_batch(len(cols["bucket"]))
+
+
+def _fused_features(trace, cfg, chunk=DEFAULT_CHUNK):
+    """The fused extraction of a whole trace as a host FeatureSet."""
+    b = _fused_columns(trace_columns(trace, cfg), cfg, chunk)
+    return FeatureSet(**{f: np.asarray(b[f]) for f in FIELDS})
+
+
+def _scan_columns(n, bucket=None, outcome=None, addr=None, mem=None):
+    """Raw fused-kernel columns from scan-oracle inputs: ``outcome`` in
+    {-1, 0, +1} becomes the branch/taken bits, ``mem`` the mem bit."""
+    z = np.zeros(n, np.int32)
+    outcome = np.zeros(n, np.float32) if outcome is None else outcome
+    mem = z if mem is None else mem
+    return {
+        "bucket": z if bucket is None else bucket,
+        "addr": z if addr is None else addr,
+        "opcode": z, "dst": z, "src1": z, "src2": z,
+        "is_branch": outcome != 0.0,
+        "taken": outcome > 0.0,
+        "is_mem": mem != 0,
+        "is_store": np.zeros(n, bool),
+    }
 
 
 def _assert_featuresets_bitwise(a, b, msg=""):
@@ -95,7 +125,7 @@ def test_signed_log_accuracy_vs_true_log2():
 
 
 # ---------------------------------------------------------------------------
-# kernels vs jnp scan oracles (padding / chunk geometry)
+# the megakernel's scans vs the jnp scan oracles (padding / chunk geometry)
 # ---------------------------------------------------------------------------
 
 
@@ -105,9 +135,10 @@ def test_branch_history_kernel_vs_scan_ref(n, chunk):
     n_buckets, n_queue = 8, 5
     bucket = rng.integers(0, n_buckets, n).astype(np.int32)
     outcome = rng.choice([-1.0, 0.0, 1.0], n).astype(np.float32)
-    ker = branch_history_scan(
-        bucket, outcome, n_buckets=n_buckets, n_queue=n_queue, chunk=chunk
-    )
+    cfg = FeatureConfig(n_buckets=n_buckets, n_queue=n_queue, n_mem=4)
+    ker = _fused_columns(
+        _scan_columns(n, bucket=bucket, outcome=outcome), cfg, chunk
+    )["brhist"]
     ref = branch_history_scan_ref(
         bucket, outcome, n_buckets=n_buckets, n_queue=n_queue
     )
@@ -120,18 +151,18 @@ def test_memdist_kernel_vs_scan_ref(n, chunk):
     n_mem = 6
     addr = rng.integers(0, 1 << 20, n).astype(np.int32)
     mem = (rng.random(n) < 0.6).astype(np.int32)
-    ker = memdist_delta_scan(addr, mem, n_mem=n_mem, chunk=chunk)
-    ref = memdist_delta_scan_ref(addr, mem, n_mem=n_mem)
-    np.testing.assert_array_equal(np.asarray(ker), np.asarray(ref))
+    cfg = FeatureConfig(n_buckets=4, n_queue=3, n_mem=n_mem)
+    ker = _fused_columns(_scan_columns(n, addr=addr, mem=mem), cfg, chunk)["memdist"]
+    ref = signed_log_device(memdist_delta_scan_ref(addr, mem, n_mem=n_mem), np.int32(0))
+    np.testing.assert_array_equal(
+        np.asarray(ker).view(np.int32), np.asarray(ref).view(np.int32)
+    )
 
 
 def test_kernels_empty_input():
-    assert branch_history_scan(
-        np.zeros(0, np.int32), np.zeros(0, np.float32), n_buckets=4, n_queue=3
-    ).shape == (0, 3)
-    assert memdist_delta_scan(
-        np.zeros(0, np.int32), np.zeros(0, np.int32), n_mem=4
-    ).shape == (0, 4)
+    b = _fused_columns(_scan_columns(0), FeatureConfig(n_buckets=4, n_queue=3, n_mem=4))
+    assert b["brhist"].shape == (0, 3)
+    assert b["memdist"].shape == (0, 4)
 
 
 # ---------------------------------------------------------------------------
@@ -147,7 +178,7 @@ def test_device_extraction_matches_reference_bitwise(bench):
         FeatureConfig(n_buckets=2, n_queue=3, n_mem=2),
     ):
         ref = extract_features_reference(ft, cfg, with_labels=False)
-        dev = extract_features_device(ft, cfg, with_labels=False, chunk=256)
+        dev = _fused_features(ft, cfg, chunk=256)
         _assert_featuresets_bitwise(ref, dev, msg=f"{bench}/{cfg.n_buckets}")
 
 
@@ -163,7 +194,7 @@ def test_device_extraction_hash_collision_heavy():
         FeatureConfig(n_buckets=3, n_queue=5, n_mem=4),  # non-power-of-two
     ):
         ref = extract_features_reference(t, cfg, with_labels=False)
-        dev = extract_features_device(t, cfg, with_labels=False, chunk=512)
+        dev = _fused_features(t, cfg, chunk=512)
         _assert_featuresets_bitwise(ref, dev, msg=f"nb={cfg.n_buckets}")
 
 
@@ -181,22 +212,20 @@ def test_device_extraction_empty_queue_boundaries():
     }
     for name, t in cases.items():
         ref = extract_features_reference(t, cfg, with_labels=False)
-        dev = extract_features_device(t, cfg, with_labels=False, chunk=64)
+        dev = _fused_features(t, cfg, chunk=64)
         _assert_featuresets_bitwise(ref, dev, msg=name)
-    assert not extract_features_device(
-        cases["neither"], cfg, with_labels=False
-    ).brhist.any()
+    assert not _fused_features(cases["neither"], cfg).brhist.any()
 
 
 def test_device_extraction_matches_vectorized_bitwise():
-    """All three implementations (reference loop, vectorized NumPy, Pallas)
+    """All three implementations (reference loop, vectorized NumPy, fused)
     agree bitwise on a mem-heavy trace with negative/zero/duplicate deltas."""
     rng = np.random.default_rng(11)
     t = _random_trace(2000, rng, branch_p=0.3, mem_p=0.7, addr_hi=1 << 24)
     cfg = FeatureConfig(n_buckets=16, n_queue=6, n_mem=12)
     ref = extract_features_reference(t, cfg, with_labels=False)
     vec = extract_features(t, cfg, with_labels=False)
-    dev = extract_features_device(t, cfg, with_labels=False)
+    dev = _fused_features(t, cfg)
     _assert_featuresets_bitwise(ref, vec, msg="vec")
     _assert_featuresets_bitwise(ref, dev, msg="dev")
 
@@ -207,13 +236,5 @@ def test_trace_columns_rejects_wide_addresses():
     with pytest.raises(ValueError, match="2\\^30"):
         trace_columns(t, FeatureConfig())
     with pytest.raises(ValueError):
-        extract_features_device(t, FeatureConfig(), with_labels=False)
+        _fused_features(t, FeatureConfig())
 
-
-def test_device_extraction_labels_passthrough(small_tao_setup):
-    cfg, _, al, _ = small_tao_setup
-    dev = extract_features_device(al.adjusted, cfg.features, with_labels=True)
-    ref = extract_features_reference(al.adjusted, cfg.features, with_labels=True)
-    _assert_featuresets_bitwise(ref, dev, msg="adjusted")
-    assert dev.labels is not None
-    np.testing.assert_array_equal(dev.labels["fetch_lat"], ref.labels["fetch_lat"])

@@ -92,9 +92,9 @@ def test_nonbranch_nonmem_rows_zero():
 
 
 def test_feature_backends_bitwise_identical_on_unit_traces():
-    """NumPy and Pallas backends agree bit for bit on the hand-built unit
+    """NumPy and fused backends agree bit for bit on the hand-built unit
     traces above (collisions, empty queues, signed deltas included)."""
-    from repro.kernels.features.ops import extract_features_device
+    from repro.kernels.fused.ops import FusedExtractor, trace_columns
 
     cfg = FeatureConfig(n_buckets=2, n_queue=3, n_mem=2)
     rows = [
@@ -108,9 +108,9 @@ def test_feature_backends_bitwise_identical_on_unit_traces():
     ]
     t = _mk_trace(rows)
     host = extract_features(t, cfg, with_labels=False)
-    dev = extract_features_device(t, cfg, with_labels=False, chunk=4)
+    dev = FusedExtractor(trace_columns(t, cfg), cfg, chunk=4).next_batch(len(t))
     for f in ("opcode", "regbits", "flags", "brhist", "memdist"):
-        np.testing.assert_array_equal(getattr(host, f), getattr(dev, f), err_msg=f)
+        np.testing.assert_array_equal(getattr(host, f), np.asarray(dev[f]), err_msg=f)
 
 
 def test_labels_from_adjusted_trace(small_tao_setup):

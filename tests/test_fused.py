@@ -2,12 +2,12 @@
 
 The tentpole contracts, each enforced bitwise or with a declared band:
 
-  * the fused megakernel == the ``lax.scan`` oracle == the staged Pallas
+  * the fused megakernel == the ``lax.scan`` oracle == the NumPy
     extraction, bit-for-bit, across chunk/length geometry sweeps;
   * batch-granular extraction with the scan state threaded across
     ``FusedExtractor.next_batch`` calls == one monolithic pass;
   * ``feature_backend="fused"`` produces CPI / MPKI / phase curves
-    bit-identical to the ``"pallas"`` and ``"numpy"`` backends, while
+    bit-identical to the ``"numpy"`` backend, while
     SHARING their compiled step (one compile per geometry, ever), and its
     extraction is one compiled program per geometry, launched once per
     batch;
@@ -24,18 +24,19 @@ import numpy as np
 import pytest
 
 from repro.core import FeatureConfig, TaoConfig, init_tao
+from repro.core.features import extract_features
 from repro.engine import (
     EngineConfig,
     StreamingEngine,
     cache_stats,
     clear_step_cache,
 )
-from repro.kernels.features.ops import (
-    device_feature_arrays,
+from repro.kernels.fused.ops import (
+    FusedExtractor,
+    init_fused_state,
     signed_log_device,
     trace_columns,
 )
-from repro.kernels.fused.ops import FusedExtractor, init_fused_state
 from repro.kernels.fused.ref import fused_scan_ref, init_state_ref
 from repro.uarch import get_benchmark, run_functional
 from repro.uarch.isa import FUNC_TRACE_DTYPE, Op
@@ -91,7 +92,7 @@ def params():
 
 
 # ---------------------------------------------------------------------------
-# Layer 1: megakernel vs the scan oracle vs the staged backend
+# Layer 1: megakernel vs the scan oracle vs the NumPy extraction
 # ---------------------------------------------------------------------------
 
 
@@ -126,13 +127,12 @@ def test_fused_matches_scan_ref(n, chunk):
 
 
 @pytest.mark.parametrize("bench", ["mcf", "dee", "lee"])
-def test_fused_matches_staged_bitwise(bench):
+def test_fused_matches_numpy_bitwise(bench):
     t = run_functional(get_benchmark(bench), 2500)
-    cols = trace_columns(t, FCFG)
-    staged = device_feature_arrays(cols, FCFG)
-    fused, _ = _fused_pass(cols)
+    ref = extract_features(t, FCFG, with_labels=False)
+    fused, _ = _fused_pass(trace_columns(t, FCFG))
     for f in FEATURE_FIELDS:
-        _assert_bitwise(fused[f], staged[f], f"{bench}/{f}")
+        _assert_bitwise(fused[f], getattr(ref, f), f"{bench}/{f}")
 
 
 def test_fused_collision_and_boundary_geometry():
@@ -143,11 +143,10 @@ def test_fused_collision_and_boundary_geometry():
         _random_trace(300, rng, branch_p=0.5, mem_p=0.0),
         _random_trace(1, rng),
     ):
-        cols = trace_columns(t, FCFG)
-        staged = device_feature_arrays(cols, FCFG)
-        fused, _ = _fused_pass(cols)
+        ref = extract_features(t, FCFG, with_labels=False)
+        fused, _ = _fused_pass(trace_columns(t, FCFG))
         for f in FEATURE_FIELDS:
-            _assert_bitwise(fused[f], staged[f], f)
+            _assert_bitwise(fused[f], getattr(ref, f), f)
 
 
 def test_fused_state_threading_across_batches():
@@ -171,6 +170,26 @@ def test_fused_state_threading_across_batches():
         ex.next_batch(301)
     with pytest.raises(ValueError):
         FusedExtractor(cols, FCFG, pad_to=100)
+
+
+def test_fused_extractor_refuses_past_its_end_and_keeps_its_place():
+    """Without ``pad_to`` the extractor ends at the columns' end; a batch
+    past it raises before anything is dispatched, so the extractor stays
+    where it was and the rest of the trace still comes out exact."""
+    rng = np.random.default_rng(13)
+    cols = trace_columns(_random_trace(500, rng), FCFG)
+    one, _ = _fused_pass(cols)
+    ex = FusedExtractor(cols, FCFG)
+    first = ex.next_batch(300)
+    with pytest.raises(ValueError, match="past the padded column end"):
+        ex.next_batch(201)
+    rest = ex.next_batch(200)
+    for f in FEATURE_FIELDS:
+        _assert_bitwise(
+            np.concatenate([np.asarray(first[f]), np.asarray(rest[f])]), one[f], f
+        )
+    with pytest.raises(ValueError, match="past the padded column end"):
+        ex.next_batch(1)
 
 
 def test_fused_extractor_from_a_given_state_equals_the_default():
@@ -201,7 +220,7 @@ PHASE_METRICS = ("cpi", "branch_mpki", "l1d_mpki", "cpi_phase", "l1d_phase")
 @pytest.mark.sanitize
 def test_engine_fused_backend_bit_identical(params, trace):
     results = {}
-    for backend in ("numpy", "pallas", "fused"):
+    for backend in ("numpy", "fused"):
         e = StreamingEngine(
             params, CFG,
             EngineConfig(batch_size=13, feature_backend=backend,
@@ -209,21 +228,17 @@ def test_engine_fused_backend_bit_identical(params, trace):
         )
         results[backend] = e.simulate(trace)
         assert e.num_compiles == 1, (backend, e.num_compiles)
-    base = results["numpy"]
-    for backend in ("pallas", "fused"):
-        r = results[backend]
-        for m in ("cpi", "branch_mpki", "l1d_mpki"):
-            assert r.metrics[m] == base.metrics[m], (backend, m)
-        for m in ("cpi_phase", "l1d_phase"):
-            _assert_bitwise(
-                getattr(r, m), getattr(base, m), f"{backend}/{m}"
-            )
+    base, r = results["numpy"], results["fused"]
+    for m in ("cpi", "branch_mpki", "l1d_mpki"):
+        assert r.metrics[m] == base.metrics[m], m
+    for m in ("cpi_phase", "l1d_phase"):
+        _assert_bitwise(getattr(r, m), getattr(base, m), m)
 
 
 def test_engine_fused_collect_arrays_bitwise(params, trace):
     a = StreamingEngine(
         params, CFG,
-        EngineConfig(batch_size=16, feature_backend="pallas", collect=True),
+        EngineConfig(batch_size=16, feature_backend="numpy", collect=True),
     ).simulate(trace)
     b = StreamingEngine(
         params, CFG,
@@ -239,30 +254,30 @@ def test_engine_fused_short_and_ragged_traces(params):
     for n in (1, 5, CFG.window - 1, CFG.window, CFG.window + 1, 400):
         ft = run_functional(get_benchmark("lee"), n)
         a = simulate_trace(params, ft, CFG, batch_size=13,
-                           feature_backend="pallas")
+                           feature_backend="numpy")
         b = simulate_trace(params, ft, CFG, batch_size=13,
                            feature_backend="fused")
         assert a.cpi == b.cpi, n
 
 
-def test_fused_shares_compiled_step_across_backends(params, trace):
-    """feature_backend stays out of the step-cache key: the fused engine
-    reuses the executable a numpy/pallas engine already compiled — the
+@pytest.mark.parametrize("order", [("numpy", "fused"), ("fused", "numpy")])
+def test_fused_shares_compiled_step_across_backends(params, trace, order):
+    """feature_backend stays out of the step-cache key: whichever backend
+    runs second reuses the executable the first one compiled — the
     compile-count guard for 'fused = 1 compile per geometry, shared'."""
     # earlier tests may have compiled this exact geometry into the
     # process-wide cache — start cold so the counts are deterministic
     clear_step_cache()
     before = cache_stats()["entries"]
-    e_np = StreamingEngine(
-        params, CFG, EngineConfig(batch_size=11, feature_backend="numpy")
-    )
-    e_np.simulate(trace)
-    e_fu = StreamingEngine(
-        params, CFG, EngineConfig(batch_size=11, feature_backend="fused")
-    )
-    e_fu.simulate(trace)
-    assert e_np.num_compiles == 1
-    assert e_fu.num_compiles == 1          # same shared _CachedStep entry
+    engines = []
+    for backend in order:
+        e = StreamingEngine(
+            params, CFG, EngineConfig(batch_size=11, feature_backend=backend)
+        )
+        e.simulate(trace)
+        engines.append(e)
+    for e in engines:
+        assert e.num_compiles == 1         # same shared _CachedStep entry
     assert cache_stats()["entries"] == before + 1
 
 
@@ -367,7 +382,7 @@ def test_int8_gets_own_step_cache_entry(params, trace):
     )
     q_b = StreamingEngine(
         params, CFG,
-        EngineConfig(batch_size=9, feature_backend="pallas", precision="int8"),
+        EngineConfig(batch_size=9, feature_backend="numpy", precision="int8"),
     )
     ra = q_a.simulate(trace)
     rb = q_b.simulate(trace)

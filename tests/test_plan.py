@@ -229,12 +229,12 @@ def test_windowed_metric_stays_on_device_and_matches_oracle(params, trace):
     res2 = e2.simulate(trace)
     np.testing.assert_array_equal(res2.cpi_phase, curve)
     assert "fetch_lat" not in res2.available_metrics
-    # numpy and pallas backends agree bit-for-bit on the curve
+    # numpy and fused backends agree bit-for-bit on the curve
     e3 = StreamingEngine(
         params,
         CFG,
         EngineConfig(
-            batch_size=13, feature_backend="pallas", metrics=("cpi", "cpi_phase")
+            batch_size=13, feature_backend="fused", metrics=("cpi", "cpi_phase")
         ),
     )
     np.testing.assert_array_equal(e3.simulate(trace).cpi_phase, curve)
@@ -388,7 +388,7 @@ def test_sharded_plan_keeps_one_zero_state_subprocess():
     ft = run_functional(get_benchmark("mcf"), 2000)
     lengths = (2000, 700, 30)
     mesh = data_mesh()
-    for backend in ("numpy", "pallas", "fused"):
+    for backend in ("numpy", "fused"):
         ecfg = EngineConfig(batch_size=32, mesh=mesh, feature_backend=backend)
         engine = StreamingEngine(params, cfg, ecfg)
         single = StreamingEngine(params, cfg, EngineConfig(batch_size=32,
@@ -448,7 +448,7 @@ def test_plans_acceptance_subprocess():
     # 1. bit-identical metrics across plans, both backends
     a = StreamingEngine(params, cfg, EngineConfig(
         batch_size=32, metrics=METRICS)).simulate(ft)
-    for backend in ("numpy", "pallas"):
+    for backend in ("numpy", "fused"):
         e = StreamingEngine(params, cfg, EngineConfig(
             batch_size=32, mesh=mesh, feature_backend=backend,
             metrics=METRICS))

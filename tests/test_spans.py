@@ -21,8 +21,13 @@ from repro.core import FeatureConfig, TaoConfig, init_tao
 from repro.core.transfer import warmup_train_step
 from repro.engine import EngineConfig, StreamingEngine
 from repro.engine.runner import prefetch_to_device
-from repro.kernels.features.ops import kernel_chunk, trace_columns
-from repro.kernels.fused.ops import _fused_padded, _pack, init_fused_state
+from repro.kernels.fused.ops import (
+    _fused_padded,
+    _pack,
+    init_fused_state,
+    kernel_chunk,
+    trace_columns,
+)
 from repro.spans import call_span
 from repro.uarch import UARCH_A
 from repro.uarch.isa import FUNC_TRACE_DTYPE

@@ -437,16 +437,16 @@ tr = sess.capture("dee", 1200)
 model = sess.init_model(seed=3)
 rep = sess.sweep({"m": model}, {"t": tr}, metrics=METRICS)
 res = rep.results["m/t"]
-pal = model.simulate(tr, feature_backend="pallas", metrics=METRICS)
+fu = model.simulate(tr, feature_backend="fused", metrics=METRICS)
 print("CHILD:" + json.dumps({
     "cpi": res.cpi,
     "branch_mpki": res.branch_mpki,
     "l1d_mpki": res.l1d_mpki,
     "cpi_phase": np.asarray(res.cpi_phase).tolist(),
-    "pallas_cpi": pal.cpi,
-    "pallas_branch_mpki": pal.branch_mpki,
-    "pallas_l1d_mpki": pal.l1d_mpki,
-    "pallas_cpi_phase": np.asarray(pal.cpi_phase).tolist(),
+    "fused_cpi": fu.cpi,
+    "fused_branch_mpki": fu.branch_mpki,
+    "fused_l1d_mpki": fu.l1d_mpki,
+    "fused_cpi_phase": np.asarray(fu.cpi_phase).tolist(),
     "xla": xla_cache_counters(),
     "extractions": num_extractions(),
     "sweep_extracted": rep.features_extracted,
@@ -497,8 +497,8 @@ def test_cross_process_zero_cold_start(tmp_path):
     # bit-identical results, scalar and phase curve, on both backends
     for k in (
         "cpi", "branch_mpki", "l1d_mpki", "cpi_phase",
-        "pallas_cpi", "pallas_branch_mpki", "pallas_l1d_mpki",
-        "pallas_cpi_phase",
+        "fused_cpi", "fused_branch_mpki", "fused_l1d_mpki",
+        "fused_cpi_phase",
     ):
         assert warm[k] == cold[k], k
 

@@ -4,9 +4,9 @@ simulate step, compiled for a described ``v5e:2x2`` topology without a chip.
 Interpret mode cannot see what Mosaic refuses (block shapes off the (8, 128)
 tiling, scalars read out of vectors, unaligned lane shifts), nor a step that
 does not fit the device.  These compile the fused megakernel at the
-published widths (``repro.configs.tao``) and at the CI geometry, the two
-staged scan kernels, and the fp32 simulate step at the published widths on
-one chip and under a 4-chip data plan — a few seconds each, no chip time.  The topology is described inside a
+published widths (``repro.configs.tao``) and at the CI geometry, and the
+fp32 simulate step at the published widths on one chip and under a 4-chip
+data plan — a few seconds each, no chip time.  The topology is described inside a
 fixture (never at import), so every xdist worker collects the same tests and
 only the worker running this file loads the TPU compiler.
 """
@@ -23,12 +23,12 @@ from repro.core import FeatureConfig
 from repro.core.model import init_tao
 from repro.engine import EngineConfig, ExecutionPlan, StreamingEngine, clear_step_cache
 from repro.engine.aot import abstract_like
-from repro.kernels.features.ops import (
+from repro.kernels.fused.ops import (
+    _COLUMN_KEYS,
     DEFAULT_CHUNK,
-    _branch_history_padded,
-    _memdist_padded,
+    _fused_padded,
+    init_fused_state,
 )
-from repro.kernels.fused.ops import _COLUMN_KEYS, _fused_padded, init_fused_state
 
 CI_FEATURES = FeatureConfig(n_buckets=32, n_queue=4, n_mem=8)
 PUBLISHED = CONFIG.features
@@ -85,20 +85,6 @@ def test_fused_megakernel_compiles(one_chip, fc, window):
     )
     assert "tpu_custom_call" in lowered.as_text()
     lowered.compile()
-
-
-@pytest.mark.parametrize("fc", [PUBLISHED, CI_FEATURES], ids=["published", "ci"])
-def test_staged_scan_kernels_compile(one_chip, fc):
-    n = BATCH * CONFIG.window
-    i32 = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
-    f32 = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
-    _branch_history_padded.lower(
-        i32, f32, n_buckets=fc.n_buckets, n_queue=fc.n_queue,
-        chunk=DEFAULT_CHUNK, interpret=False,
-    ).compile()
-    _memdist_padded.lower(
-        i32, i32, n_mem=fc.n_mem, chunk=DEFAULT_CHUNK, interpret=False
-    ).compile()
 
 
 @pytest.mark.parametrize("chips", [1, 4])
