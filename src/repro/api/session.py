@@ -1124,9 +1124,9 @@ class Session:
                     )
                     eng = StreamingEngine(abstract, self.cfg, ecfg, heads=heads)
                     engines[ekey] = eng
-                entry = eng.warmup(n)
-                compiled += 1
-                aot += entry.aot is not None
+                for entry in eng.warmup(n):
+                    compiled += 1
+                    aot += entry.aot is not None
         trained = 0
         if train:
             recipes = [{}] if train is True else list(train)

@@ -156,6 +156,7 @@ def stream_batches(
     stride: Optional[int] = None,
     pad: bool = True,
     extra: Optional[Dict[str, np.ndarray]] = None,
+    rows: Optional[Sequence[int]] = None,
 ) -> Iterator[Dict[str, np.ndarray]]:
     """Stream fixed-shape window batches without materializing all windows.
 
@@ -164,9 +165,12 @@ def stream_batches(
     multi-million-instruction traces.  Every batch carries a float32 "valid"
     mask of shape (batch_size, W); when `pad` is set the final ragged batch is
     zero-padded to `batch_size` rows (mask rows 0) so a single jit
-    compilation covers the whole stream.  `extra` arrays (e.g. the trace's
-    is_branch/is_mem columns) are windowed on the same grid and yielded
-    alongside the feature keys.
+    compilation covers the whole stream.  `rows`, when given, is the row
+    count of each batch in turn (the engine's batch plan,
+    ``StreamingEngine.batch_rows``): batch i takes the next `rows[i]`
+    windows and is padded to `rows[i]` rows.  `extra` arrays (e.g. the
+    trace's is_branch/is_mem columns) are windowed on the same grid and
+    yielded alongside the feature keys.
     """
     stride = stride or window
     views = {k: window_view(getattr(fs, k), window, stride) for k in _INPUT_KEYS}
@@ -174,14 +178,20 @@ def stream_batches(
         views.update({k: window_view(v, window, stride) for k, v in extra.items()})
     nw = len(views["opcode"])
     w_eff = views["opcode"].shape[1]
-    for lo in range(0, nw, batch_size):
-        hi = min(lo + batch_size, nw)
-        rows = batch_size if pad else hi - lo
-        batch = {k: _pad_rows(v[lo:hi], rows) for k, v in views.items()}
-        valid = np.zeros((rows, w_eff), dtype=np.float32)
+    if rows is None:
+        rows = [batch_size] * -(-nw // batch_size)
+    elif sum(rows) < nw:
+        raise ValueError(f"batch rows {tuple(rows)} hold fewer than the {nw} windows")
+    lo = 0
+    for size in rows:
+        hi = min(lo + size, nw)
+        n_rows = size if pad else hi - lo
+        batch = {k: _pad_rows(v[lo:hi], n_rows) for k, v in views.items()}
+        valid = np.zeros((n_rows, w_eff), dtype=np.float32)
         valid[: hi - lo] = 1.0
         batch["valid"] = valid
         yield batch
+        lo = hi
 
 
 # windows hashed per contiguous block by iter_window_digests

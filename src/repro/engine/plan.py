@@ -166,10 +166,14 @@ class ExecutionPlan:
             n *= self.mesh.shape[a]
         return n
 
+    def divides(self, batch_size: int) -> bool:
+        """Whether ``batch_size`` rows split evenly over the plan's shards."""
+        return batch_size % self.num_shards == 0
+
     def validate_batch(self, batch_size: int) -> None:
         """Reject batch sizes the plan cannot split evenly (shard_map jit
         arguments do not support uneven padding)."""
-        if batch_size % self.num_shards:
+        if not self.divides(batch_size):
             raise ValueError(
                 f"batch_size={batch_size} does not divide over the plan's "
                 f"{self.num_shards} shards (axes {self.batch_axes} of mesh "

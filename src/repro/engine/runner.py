@@ -8,10 +8,12 @@ The fast path behind §4.2 inference: a functional trace flows through
 
 Design points (each measured by ``benchmarks/bench_timing.py``):
 
-  * **One compilation.**  Every batch has shape (batch_size, W); the ragged
-    final batch is zero-padded and masked instead of retraced, so the whole
-    run — and every later trace with the same effective window — reuses a
-    single executable.
+  * **Two geometries at most.**  Every full batch has shape
+    (batch_size, W); a request's last batch is zero-padded and masked
+    instead of retraced, on half the rows when at most half a batch of
+    windows is left (``batch_rows``), so padding stays under half a
+    batch per request.  The whole run — and every later trace with the
+    same effective window — reuses the one or two executables.
   * **On-device accumulation.**  The step folds each batch into the carry
     pytrees declared by the requested ``MetricSpec``s (``engine.metrics``):
     CPI / branch-MPKI / L1D-MPKI by default, anything plug-in code
@@ -462,10 +464,10 @@ def _per_head(tree, heads: int):
 class StreamingEngine:
     """Compile once, stream any number of traces.
 
-    An engine instance owns the jitted step for a (params-structure,
+    An engine instance owns the jitted steps for a (params-structure,
     TaoConfig, EngineConfig) triple; ``num_compiles`` counts actual traces
-    of the step function, which the test suite pins to one per effective
-    window length regardless of trace/batch geometry.
+    of the step function, which the test suite pins to one per geometry
+    (batch rows, effective window) that ``batch_rows`` plans.
     """
 
     def __init__(
@@ -526,7 +528,8 @@ class StreamingEngine:
         # pre-quantized int8 tree (registry/store path); lazily computed
         # from the fp32 params otherwise when precision="int8"
         self._qparams = qparams
-        self._steps: Dict[int, _CachedStep] = {}  # effective window -> step
+        # geometry (batch rows, effective window) -> step
+        self._steps: Dict[Tuple[int, int], _CachedStep] = {}
         # extraction programs launched (fused: one per batch), for the
         # sweep scheduler's counters
         self.extractions = 0
@@ -541,19 +544,36 @@ class StreamingEngine:
     def num_compiles(self) -> int:
         """Traces of the step function across every step this engine used
         (shared with other engines of identical config — at most one per
-        effective window and params structure either way)."""
+        geometry and params structure either way)."""
         return sum(e.compiles for e in self._steps.values())
+
+    def batch_rows(self, n: int) -> Tuple[int, ...]:
+        """The rows of each batch a trace of ``n`` instructions runs, in
+        order: ``w // batch_size`` full batches of its ``w`` windows, then
+        the rest, if any, as one tail batch.  The tail runs on half the
+        rows when it holds at most half a batch, the batch size is even
+        and the half still divides over the plan's shards; otherwise on
+        the full rows.  Both feature backends stream this plan."""
+        if n < 1:
+            raise ValueError("cannot simulate an empty trace")
+        bsz = self.ecfg.batch_size
+        full, rem = divmod(num_windows(n, self.cfg.window, self.cfg.window), bsz)
+        if not rem:
+            return (bsz,) * full
+        half = bsz // 2
+        tail = half if rem <= half and bsz % 2 == 0 and self.plan.divides(half) else bsz
+        return (bsz,) * full + (tail,)
 
     # ---- jitted step ---------------------------------------------------
 
     # tao: step-builder[engine-step] ignore=entry
-    def _build_step(self, w_eff: int, entry: _CachedStep):
+    def _build_step(self, rows: int, w_eff: int, entry: _CachedStep):
         cfg = self.cfg
         collect = self.ecfg.collect
         plan = self.plan
         actx = plan.axis_context()
         specs = self._specs
-        bsz_global = self.ecfg.batch_size
+        bsz_global = rows
         # trace-time branch: the fp32 forward or its W8A8 quantized twin
         # (the choice is baked into the executable, hence the cache key)
         forward = tao_forward if self.ecfg.precision == "fp32" else tao_forward_int8
@@ -644,19 +664,22 @@ class StreamingEngine:
         )
         return jax.jit(mapped)
 
-    def _get_step(self, w_eff: int) -> _CachedStep:
-        entry = self._steps.get(w_eff)
+    def _get_step(self, geometry: Tuple[int, int]) -> _CachedStep:
+        """The step entry of one batch geometry, ``(rows, w_eff)``."""
+        entry = self._steps.get(geometry)
         if entry is None:
+            rows, w_eff = geometry
             # Keyed on exactly what the compiled step depends on — notably
             # NOT prefetch or feature_backend, so "numpy" and "fused"
             # engines of the same shape share one executable
             # (precision IS keyed: int8 bakes a different forward).  The
             # resolved plan (not the raw mesh) is the partitioning key, so
             # EngineConfig(mesh=m) and EngineConfig(plan=resolve(m)) also
-            # share one.
+            # share one.  The batch's rows, not the engine's batch size:
+            # a half-batch tail and a full batch of that size are one step.
             key = (  # tao: step-key[engine-step]
                 self.cfg,
-                self.ecfg.batch_size,
+                rows,
                 self.ecfg.collect,
                 self.ecfg.precision,
                 self.plan,
@@ -670,11 +693,11 @@ class StreamingEngine:
                 fault_point("engine.compile", payload=f"w{w_eff}")
                 _STEP_STATS["misses"] += 1
                 entry = _CachedStep()
-                entry.fn = self._build_step(w_eff, entry)
+                entry.fn = self._build_step(rows, w_eff, entry)
                 _STEP_CACHE[key] = entry
             else:
                 _STEP_STATS["hits"] += 1
-            self._steps[w_eff] = entry
+            self._steps[geometry] = entry
         else:
             _STEP_STATS["hits"] += 1
         return entry
@@ -742,22 +765,21 @@ class StreamingEngine:
         carry[_GRID_KEY]["total"] = total
         return carry
 
-    def step_entry_for(self, n: int) -> _CachedStep:
-        """The cached step entry ``simulate`` will use for a trace of
-        length ``n`` (created lazily; its ``compiles`` counter lets callers
+    def step_entries_for(self, n: int) -> Tuple[_CachedStep, ...]:
+        """The cached step entries ``simulate`` will use for a trace of
+        length ``n``, one per geometry of ``batch_rows(n)`` in order of
+        first use (created lazily; their ``compiles`` counters let callers
         like the sweep scheduler attribute compilations precisely)."""
-        if n < 1:
-            raise ValueError("cannot simulate an empty trace")
         w_eff = min(self.cfg.window, n)
-        return self._get_step(w_eff)
+        rows = dict.fromkeys(self.batch_rows(n))
+        return tuple(self._get_step((r, w_eff)) for r in rows)
 
     # ---- ahead-of-time compilation --------------------------------------
 
-    def _abstract_batch(self, w_eff: int) -> Dict:
-        """ShapeDtypeStructs of one step batch — the exact shapes/dtypes
-        ``stream_batches`` (and the fused extractor, which is
-        bit-compatible) produces for this engine's geometry."""
-        b = self.ecfg.batch_size
+    def _abstract_batch(self, b: int, w_eff: int) -> Dict:
+        """ShapeDtypeStructs of one step batch of ``b`` rows — the exact
+        shapes/dtypes ``stream_batches`` (and the fused extractor, which is
+        bit-compatible) produces for that geometry."""
         f = self.cfg.features
         sds = jax.ShapeDtypeStruct
         return {
@@ -771,8 +793,10 @@ class StreamingEngine:
             "is_mem": sds((b, w_eff), jnp.bool_),
         }
 
-    def warmup(self, n: int) -> _CachedStep:
-        """Compile the step for traces of length ``n`` ahead of time.
+    def warmup(self, n: int) -> Tuple[_CachedStep, ...]:
+        """Compile the steps for traces of length ``n`` ahead of time: one
+        per geometry of ``batch_rows(n)`` (the full batch, the half-batch
+        tail, or both), returned as ``step_entries_for(n)`` gives them.
 
         Lowers from abstract (ShapeDtypeStruct) params and batch — so the
         engine may hold abstract params from ``jax.eval_shape`` — and
@@ -786,21 +810,22 @@ class StreamingEngine:
         dispatch stays with the jitted function, which owns the
         shard-placement inference.  Idempotent per geometry.
         """
-        entry = self.step_entry_for(n)
-        if entry.aot is not None:
-            return entry
+        entries = self.step_entries_for(n)
         if self.plan.sharded or jax.process_count() > 1:
-            return entry
+            return entries
         w_eff = min(self.cfg.window, n)
-        lowered = entry.fn.lower(
-            abstract_like(self._run_params()),
-            abstract_like(self.init_carry(n)),
-            self._abstract_batch(w_eff),
-        )
-        compiled = lowered.compile()
-        entry.est_bytes = compile_bytes_estimate(compiled)
-        entry.aot = compiled
-        return entry
+        for rows, entry in zip(dict.fromkeys(self.batch_rows(n)), entries):
+            if entry.aot is not None:
+                continue
+            lowered = entry.fn.lower(
+                abstract_like(self._run_params()),
+                abstract_like(self.init_carry(n)),
+                self._abstract_batch(rows, w_eff),
+            )
+            compiled = lowered.compile()
+            entry.est_bytes = compile_bytes_estimate(compiled)
+            entry.aot = compiled
+        return entries
 
     def _run_params(self):
         """The parameter tree the step actually consumes: the engine's
@@ -851,24 +876,23 @@ class StreamingEngine:
         host array, with the scan state carried across batches — model
         inputs are produced per batch and consumed by the step
         immediately, so no O(trace) feature materialization ever exists.
-        Window/padding/validity layout is ``stream_batches``'s
-        (bit-identical by construction)."""
+        Window/padding/validity layout and the batch rows are
+        ``stream_batches``'s under ``batch_rows`` (bit-identical by
+        construction)."""
         from ..kernels.fused.ops import FusedExtractor  # lazy: module note
 
-        bsz = self.ecfg.batch_size
-        nb = -(-(count // w_eff) // bsz)
-        per = bsz * w_eff
+        rows = self.batch_rows(count)
         # the scan starts from the engine's kept zero carry: no device op
         # here, the request's one put is init_carry's
         extractor = FusedExtractor(
             {k: v[:count] for k, v in cols.items()},
             self.cfg.features,
-            pad_to=nb * per,
+            pad_to=sum(rows) * w_eff,
             state=self._zero_state()[1],
         )
-        for _ in range(nb):
+        for r in rows:
             with span("fused.extract"):
-                batch = extractor.next_batch(per, (bsz, w_eff))
+                batch = extractor.next_batch(r * w_eff, (r, w_eff))
                 self.extractions += 1
                 if self.plan.sharded:
                     batch = self.plan.device_put(batch)
@@ -905,24 +929,25 @@ class StreamingEngine:
         w_eff = min(cfg.window, n)
         # exact instruction count from the window grid (no float rounding)
         count = num_windows(n, cfg.window, cfg.window) * w_eff
-        bsz = self.ecfg.batch_size
-        nb = -(-(count // w_eff) // bsz)
+        rows = self.batch_rows(n)
         with call_span(
             "engine.simulate",
             instructions=count,
-            positions=nb * bsz * w_eff,
-            batches=nb,
+            positions=sum(rows) * w_eff,
+            batches=len(rows),
+            half_batches=int(rows[-1] != self.ecfg.batch_size),
             heads=self.heads,
         ):
-            entry = self._get_step(w_eff)
-            # AOT-warmed geometry: call the compiled executable directly (no
-            # dispatch-time retracing; params must be committed device arrays)
-            if entry.aot is not None:
-                step = entry.aot
-                params = self._committed_params()
-            else:
-                step = entry.fn
-                params = self._run_params()
+            steps = {}  # batch rows -> (callable, params)
+            for r in dict.fromkeys(rows):
+                entry = self._get_step((r, w_eff))
+                # AOT-warmed geometry: call the compiled executable directly
+                # (no dispatch-time retracing; params must be committed
+                # device arrays)
+                if entry.aot is not None:
+                    steps[r] = (entry.aot, self._committed_params())
+                else:
+                    steps[r] = (entry.fn, self._run_params())
 
             if features is None and self.ecfg.feature_backend == "fused":
                 from ..kernels.fused.ops import trace_columns  # lazy: module note
@@ -949,6 +974,7 @@ class StreamingEngine:
                         "is_branch": func_trace["is_branch"],
                         "is_mem": func_trace["is_mem"],
                     },
+                    rows=rows,
                 )
                 batches = (
                     self._prefetched(host_batches)
@@ -962,7 +988,8 @@ class StreamingEngine:
             with span("engine.upload"):
                 carry = self.init_carry(n)
             pers = []
-            for batch in batches:
+            for batch, r in zip(batches, rows):
+                step, params = steps[r]
                 with span("engine.step"):
                     carry, per = step(params, carry, batch)
                 if self.ecfg.collect:

@@ -51,7 +51,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.dataset import num_windows
 from ..core.features import FeatureSet, extract_features
 from ..core.model import TaoConfig
 from ..resilience.faults import fault_point
@@ -237,7 +236,7 @@ class TraceSweeper:
         engine = StreamingEngine(
             abstract_params(self.cfg, heads), self.cfg, self.ecfg, heads=heads
         )
-        entries = [engine.warmup(n) for n in sorted(set(trace_lengths))]
+        entries = [e for n in sorted(set(trace_lengths)) for e in engine.warmup(n)]
         return {
             "geometries": len(entries),
             "aot_compiled": sum(1 for e in entries if e.aot is not None),
@@ -391,14 +390,13 @@ class TraceSweeper:
                 fault_point("scheduler.consume", payload=job.key)
             trace = group[0].trace
             engine = engine_for(group)
-            # snapshot the shared step entry BEFORE simulating, so the
+            # snapshot the shared step entries BEFORE simulating, so the
             # report attributes only compiles this sweep triggered
-            entry = engine.step_entry_for(len(trace))
-            if id(entry) not in entries:
-                entries[id(entry)] = entry
-                baseline[id(entry)] = entry.compiles
-            nw = num_windows(len(trace), self.cfg.window, self.cfg.window)
-            batches = -(-nw // self.ecfg.batch_size)
+            for entry in engine.step_entries_for(len(trace)):
+                if id(entry) not in entries:
+                    entries[id(entry)] = entry
+                    baseline[id(entry)] = entry.compiles
+            batches = len(engine.batch_rows(len(trace)))
             before = engine.extractions
             with span("sweep.group", trace=index, heads=len(group), batches=batches):
                 out = engine.simulate_heads(trace, features=features)

@@ -664,10 +664,10 @@ class TraceServer:
                     features = await self._feature_entry(p)
                     p.extract_s += time.perf_counter() - t_f
                 engine = self._engine_for(p)
-                entry = engine.step_entry_for(p.n)
-                if id(entry) not in self._step_entries:
-                    self._step_entries[id(entry)] = entry
-                    self._step_baseline[id(entry)] = entry.compiles
+                for entry in engine.step_entries_for(p.n):
+                    if id(entry) not in self._step_entries:
+                        self._step_entries[id(entry)] = entry
+                        self._step_baseline[id(entry)] = entry.compiles
                 items.append((p, features, engine))
             except BaseException as e:
                 # a failed extraction future must not poison the cache
@@ -797,12 +797,12 @@ class TraceServer:
                 metrics=self.default_metrics,
             ))
             for n in sorted(set(trace_lengths)):
-                entry = engine.warmup(n)
-                if id(entry) not in self._step_entries:
-                    self._step_entries[id(entry)] = entry
-                    self._step_baseline[id(entry)] = entry.compiles
-                compiled += 1
-                aot += entry.aot is not None
+                for entry in engine.warmup(n):
+                    if id(entry) not in self._step_entries:
+                        self._step_entries[id(entry)] = entry
+                        self._step_baseline[id(entry)] = entry.compiles
+                    compiled += 1
+                    aot += entry.aot is not None
         return {"geometries": compiled, "aot_compiled": aot}
 
     # ---- observability ---------------------------------------------------

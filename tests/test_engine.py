@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
 from repro.core import (
     FeatureConfig,
@@ -17,7 +18,13 @@ from repro.core import (
     window_view,
 )
 from repro.core.simulate import simulate_trace, simulate_trace_legacy
-from repro.engine import EngineConfig, MetricNotCollectedError, StreamingEngine
+from repro.engine import (
+    EngineConfig,
+    ExecutionPlan,
+    MetricNotCollectedError,
+    PER_INSTRUCTION_KEYS,
+    StreamingEngine,
+)
 from repro.engine.metrics import resolve_metrics
 from repro.uarch import get_benchmark, run_functional
 
@@ -240,6 +247,93 @@ def test_init_carry_is_the_kept_zeros_and_a_fresh_total(backend, params):
     assert engine.state_builds == 1
 
 
+# ---------------------------------------------------------------------------
+# The batch plan: full batches, then a tail on half the rows where it fits
+# ---------------------------------------------------------------------------
+
+HALF = 16  # an even batch size: a tail of at most 8 windows runs on 8 rows
+
+
+def _instructions(windows: int) -> int:
+    """A trace length of exactly ``windows`` windows, with a ragged rest."""
+    return windows * CFG.window + 5
+
+
+@pytest.mark.parametrize(
+    "windows,rows",
+    [
+        (2 * HALF, (HALF, HALF)),                       # no tail
+        (2 * HALF + 1, (HALF, HALF, HALF // 2)),
+        (2 * HALF + HALF // 2, (HALF, HALF, HALF // 2)),
+        (2 * HALF + HALF // 2 + 1, (HALF, HALF, HALF)),
+        (3, (HALF // 2,)),                              # the tail is the whole trace
+        (HALF // 2 + 1, (HALF,)),
+    ],
+)
+def test_batch_rows_plan(params, windows, rows):
+    engine = StreamingEngine(params, CFG, EngineConfig(batch_size=HALF))
+    assert engine.batch_rows(_instructions(windows)) == rows
+
+
+@pytest.mark.parametrize("tail", [1, HALF // 2, HALF // 2 + 1])
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_half_batch_tail_matches_one_geometry(backend, tail, params, trace, monkeypatch):
+    """A trace whose last batch holds ``tail`` windows runs that batch on
+    half the rows when they hold it, and gives what the run that pads the
+    tail to a full batch gives: the same integer counts and per-instruction
+    arrays, the same cycles up to float summation order."""
+    import repro.engine.runner as R
+
+    ft = trace[: _instructions(2 * HALF + tail)]
+    ecfg = EngineConfig(batch_size=HALF, feature_backend=backend, collect=True)
+    calls = []
+    call_span = R.call_span
+
+    def recorded(name, **args):
+        calls.append((name, args))
+        return call_span(name, **args)
+
+    monkeypatch.setattr(R, "call_span", recorded)
+    got = StreamingEngine(params, CFG, ecfg).simulate(ft)
+    half = tail <= HALF // 2
+    assert calls == [("engine.simulate", {
+        "instructions": (2 * HALF + tail) * CFG.window,
+        "positions": (2 * HALF + (HALF // 2 if half else HALF)) * CFG.window,
+        "batches": 3,
+        "half_batches": int(half),
+        "heads": 1,
+    })]
+
+    monkeypatch.setattr(StreamingEngine, "batch_rows", lambda self, n: (HALF,) * 3)
+    want = StreamingEngine(params, CFG, ecfg).simulate(ft)
+    assert got.num_instructions == want.num_instructions
+    assert got.branch_mpki == want.branch_mpki
+    assert got.l1d_mpki == want.l1d_mpki
+    for m in ("cpi", "total_cycles"):
+        np.testing.assert_allclose(got.metrics[m], want.metrics[m], rtol=1e-6, err_msg=m)
+    for k in PER_INSTRUCTION_KEYS:
+        np.testing.assert_array_equal(getattr(got, k), getattr(want, k), err_msg=k)
+
+
+def test_tail_keeps_one_geometry(params, trace):
+    """An odd batch size has no half; a half batch that would not divide
+    over the plan's shards cannot run there.  Either way the tail keeps the
+    full rows and the engine one step."""
+    odd = StreamingEngine(params, CFG, EngineConfig(batch_size=13))
+    n = _instructions(2 * 13 + 1)
+    assert odd.batch_rows(n) == (13, 13, 13)
+    odd.simulate(trace[:n])
+    assert sorted(odd._steps) == [(13, CFG.window)] and odd.num_compiles == 1
+
+    # eight shards: a half of 4 rows does not divide, a half of 8 does
+    plan = ExecutionPlan(
+        kind="sharded", mesh=AbstractMesh((8,), ("data",)), batch_axes=("data",)
+    )
+    for bsz, rows in [(8, (8, 8, 8)), (16, (16, 16, 8))]:
+        engine = StreamingEngine(params, CFG, EngineConfig(batch_size=bsz, plan=plan))
+        assert engine.batch_rows(_instructions(2 * bsz + 1)) == rows, bsz
+
+
 @pytest.mark.sanitize
 def test_engine_feature_backends_bitwise_identical(params, trace):
     """The "fused" backend must reproduce the "numpy" backend exactly:
@@ -434,7 +528,10 @@ def test_engine_multidevice_shard_map():
         assert abs(r.cpi - leg.cpi) / leg.cpi < 1e-5, (names, r.cpi, leg.cpi)
         assert r.branch_mpki == leg.branch_mpki
         assert r.l1d_mpki == leg.l1d_mpki
-        assert e.num_compiles == 1
+        # 176 windows: five (32, 17) batches, then a 16-window tail on the
+        # half geometry (16, 17), which still divides over both meshes
+        assert e.num_compiles == 2
+        assert sorted(e._steps) == [(16, 17), (32, 17)]
     print("SHARD_OK")
     """)
     env = dict(os.environ)

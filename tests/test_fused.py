@@ -310,6 +310,44 @@ def test_engine_fused_extraction_compiles_once_launches_per_batch(params, monkey
     assert program._cache_size() == before + 1
 
 
+@pytest.mark.parametrize(
+    "window,windows,rows",
+    [(10, 2 * 16 + 3, (16, 8)), (12, 2 * 16, (16,)), (14, 3, (8,))],
+)
+def test_warmup_compiles_exactly_the_geometries_simulate_runs(
+    window, windows, rows, monkeypatch
+):
+    """``warmup(n)`` compiles one step per geometry of ``batch_rows(n)`` —
+    the full batches, the half-batch tail, or both — and the simulate that
+    follows compiles no step; its extraction launches run the same plan."""
+    from repro.kernels.fused import ops
+
+    # widths of this test alone, one window per case: fresh step entries
+    cfg = TaoConfig(window=window, d_model=32, n_heads=2, n_layers=1, d_ff=56,
+                    d_cat=16, features=FCFG)
+    n = windows * window + 3
+    engine = StreamingEngine(init_tao(jax.random.PRNGKey(0), cfg), cfg,
+                             EngineConfig(batch_size=16, feature_backend="fused"))
+    entries = engine.warmup(n)
+    assert sorted(engine._steps) == sorted((r, window) for r in rows)
+    assert len(entries) == len(rows) and engine.num_compiles == len(rows)
+    if jax.process_count() == 1:
+        assert all(e.aot is not None for e in entries)
+
+    program = ops._fused_padded
+    launches = []
+
+    def counting(*args, **kwargs):
+        launches.append(kwargs["shape"])
+        return program(*args, **kwargs)
+
+    monkeypatch.setattr(ops, "_fused_padded", counting)
+    engine.simulate(run_functional(get_benchmark("dee"), n))
+    assert engine.num_compiles == len(rows)
+    assert launches == [(r, window) for r in engine.batch_rows(n)]
+    assert launches[-1] == (rows[-1], window)
+
+
 def test_engine_rejects_unknown_precision(params):
     with pytest.raises(ValueError, match="precision"):
         StreamingEngine(params, CFG, EngineConfig(precision="fp16"))

@@ -108,6 +108,7 @@ def test_simulate_spans(engine, trace, tmp_path):
     assert args["instructions"] == res.num_instructions
     assert args["batches"] == nb
     assert args["positions"] == nb * BATCH * w_eff
+    assert args["half_batches"] == 0  # the tail of 7 windows needs the full 8 rows
     assert len(named(evs, "fused.extract")) == nb
     assert len(named(evs, "engine.step")) == nb
     assert len(named(evs, "engine.columns")) == len(named(evs, "engine.sync")) == 1
@@ -171,7 +172,7 @@ def test_executable_names_the_trace_readers_match(engine, params):
     """The benchmark's readers match "XLA Modules" events by these names
     (``bench/metrics``): ``jit_body`` (engine step), ``jit__fused_padded``
     (fused extraction), ``jit_step`` (transfer step)."""
-    entry = engine.warmup(N)
+    (entry,) = engine.warmup(N)
     assert module_name(entry.aot) == "jit_body"
 
     cols = trace_columns(np.zeros(64, dtype=FUNC_TRACE_DTYPE), FCFG)

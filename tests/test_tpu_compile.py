@@ -6,7 +6,8 @@ tiling, scalars read out of vectors, unaligned lane shifts), nor a step that
 does not fit the device.  These compile the fused megakernel at the
 published widths (``repro.configs.tao``) and at the CI geometry, and the
 fp32 simulate step at the published widths on one chip and under a 4-chip
-data plan — a few seconds each, no chip time.  The topology is described inside a
+data plan, for the full batch and for the half-batch tail — a few seconds
+each, no chip time.  The topology is described inside a
 fixture (never at import), so every xdist worker collects the same tests and
 only the worker running this file loads the TPU compiler.
 """
@@ -104,10 +105,10 @@ def test_simulate_step_compiles_at_published_widths(topo, chips):
     engine = StreamingEngine(params, CONFIG, EngineConfig(batch_size=BATCH, plan=plan))
     n = 100_000
     try:
-        compiled = engine.step_entry_for(n).fn.lower(
+        compiled = engine.step_entries_for(n)[0].fn.lower(
             _on(whole, params),
             _on(whole, jax.eval_shape(lambda: engine.init_carry(n))),
-            _on(rows, engine._abstract_batch(CONFIG.window)),
+            _on(rows, engine._abstract_batch(BATCH, CONFIG.window)),
         ).compile()
     finally:
         # the process-wide step entry now holds a trace for the described
@@ -136,10 +137,10 @@ def test_stacked_sweep_step_compiles_at_published_widths(topo):
     )
     n = 131_072
     try:
-        compiled = engine.step_entry_for(n).fn.lower(
+        compiled = engine.step_entries_for(n)[0].fn.lower(
             _on(whole, params),
             _on(whole, jax.eval_shape(lambda: engine.init_carry(n))),
-            _on(plan.batch_sharding(), engine._abstract_batch(CONFIG.window)),
+            _on(plan.batch_sharding(), engine._abstract_batch(BATCH, CONFIG.window)),
         ).compile()
     finally:
         clear_step_cache()
@@ -147,3 +148,41 @@ def test_stacked_sweep_step_compiles_at_published_widths(topo):
     # per chip: the 32 stacked models (2.5 GB) and the step's temporaries,
     # beside the 32 models the sweep holds replicated (2.5 GB), of 16 GB
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6 * 2**30
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_half_batch_tail_compiles_at_published_widths(topo, chips):
+    """The half-batch tail geometry (32 x 129) that a request ends on when
+    at most 32 windows are left: its step on one chip and under the 4-chip
+    data plan (8 rows per chip), and on one chip its extraction program."""
+    params = jax.eval_shape(lambda: init_tao(jax.random.PRNGKey(0), CONFIG))
+    whole = rows = SingleDeviceSharding(topo.devices[0])
+    plan = None
+    if chips == 4:
+        mesh = Mesh(np.array(topo.devices[:4]).reshape(4), ("data",))
+        plan = ExecutionPlan.resolve(mesh, batch_size=BATCH)
+        whole = NamedSharding(mesh, PartitionSpec())
+        rows = plan.batch_sharding()
+    engine = StreamingEngine(params, CONFIG, EngineConfig(batch_size=BATCH, plan=plan))
+    n = (BATCH + 5) * CONFIG.window
+    assert engine.batch_rows(n) == (BATCH, BATCH // 2)
+    try:
+        _, half = engine.step_entries_for(n)
+        half.fn.lower(
+            _on(whole, params),
+            _on(whole, jax.eval_shape(lambda: engine.init_carry(n))),
+            _on(rows, engine._abstract_batch(BATCH // 2, CONFIG.window)),
+        ).compile()
+    finally:
+        clear_step_cache()
+    if chips == 1:
+        state = jax.eval_shape(lambda: init_fused_state(PUBLISHED))
+        m = BATCH // 2 * CONFIG.window
+        _fused_padded.lower(
+            *_on(whole, [jax.ShapeDtypeStruct((len(_COLUMN_KEYS), m), jnp.int32),
+                         state["table"], state["queue"],
+                         jax.ShapeDtypeStruct((2,), jnp.int32)]),
+            shape=(BATCH // 2, CONFIG.window), n_queue=PUBLISHED.n_queue,
+            n_mem=PUBLISHED.n_mem, n_flags=PUBLISHED.flags_dim,
+            chunk=DEFAULT_CHUNK, interpret=False,
+        ).compile()
