@@ -1,8 +1,11 @@
 """Fig. 9 — simulation accuracy vs the SimNet baseline.
 
-Trains Tao (multi-metric, functional-trace inputs) and SimNet (CNN,
-detailed-trace inputs) on the train benchmarks for each µarch and compares
-per-benchmark CPI error against the detailed simulator's ground truth.
+Trains Tao (multi-metric, functional-trace inputs) and SimNet (CNN over
+the in-flight context, detailed-trace inputs, trained teacher-forced on the
+true latencies) on the train benchmarks for each µarch, simulates each test
+benchmark with both through the engine (SimNet sequentially, over parallel
+sub-traces) and compares per-benchmark CPI error against the detailed
+simulator's ground truth.
 
 Doubles as the int8 accuracy-parity gate: every trained (µarch, bench)
 pair is re-simulated with ``precision="int8"`` and must stay within 5%
@@ -26,14 +29,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.api import TrainedModel
 from repro.core.align import build_adjusted_trace
 from repro.core.simnet import (
     SimNetConfig,
     init_simnet,
     make_simnet_step,
-    simnet_features,
-    simnet_forward,
-    simnet_windows,
+    pack_rows,
+    teacher_inputs,
+    true_latencies,
 )
 from repro.train.optim import AdamWConfig, adamw_init
 from repro.uarch import UARCH_A, UARCH_B, UARCH_C, get_benchmark, run_detailed, run_functional
@@ -53,51 +57,46 @@ from .common import (
     tao_config,
 )
 
+SIMNET_BATCH = 64
+
+
+def _simnet_rows(uarch, bench, n):
+    """SimNet's input: the detailed trace of ``bench`` on ``uarch``."""
+    prog = get_benchmark(bench)
+    det, _ = run_detailed(prog, run_functional(prog, n), uarch)
+    return build_adjusted_trace(det).adjusted
+
 
 def _train_simnet(uarch, window):
-    cfg = SimNetConfig(window=window)
-    feats = []
+    """SimNet trained on teacher-forced contexts: every instruction's
+    context built from the detailed trace's true latencies by the function
+    the engine's scan uses with predicted ones."""
+    cfg = SimNetConfig(window=window, subtrace=4 * window)
+    traces = []
     for b in TRAIN_BENCHES:
-        prog = get_benchmark(b)
-        ft = run_functional(prog, TRACE_LEN)
-        det, _ = run_detailed(prog, ft, uarch)
-        al = build_adjusted_trace(det)
-        feats.append(simnet_features(al.adjusted))
-    x = np.concatenate([f["x"] for f in feats])
-    labels = np.concatenate([f["labels"] for f in feats])
-    ds = simnet_windows({"x": x, "labels": labels}, window)
+        rows = _simnet_rows(uarch, b, TRACE_LEN)
+        traces.append((jnp.asarray(pack_rows(rows)), jnp.asarray(true_latencies(rows))))
+    inputs = jax.jit(lambda words, lat, idx: teacher_inputs(words, lat, idx, cfg)[0])
     params = init_simnet(jax.random.PRNGKey(0), cfg)
     opt = adamw_init(params)
     step = make_simnet_step(cfg, AdamWConfig(lr=1e-3))
     rng = np.random.default_rng(0)
-    n = len(ds["x"])
     for _ep in range(EPOCHS):
-        order = rng.permutation(n)
-        for lo in range(0, n - 8 + 1, 8):
-            idx = order[lo : lo + 8]
-            batch = {"x": jnp.asarray(ds["x"][idx]), "labels": jnp.asarray(ds["labels"][idx])}
-            params, opt, loss = step(params, opt, batch)
+        for words, lat in traces:
+            order = rng.permutation(len(lat))
+            for lo in range(0, len(order) - SIMNET_BATCH + 1, SIMNET_BATCH):
+                idx = jnp.asarray(order[lo: lo + SIMNET_BATCH])
+                batch = {"x": inputs(words, lat, idx), "labels": lat[idx].astype(jnp.float32)}
+                params, opt, _ = step(params, opt, batch)
     return cfg, params
 
 
 def _simnet_cpi(cfg, params, uarch, bench):
-    """SimNet needs the µarch-specific detailed trace as INPUT."""
-    prog = get_benchmark(bench)
-    ft = run_functional(prog, TEST_LEN)
-    det, _ = run_detailed(prog, ft, uarch)
-    al = build_adjusted_trace(det)
-    feats = simnet_features(al.adjusted)
-    ds = simnet_windows(feats, cfg.window)
-    preds = []
-    fwd = jax.jit(lambda p, x: simnet_forward(p, x, cfg))
-    for lo in range(0, len(ds["x"]), 32):
-        out = fwd(params, jnp.asarray(ds["x"][lo : lo + 32]))
-        preds.append(np.asarray(out, np.float32))
-    from repro.core.model import LAT_SCALE
-
-    lat = np.maximum(np.concatenate(preds).reshape(-1, 2), 0.0) * LAT_SCALE
-    total = lat[:, 0].sum() + lat[-1, 1]
-    return total / len(lat)
+    """SimNet simulates the µarch-specific detailed trace of the test
+    program, sequentially, through the engine."""
+    rows = _simnet_rows(uarch, bench, TEST_LEN)
+    model = TrainedModel(params=params, cfg=cfg, name=f"simnet-{uarch.name}")
+    return model.engine(batch_size=SIMNET_BATCH).simulate(rows).cpi
 
 
 def run() -> None:

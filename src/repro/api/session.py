@@ -45,6 +45,7 @@ from ..core.dataset import (
 from ..core.align import build_adjusted_trace
 from ..core.features import FeatureSet, extract_features
 from ..core.model import TaoConfig, init_tao
+from ..core.simnet import SimNetConfig
 from ..core.multiarch import METHODS, eval_loss, init_multiarch, make_joint_step
 from ..core.selection import (
     measure_design_metrics,
@@ -62,6 +63,7 @@ from ..engine.aot import enable_persistent_cache, persistent_cache_status
 from ..engine.metrics import DEFAULT_METRICS, MetricSpec
 from ..engine.plan import ExecutionPlan
 from ..engine.runner import EngineConfig, SimulationResult, StreamingEngine
+from ..engine.sequential import make_engine
 from ..engine.scheduler import SweepJob, SweepReport, TraceSweeper, abstract_params
 from ..store import (
     ArtifactStore,
@@ -164,10 +166,14 @@ class TrainedModel:
     """Trained Tao parameters bound to their config: the simulate/transfer
     half of the workflow.  Engines are cached per EngineConfig, so repeated
     ``simulate`` calls (and every model of the same shape, via the
-    process-wide step cache) reuse one compiled executable."""
+    process-wide step cache) reuse one compiled executable.
+
+    A ``SimNetConfig`` makes it a SimNet model: its engine is the
+    sequential one (``engine/sequential.py``) and it simulates a detailed
+    trace's rows of its design point, not a functional trace."""
 
     params: Dict
-    cfg: TaoConfig
+    cfg: Union[TaoConfig, SimNetConfig]
     name: str = "tao"
     uarch: Optional[MicroArchConfig] = None
     losses: List[float] = dataclasses.field(default_factory=list)
@@ -202,7 +208,7 @@ class TrainedModel:
             # process (and the registry's serve path) shares one set of
             # scales instead of re-deriving them per engine
             qp = self.quantized_params() if ecfg.precision == "int8" else None
-            engine = StreamingEngine(self.params, self.cfg, ecfg, qparams=qp)
+            engine = make_engine(self.params, self.cfg, ecfg, qparams=qp)
             self._engines[ecfg] = engine
             if len(self._engines) == _ENGINE_CACHE_WARN:
                 warnings.warn(
@@ -235,7 +241,9 @@ class TrainedModel:
         (inherited from ``Session(mesh=...)``); ``feature_backend=`` /
         ``precision=`` likewise override the stamped defaults
         (``"fused"``/``"int8"`` for the megakernel + W8A8 path —
-        docs/api.md)."""
+        docs/api.md).  A SimNet model (its engine is picked by the
+        model's kind) takes a detailed trace's rows, on one device at
+        fp32."""
         if plan is None and mesh is None:
             plan = self.sim_plan
         backend = feature_backend or self.sim_feature_backend
@@ -249,7 +257,8 @@ class TrainedModel:
             metrics=tuple(metrics) if metrics is not None else DEFAULT_METRICS,
         )
         ft = trace.functional if isinstance(trace, Trace) else trace
-        if features is None and self.store is not None and backend == "numpy":
+        if features is None and self.store is not None and backend == "numpy" \
+                and engine.takes_features:
             features = self._stored_features(trace, ft)
         return engine.simulate(ft, features=features)
 

@@ -4,7 +4,9 @@ See ``docs/engine.md`` for the data-flow architecture and
 ``benchmarks/bench_timing.py`` for the measured speedup over the legacy
 host-loop path (``repro.core.simulate.simulate_trace_legacy``).  Metric
 accumulators are pluggable (``engine.metrics``); multi-trace DSE sweeps
-run through the async scheduler (``engine.scheduler``).
+run through the async scheduler (``engine.scheduler``).  SimNet, whose
+predictions feed its own inputs, runs a sequential scan over parallel
+sub-traces (``engine.sequential``); ``make_engine`` picks by model kind.
 """
 from .metrics import (
     DEFAULT_METRICS,
@@ -36,6 +38,7 @@ from .runner import (
     simulate_trace_engine,
 )
 from .scheduler import SweepJob, SweepReport, TraceSweeper, sweep_traces
+from .sequential import SimNetEngine, SimNetResult, make_engine
 
 __all__ = [
     "AxisContext",
@@ -59,8 +62,11 @@ __all__ = [
     "windowed_spec",
     "MetricNotCollectedError",
     "MetricNotComputedError",
+    "SimNetEngine",
+    "SimNetResult",
     "SimulationResult",
     "StreamingEngine",
+    "make_engine",
     "simulate_trace_engine",
     "SweepJob",
     "SweepReport",

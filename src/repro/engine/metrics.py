@@ -69,6 +69,7 @@ __all__ = [
     "DLEVEL_HIST",
     "CPI_PHASE",
     "L1D_PHASE",
+    "detailed_input_mpki",
 ]
 
 
@@ -389,3 +390,14 @@ def resolve_metrics(
     if not specs:
         raise ValueError("at least one metric is required")
     return tuple(specs)
+
+
+def detailed_input_mpki(rows: np.ndarray) -> Dict[str, float]:
+    """Branch and L1D MPKI read from a detailed trace's rows (``mispred``;
+    ``is_mem`` with ``dlevel`` at L2 or beyond).  A model that takes these
+    events as inputs (SimNet) predicts neither: they are reported as what
+    the detailed simulator saw, under names that say so."""
+    n = max(len(rows), 1)
+    l1d = rows["is_mem"] & (rows["dlevel"] >= DLEVEL_L2)
+    return {"detailed_branch_mpki": 1000.0 * int(np.count_nonzero(rows["mispred"])) / n,
+            "detailed_l1d_mpki": 1000.0 * int(np.count_nonzero(l1d)) / n}

@@ -470,6 +470,10 @@ class StreamingEngine:
     (batch rows, effective window) that ``batch_rows`` plans.
     """
 
+    # ``simulate`` takes a functional trace and, optionally, its host
+    # FeatureSet (which ``TrainedModel`` keeps in the artifact store)
+    takes_features = True
+
     def __init__(
         self,
         params: Dict,

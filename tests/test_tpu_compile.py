@@ -186,3 +186,29 @@ def test_half_batch_tail_compiles_at_published_widths(topo, chips):
             n_mem=PUBLISHED.n_mem, n_flags=PUBLISHED.flags_dim,
             chunk=DEFAULT_CHUNK, interpret=False,
         ).compile()
+
+
+def test_simnet_scan_compiles_at_published_widths(one_chip):
+    """SimNet's sequential scan (``engine/sequential.py``) at the C3 widths
+    for one launch of 1,024 sub-traces (warm-up 128 + 1,024 iterations), as
+    the ``simnet-c3.sim-long`` cell runs it, and its smallest tail."""
+    from repro.core.simnet import SimNetConfig, init_simnet
+    from repro.engine import SimNetEngine
+
+    cfg = SimNetConfig()
+    params = jax.eval_shape(lambda: init_simnet(jax.random.PRNGKey(0), cfg))
+    engine = SimNetEngine(params, cfg, EngineConfig(batch_size=1024))
+    n = 1024 * cfg.subtrace
+    assert engine.batch_rows(n) == (1024,)
+    assert engine.batch_rows(1) == (64,)
+    try:
+        for rows in (1024, 64):
+            compiled = engine._get_step((rows, cfg.window)).fn.lower(
+                _on(one_chip, params), {}, _on(one_chip, engine._abstract_batch(rows, cfg.window)),
+            ).compile()
+            mem = compiled.memory_analysis()
+            # the packed input (9 MB), the latencies out (9 MB), the carry
+            # and one iteration's activations (~0.3 GB at 1,024 rows)
+            assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2 * 2**30
+    finally:
+        clear_step_cache()
